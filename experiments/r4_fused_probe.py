@@ -1,6 +1,5 @@
-"""Isolate which construct of the fused DMA kernel Mosaic rejects on this
-rig (the r4_validate run died with a compile-helper 500, the same failure
-class round 3 hit with its 3-D BlockSpec gather).
+"""Isolate which construct of the fused DMA kernel Mosaic rejects (the
+same failure class round 3 hit with its 3-D BlockSpec gather).
 
 Variants build up: scalar prefetch -> ANY input + static DMA -> dynamic
 offset DMA -> u8 payloads -> the iota row-select -> the full fused body.

@@ -31,15 +31,12 @@ async def main():
     assert rs_tpu.on_tpu(), "this drive needs the real TPU"
     out = {"on_tpu": True}
 
-    # exercise the production compile-cache path: run this script twice
-    # with SWFS_COMPILE_CACHE set and compare the first-touch latencies
-    cache_dir = os.environ.get("SWFS_COMPILE_CACHE")
-    if cache_dir:
-        from seaweedfs_tpu.ops.rs_resident import (
-            enable_persistent_compile_cache,
-        )
+    # exercise the production compile-cache path (JAX_COMPILATION_CACHE_DIR,
+    # else the fixed in-checkout path): run this script twice and compare
+    # the first-touch latencies
+    from seaweedfs_tpu.ops.rs_resident import enable_persistent_compile_cache
 
-        out["compile_cache"] = enable_persistent_compile_cache(cache_dir)
+    out["compile_cache"] = enable_persistent_compile_cache()
 
     tmp = tempfile.mkdtemp(prefix="serving_e2e_")
     cluster = LocalCluster(

@@ -77,9 +77,9 @@ async def run(args) -> None:
     if len(counts) == 1:
         counts = counts * len(dirs)
     if args.ec_device_cache_mb > 0:
-        from ..ops.rs_resident import compile_cache_for_volume_dirs
+        from ..ops.rs_resident import enable_persistent_compile_cache
 
-        compile_cache_for_volume_dirs(args.ec_device_cache_mb, dirs)
+        enable_persistent_compile_cache()
     vs = VolumeServer(
         masters=[ms.advertise_url],
         directories=dirs,

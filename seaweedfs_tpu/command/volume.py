@@ -503,11 +503,12 @@ async def run(args) -> None:
             ec_serving.mesh_process_count,
         )
     if args.ec_device_cache_mb > 0:
-        # process entry point: persist kernel compiles next to the data so
-        # restarts don't re-pay tens of seconds per reconstruct shape
-        from ..ops.rs_resident import compile_cache_for_volume_dirs
+        # process entry point: persist kernel compiles (at
+        # JAX_COMPILATION_CACHE_DIR, else one fixed path in the checkout)
+        # so restarts don't recompile every reconstruct shape
+        from ..ops.rs_resident import enable_persistent_compile_cache
 
-        compile_cache_for_volume_dirs(args.ec_device_cache_mb, dirs)
+        enable_persistent_compile_cache()
     if len(counts) == 1:
         counts = counts * len(dirs)
     vs = VolumeServer(

@@ -214,14 +214,15 @@ class StreamEncoder:
                 workload="warmup",
                 busy_s=time.perf_counter() - t0, dispatches=1,
             )
-        except Exception:  # noqa: BLE001 — a failed ingest AOT compile
-            # must not kill the shared executor; the shape keeps
+        except Exception as e:  # noqa: BLE001 — a failed ingest AOT
+            # compile must not kill the shared executor; the shape keeps
             # encoding on the host codec, which serves it fine
             import logging
 
             logging.getLogger(__name__).exception(
                 "ingest AOT compile failed for %s", key
             )
+            rs_resident.note_device_failure("aot", f"shape {key}: {e!r}")
             with rs_resident._shapes_lock:
                 rs_resident._aot_pending.discard(key)
                 rs_resident._aot_failed.add(key)

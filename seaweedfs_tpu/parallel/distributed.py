@@ -24,10 +24,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:  # jax >= 0.8 promoted shard_map out of experimental
-    from jax import shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops import gf256
@@ -266,14 +263,8 @@ def _staged_worker_main(argv) -> None:
         f"--xla_force_host_platform_device_count={args.devices_per_proc}"
     )
     jax.config.update("jax_platforms", "cpu")
-    try:  # cross-process CPU collectives
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception as e:  # noqa: BLE001 — older jax: default impl
-        import logging
-
-        logging.getLogger("parallel").debug(
-            "gloo CPU collectives unavailable (older jax?): %s", e
-        )
+    # cross-process CPU collectives
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     from . import mesh as mesh_mod
 
     mesh_mod.initialize_distributed(args.coordinator, args.pid, args.nproc)

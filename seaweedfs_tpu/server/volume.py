@@ -1072,8 +1072,47 @@ class VolumeServer:
             {
                 "Version": "seaweedfs-tpu",
                 "Volumes": [vars(i) for i in infos],
+                "Device": await asyncio.to_thread(self._device_status),
             }
         )
+
+    def _device_status(self) -> dict:
+        """Device identity, resolved EC backend, residency and swallowed
+        pin/warm/AOT failures (rs_resident.device_status) — what tells a
+        run that used the chip from one that fell back to the host.
+
+        Only for a process that already uses the device: one with a
+        device shard cache, one whose -ec.backend names a device codec,
+        or one that has resolved an `auto` backend (the ingest plane
+        does at start-up, -ec.backend=auto on its first EC operation) —
+        that resolution is what initialises the JAX backend.  Any other
+        server answers {"initialised": False} and initialises nothing:
+        /status is a readiness probe, and a probe must not be what
+        takes the chip."""
+        from ..ops import rs
+
+        cache = self.store.ec_device_cache
+        backend = self.store.ec_backend
+        if not (
+            cache is not None
+            or backend in ("pallas", "xla")
+            or rs.auto_resolved()
+        ):
+            return {"initialised": False, "ec_backend": backend}
+        try:
+            from ..ops import rs_resident
+
+            return {
+                "initialised": True,
+                **rs_resident.device_status(backend, cache),
+            }
+        except Exception as e:  # noqa: BLE001 — no usable backend: the
+            # probe still answers, and says why
+            return {
+                "initialised": False,
+                "ec_backend": backend,
+                "error": repr(e)[:500],
+            }
 
     async def h_needle(self, request: web.Request) -> web.StreamResponse:
         if request.method in ("GET", "HEAD"):

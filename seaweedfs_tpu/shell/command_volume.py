@@ -823,7 +823,9 @@ async def cmd_volume_device_status(env, args):
     status from the master's telemetry plane — HBM used/budget/
     headroom (aggregate AND one row per mesh device under the r19
     sharded layout), resident shard counts per EC volume, compile-cache
-    hit/miss, evictions, pin claims.  -hot additionally fetches each
+    hit/miss, evictions, pin claims, plus each fresh node's device
+    identity (platform, device kind, count, resolved EC backend) and
+    pin/warm/AOT failure counts from its /status.  -hot additionally fetches each
     node's /debug/device/hot: the per-call-shape dispatch counters and
     latency EWMAs, hottest first — "what shape is the device actually
     spending its time in" as one command"""
@@ -877,6 +879,8 @@ async def cmd_volume_device_status(env, args):
             )
         for vid, count in dev["resident_shards_by_volume"].items():
             env.write(f"  ec volume {vid}: {count} resident shards")
+        if not n["stale"]:
+            await _print_device_identity(env, url)
         if hot_limit and not n["stale"]:
             await _print_hot_shapes(env, url, hot_limit)
 
@@ -954,6 +958,46 @@ async def cmd_volume_device_attribution(env, args):
                         f"calls={d['dispatches']} "
                         f"bytes={fmt_bytes(d['bytes'])}"
                     )
+
+
+async def _print_device_identity(env, url: str) -> None:
+    """Fetch + print one node's /status "Device" block: which
+    accelerator JAX found, what -ec.backend resolved to on it, and the
+    pin / warm / AOT-compile failures the server survived (each falls
+    back to the host codec, so only these counts tell)."""
+    import aiohttp
+
+    try:
+        async with aiohttp.ClientSession() as sess:
+            async with sess.get(f"http://{url}/status") as r:
+                if r.status != 200:
+                    raise ValueError(f"HTTP {r.status}")
+                dev = (await r.json())["Device"]
+    except Exception as e:  # noqa: BLE001 — one unreachable node must
+        # not kill the whole status sweep
+        env.write(f"  device: unavailable ({e})")
+        return
+    if not dev["initialised"]:
+        env.write(
+            f"  device: not initialised (-ec.backend={dev['ec_backend']}"
+            + (f", {dev['error']}" if "error" in dev else "") + ")"
+        )
+        return
+    env.write(
+        f"  device: platform={dev['platform']} "
+        f"kind={dev['device_kind']!r} count={dev['device_count']} "
+        f"ec_backend={dev['ec_backend']} "
+        f"serving_kernel={dev['serving_kernel']} "
+        f"interpret={dev['interpret']}"
+    )
+    failures = dev["failures"]
+    env.write(
+        "  device failures: "
+        + " ".join(f"{k}={v['count']}" for k, v in failures.items())
+    )
+    for kind, v in failures.items():
+        if v["count"]:
+            env.write(f"    last {kind} failure: {v['last']}")
 
 
 async def _print_hot_shapes(env, url: str, limit: int) -> None:

@@ -83,6 +83,108 @@ class TestAotWarm:
         assert out == coded[3][7:1006].tobytes()
 
 
+class TestWarmPlanCoversDispatch:
+    """warm()'s plan is what keeps a pinned volume's reads off the
+    cold-shape shed: every device-call shape _pack_calls can emit for a
+    batch wanting the lost shard must be one warm() compiles.  The fetch
+    ladders warm enumerates (_fused_fetch_rungs, _sharded_fetch_rungs)
+    restate by hand what _fetch_cover / _plan produce, so the two are
+    held together here over random request mixes in every size and
+    count bucket — the CPU rehearsal of chip_smoke.py does not enforce
+    sheds, and on the chip only a few hundred reads would."""
+
+    SHARD = 4 << 20
+    MISSING = 3
+
+    def _cache(self, placement, layout):
+        kw = dict(shard_quantum=1 << 20, layout=layout)
+        if placement == "mesh":
+            kw.update(mesh_devices=4, mesh_min_shard_bytes=0)
+        cache = rs_resident.DeviceShardCache(**kw)
+        rng = np.random.default_rng(5)
+        for sid in range(14):
+            if sid not in (self.MISSING, 11):
+                # the planner never looks at the bytes
+                cache.put(40, sid, rng.integers(
+                    0, 256, size=self.SHARD, dtype=np.uint8))
+        return cache
+
+    def _keys(self, cache, requests, layout):
+        calls, _subs, survivors, a_prep, use, w_true, place = (
+            rs_resident._pack_calls(
+                cache, 40, requests, "pallas", True, layout,
+                rs_resident.DATA_SHARDS, rs_resident.TOTAL_SHARDS,
+                record_observed=False,
+            )
+        )
+        groups = cache.groups if layout == "blockdiag" else 1
+        return {
+            rs_resident._call_key(
+                kind, "pallas", groups, w_true, tile, fetch, n_bucket,
+                len(use), a_prep.shape, int(survivors[0].size), True,
+                rs_resident._key_place(cache, place),
+            )
+            for kind, _p, _c, _pad, fetch, tile, n_bucket, _d in calls
+        }
+
+    def _requests(self, rng, lo, hi, n):
+        """n reads of the lost shard whose lane-aligned delta+take lies
+        in (lo, hi], anywhere in the shard."""
+        out = []
+        for _ in range(n):
+            off = int(rng.integers(0, self.SHARD - hi))
+            delta = off % rs_resident.LANE
+            take = int(rng.integers(max(1, lo + 1 - delta), hi - delta + 1))
+            out.append((self.MISSING, off, take))
+        return out
+
+    @pytest.mark.parametrize(
+        "placement,layout",
+        [("one_device", "flat"), ("one_device", "blockdiag"),
+         ("mesh", "blockdiag")],
+    )
+    def test_every_packed_shape_is_in_the_warm_plan(
+        self, monkeypatch, placement, layout
+    ):
+        cache = self._cache(placement, layout)
+        planned = set()
+        monkeypatch.setattr(
+            rs_resident, "_schedule_aot_compiles",
+            lambda keys: planned.update(keys) or [],
+        )
+        try:
+            rs_resident.warm(
+                cache, 40, sizes=cache.warm_sizes, counts=cache.warm_counts,
+                kernel="pallas", interpret=True,
+            )
+            assert cache.aot_state(40) == "done" and planned
+            rng = np.random.default_rng(21)
+            sizes = (0,) + rs_resident.SIZE_BUCKETS
+            counts = (0,) + rs_resident.COUNT_BUCKETS
+            for lo, hi in zip(sizes, sizes[1:]):
+                for c_lo, c_hi in zip(counts, counts[1:]):
+                    for _trial in range(3):
+                        n = int(rng.integers(c_lo + 1, c_hi + 1))
+                        reqs = self._requests(rng, lo, hi, n)
+                        cold = self._keys(cache, reqs, layout) - planned
+                        assert not cold, (lo, hi, n, cold)
+            # batches mixed over every bucket, with reads long enough
+            # to split into CHUNK-sized sub-requests
+            for _trial in range(40):
+                n = int(rng.integers(1, 300))
+                reqs = []
+                for _ in range(n):
+                    size = int(2 ** rng.uniform(0, 21.6))
+                    reqs.append((
+                        self.MISSING,
+                        int(rng.integers(0, self.SHARD - size)), size,
+                    ))
+                cold = self._keys(cache, reqs, layout) - planned
+                assert not cold, (n, cold)
+        finally:
+            cache.clear()
+
+
 class TestColdShapeShed:
     def test_shed_raises_before_device_work_and_counts(self, coded):
         cache = fill_cache(coded, missing=(3, 11), vid=9)
@@ -297,6 +399,60 @@ class TestScrubMegakernel:
             store.close()
 
 
+class TestScrubWindows:
+    """Scrub walks a shard in _SCRUB_WINDOW-lane windows and sums the
+    windows' counts on the host.  Every other scrub test fits in one
+    window; here the window is patched small, so that full windows, the
+    remainder window and (megakernel) the window shared by a two-volume
+    stack all run, with corruption in each."""
+
+    WINDOW = 128 << 10
+
+    @pytest.mark.parametrize("layout", ["flat", "blockdiag"])
+    def test_remainder_window_and_two_volume_stack(
+        self, coded, monkeypatch, layout
+    ):
+        monkeypatch.setattr(rs_resident, "_SCRUB_WINDOW", self.WINDOW)
+        length = coded.shape[1]  # 300_000: two full windows + remainder
+        assert 2 * self.WINDOW < length < 3 * self.WINDOW
+        cache = rs_resident.DeviceShardCache(
+            shard_quantum=1 << 20, layout=layout
+        )
+        try:
+            for vid in (1, 2):
+                for sid in range(14):
+                    cache.put(vid, sid, coded[sid])
+            first = coded[10].copy()
+            first[17] ^= 0x01  # volume 1, parity row 0, first window
+            cache.put(1, 10, first)
+            tail = coded[12].copy()
+            tail[self.WINDOW + 5] ^= 0x80  # second window
+            tail[length - 3] ^= 0x04  # remainder window, last lanes
+            cache.put(2, 12, tail)
+            want = {1: [1, 0, 0, 0], 2: [0, 0, 2, 0]}
+
+            d0 = _counter(
+                "SeaweedFS_volumeServer_ec_scrub_device_dispatch_total",
+                {"mode": "per_volume"},
+            )
+            for vid in (1, 2):
+                mismatch, n_lanes = rs_resident.scrub_volume(cache, vid)
+                assert mismatch == want[vid], (layout, vid)
+            per_volume = -(-n_lanes // self.WINDOW)
+            assert per_volume == 3
+            assert _counter(
+                "SeaweedFS_volumeServer_ec_scrub_device_dispatch_total",
+                {"mode": "per_volume"},
+            ) == d0 + 2 * per_volume
+
+            # the stack of two shares one window: half the lanes each
+            results, stats = rs_resident.scrub_all_resident(cache)
+            assert {v: r[0] for v, r in results.items()} == want
+            assert stats["device_calls"] == -(-n_lanes // (self.WINDOW // 2))
+        finally:
+            cache.clear()
+
+
 class TestPackedMetaWire:
     def test_fused_call_ships_packed_single_row(self, coded):
         """ONE [n_bucket] int32 vector per fused call — 4 bytes/slot,
@@ -368,14 +524,34 @@ class TestObservedShapePersistence:
 
 
 class TestCompileCacheStatus:
-    def test_bad_path_observable(self, tmp_path):
+    @pytest.fixture
+    def fresh_cache_state(self, monkeypatch):
+        """enable_persistent_compile_cache is once-per-process: give the
+        test a process that has not called it, and put jax.config back."""
+        import jax
+
+        monkeypatch.setattr(rs_resident, "_COMPILE_CACHE_SET", False)
+        monkeypatch.setattr(rs_resident, "_observed_path", None)
+        was_dir = jax.config.jax_compilation_cache_dir
+        was_min = jax.config.jax_persistent_cache_min_compile_time_secs
+        yield
+        jax.config.update("jax_compilation_cache_dir", was_dir)
+        jax.config.update(
+            "jax_persistent_cache_min_compile_time_secs", was_min
+        )
+
+    def test_bad_path_observable(
+        self, tmp_path, monkeypatch, fresh_cache_state
+    ):
         """A bad cache dir must not just log once: the failure is a
         gauge plus a status field operators can query."""
         blocker = tmp_path / "not_a_dir"
         blocker.write_text("file, not dir")
-        assert not rs_resident.enable_persistent_compile_cache(
-            str(blocker / "cache")
+        monkeypatch.delenv(rs_resident.COMPILE_CACHE_ENV, raising=False)
+        monkeypatch.setattr(
+            rs_resident, "COMPILE_CACHE_DIR", str(blocker / "cache")
         )
+        assert not rs_resident.enable_persistent_compile_cache()
         st = rs_resident.compile_cache_status()
         assert st["enabled"] is False and st["error"]
         assert str(blocker / "cache") == st["path"]
@@ -383,6 +559,53 @@ class TestCompileCacheStatus:
             stats_metrics.VOLUME_SERVER_EC_COMPILE_CACHE_ENABLED._value.get()
             == 0
         )
+
+    def test_env_dir_left_alone(
+        self, tmp_path, monkeypatch, fresh_cache_state
+    ):
+        """With JAX_COMPILATION_CACHE_DIR set the program uses that
+        directory and sets none in code: jax.config's value is whatever
+        it was (JAX itself adopts the variable at import)."""
+        import jax
+
+        env_dir = tmp_path / "from_env"
+        monkeypatch.setenv(rs_resident.COMPILE_CACHE_ENV, str(env_dir))
+        jax.config.update("jax_compilation_cache_dir", "sentinel-untouched")
+        assert rs_resident.enable_persistent_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == "sentinel-untouched"
+        assert rs_resident.compile_cache_status()["path"] == str(env_dir)
+        # every warm shape persists, however fast it compiled
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+        # observed_shapes.json follows the same directory
+        assert rs_resident._observed_path == str(
+            env_dir / rs_resident.OBSERVED_SHAPES_FILE
+        )
+
+    def test_unset_env_one_fixed_path(self, monkeypatch, fresh_cache_state):
+        """Unset, the cache goes to ONE fixed path inside the checkout —
+        two servers started on two different -dir values resolve to the
+        same directory (it is part of the cache key; one that moved with
+        the data would never hit)."""
+        import jax
+
+        monkeypatch.delenv(rs_resident.COMPILE_CACHE_ENV, raising=False)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert rs_resident.COMPILE_CACHE_DIR == os.path.join(
+            repo, ".jax_compile_cache"
+        )
+        seen = []
+        for _data_dir in ("/data/a", "/data/b"):
+            # the bootstrap takes no directory at all: -dir cannot
+            # reach the cache path
+            monkeypatch.setattr(rs_resident, "_COMPILE_CACHE_SET", False)
+            assert rs_resident.enable_persistent_compile_cache()
+            seen.append(
+                (
+                    jax.config.jax_compilation_cache_dir,
+                    rs_resident.compile_cache_status()["path"],
+                )
+            )
+        assert seen == [(rs_resident.COMPILE_CACHE_DIR,) * 2] * 2
 
     def test_telemetry_carries_compile_cache_state(self):
         from seaweedfs_tpu.pb import master_pb2

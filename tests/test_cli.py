@@ -191,3 +191,34 @@ def test_cli_shell_runs_commands(tmp_path):
                     p.wait(timeout=5)
                 except subprocess.TimeoutExpired:
                     p.kill()
+
+
+def test_status_probe_does_not_initialise_jax(tmp_path):
+    """/status is a readiness probe: on a server that does not use the
+    device (-ec.backend=cpu, no device cache, ingest plane off) its
+    Device block says so and JAX is never imported — a probe must not
+    be what takes the chip.  Once the process has resolved an `auto`
+    backend (the default ingest plane does), the identity is there."""
+    script = f"""
+import sys
+from seaweedfs_tpu.ingest import IngestConfig
+from seaweedfs_tpu.server.volume import VolumeServer
+
+vs = VolumeServer(["127.0.0.1:1"], [{str(tmp_path)!r}], ec_backend="cpu",
+                  ec_ingest=IngestConfig(enabled=False))
+assert vs._device_status() == {{"initialised": False, "ec_backend": "cpu"}}
+assert "jax" not in sys.modules
+vs.store.close()
+vs = VolumeServer(["127.0.0.1:1"], [{str(tmp_path)!r}], ec_backend="cpu")
+dev = vs._device_status()
+assert dev["initialised"] and dev["platform"] == "cpu", dev
+assert dev["ec_backend"] in ("native", "numpy") and dev["device_count"] >= 1
+assert set(dev["failures"]) == {{"pin", "warm", "aot"}}
+assert dev["compile_cache"]["hits"] == 0
+"""
+    r = subprocess.run(
+        [sys.executable, "-c", script], cwd=REPO, capture_output=True,
+        text=True, timeout=120,
+        env={**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": REPO},
+    )
+    assert r.returncode == 0, r.stderr[-2000:]

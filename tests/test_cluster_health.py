@@ -246,9 +246,15 @@ def test_cluster_health_e2e(tmp_path):
     from bench import build_degraded_cluster
 
     async def go():
+        from seaweedfs_tpu.repair import RepairConfig
+
         cluster, vs, blobs, vid = await build_degraded_cluster(
             str(tmp_path), n_blobs=8, device_cache=True,
             cache_budget=1 << 30, warm_sizes=(),
+            # the master's autonomous repair rebuilds the two dropped
+            # shards within its 5 s scan: the 12-resident-shard
+            # assertions below would race it on a loaded box
+            master_kwargs={"ec_repair": RepairConfig(enabled=False)},
         )
         master_http = cluster.master.url
         try:
@@ -357,6 +363,10 @@ def test_cluster_health_e2e(tmp_path):
                 await cmd_volume_device_status(env, ["-node", vs.url])
                 out = "\n".join(str(l) for l in lines)
                 assert f"ec volume {vid}: 12 resident shards" in out
+                # the node's /status Device block: which accelerator,
+                # what the backend resolved to, swallowed failures
+                assert "device: platform=cpu kind='cpu'" in out
+                assert "device failures: pin=0 warm=0 aot=0" in out
 
                 # node goes silent: heartbeats stop, the master flags it
                 # stale within 2 intervals (pulse=1s -> stale_after=2s)

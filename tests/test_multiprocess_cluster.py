@@ -13,6 +13,7 @@ import sys
 import aiohttp
 import pytest
 
+from seaweedfs_tpu.operation.ready import wait_cluster_ready
 from seaweedfs_tpu.shell import CommandEnv, run_command
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -89,6 +90,9 @@ def test_multiprocess_cluster(tmp_path):
                 )
             )
             await wait_http(f"http://127.0.0.1:{fp}/?limit=1")
+            # answering HTTP is not registered: an upload before the
+            # volume server's first heartbeat fails at assign
+            await wait_cluster_ready(f"127.0.0.1:{mp}")
 
             # data plane: upload + range read through the filer process
             data = os.urandom(512 * 1024)
