@@ -20,11 +20,14 @@ from .trace import (
     RING,
     TRACE_HEADER,
     Trace,
+    await_span,
     configure,
     current,
     detached,
+    event,
     finish_trace,
     grpc_metadata,
+    interval,
     middleware,
     outbound_headers,
     parse_trace_header,
@@ -62,11 +65,14 @@ __all__ = [
     "timeline",
     "TRACE_HEADER",
     "Trace",
+    "await_span",
     "configure",
     "current",
     "detached",
+    "event",
     "finish_trace",
     "grpc_metadata",
+    "interval",
     "middleware",
     "outbound_headers",
     "parse_trace_header",

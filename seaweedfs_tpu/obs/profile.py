@@ -9,7 +9,16 @@ the on-demand analogue for the serving path:
     returns the trace directory (open it with any XPlane viewer).
     SWFS_DEBUG-gated like /debug/stacks — a profile capture reveals
     internals and costs device attention, so it is opt-in only.  One
-    capture at a time; concurrent requests get 409.
+    capture at a time; concurrent requests get 409.  The capture holds
+    the device's programs and operations AND the program's own spans
+    (obs/trace.py) on one clock: for its length every `obs.span` is
+    also a `TraceAnnotation` on the thread that did the work, and every
+    section that spans awaits a `<name>:begin` / `<name>:end` pair of
+    instant events with one `id`, on `/host:CPU` of the same
+    `.xplane.pb` as `/device:TPU:<n>`.  JAX's Python tracer is off
+    (every Python call as an event: millions of them in seconds, a
+    server at half speed, and nobody reads them); the named spans are
+    what the host side of a capture is for.
   * `GET /debug/device/hot` is the zero-cost half: rs_resident keeps a
     per-call-shape dispatch counter + a latency EWMA per `_call_key`
     (see ops/rs_resident.hot_shapes), so "what shape is hot right now"
@@ -22,10 +31,13 @@ is set, so the bundle carries a capture of the device during the burn.
 from __future__ import annotations
 
 import asyncio
+import itertools
 import logging
 import os
 import threading
 import time
+
+from . import trace as obs_trace
 
 log = logging.getLogger("obs")
 
@@ -41,6 +53,31 @@ KEEP_PROFILE_DIRS = 8
 
 # single-flight: jax.profiler supports one active trace per process
 _PROFILE_BUSY = threading.Lock()
+
+# what joins a `:begin` to its `:end`; never reused, so that a section
+# begun in one capture and ended in the next pairs with nothing there
+_PAIR_IDS = itertools.count(1)
+
+
+def _timeline_hook():
+    """What obs.trace.TIMELINE is for the length of one capture:
+    (name, annotations, paired) -> a callable that ends what it began."""
+    from jax.profiler import TraceAnnotation
+
+    def instant(name: str, annotations: dict) -> None:
+        with TraceAnnotation(name, **annotations):
+            pass
+
+    def begin(name: str, annotations: dict, paired: bool):
+        if not paired:
+            event = TraceAnnotation(name, **annotations)
+            event.__enter__()
+            return lambda: event.__exit__(None, None, None)
+        pair = next(_PAIR_IDS)
+        instant(name + ":begin", {**annotations, "id": pair})
+        return lambda: instant(name + ":end", {"id": pair})
+
+    return begin
 
 
 def _new_profile_dir() -> str:
@@ -89,11 +126,17 @@ async def profile_handler(request):
         try:
             import jax
 
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            obs_trace.TIMELINE = _timeline_hook()
             # start/stop around a plain sleep: the serving loop keeps
             # dispatching on its own threads, and the profiler captures
             # every device computation in the window — exactly the
             # "what was the device doing while the SLO burned" view
-            await asyncio.to_thread(jax.profiler.start_trace, trace_dir)
+            await asyncio.to_thread(
+                jax.profiler.start_trace, trace_dir,
+                profiler_options=options,
+            )
             try:
                 await asyncio.sleep(seconds)
             finally:
@@ -113,6 +156,7 @@ async def profile_handler(request):
             }
         )
     finally:
+        obs_trace.TIMELINE = None
         _PROFILE_BUSY.release()
 
 

@@ -22,7 +22,18 @@ reconstruct, disk shard read).  This module is the request-scoped view:
     distribution even when tracing is disabled;
   * completed traces go to a bounded in-memory ring served as JSON at
     /debug/traces on every server, newest-first, and requests slower
-    than `-obs.slowMs` are logged with their per-span breakdown.
+    than `-obs.slowMs` are logged with their per-span breakdown;
+  * while a `/debug/profile` capture is live (obs/profile.py sets
+    `TIMELINE`), every span is also put on the profiler's own timeline,
+    the clock the device's events are on: a `span` as one
+    `jax.profiler.TraceAnnotation` on the thread that did the work, an
+    `await_span` or `interval` (a section that awaits, so other work
+    runs on its thread meanwhile, or that a capture may begin in the
+    middle of) as two instant events `<name>:begin` / `<name>:end`
+    carrying the same `id`; `interval` and `event` are on the timeline
+    and nowhere else.  This module never imports JAX: the hook is
+    a callable the profile handler hands in, and with no capture live a
+    span pays one `is None` test on entry and one on exit.
 
 Co-hosted roles (server/cluster.py) share one ring exactly like they
 share stats.REGISTRY; separate processes (the deployed shape) each have
@@ -32,7 +43,7 @@ from __future__ import annotations
 
 import contextvars
 import logging
-import os
+import random
 import threading
 import time
 from collections import deque
@@ -57,10 +68,22 @@ _CURRENT: contextvars.ContextVar = contextvars.ContextVar(
 _STAGE_SINK: contextvars.ContextVar = contextvars.ContextVar(
     "obs_stage_sink", default=None
 )
+# the profiler-timeline hook: None, or for the length of one
+# /debug/profile capture a callable (name, annotations, paired) -> close
+# that opens the event(s) and returns what ends them (obs/profile.py)
+TIMELINE = None
+
+
+# ids come from a generator seeded once from the OS, not from a system
+# call each: a trace id joins servers and a span id is unique within its
+# trace, neither is a secret, and os.urandom is a system call a span —
+# a dozen a GET on the volume server's one loop thread (6.4 us each on
+# the benchmark's sandboxed host, PERF.md §6 "PR 24")
+_IDS = random.Random()
 
 
 def _new_id(nbytes: int = 8) -> str:
-    return os.urandom(nbytes).hex()
+    return f"{_IDS.getrandbits(8 * nbytes):0{2 * nbytes}x}"
 
 
 class Span:
@@ -331,9 +354,14 @@ class span:
 
     Works in handlers and in asyncio.to_thread workers alike (the
     context travels with the copied contextvars).  `annotate(**kw)` adds
-    facts discovered mid-block (byte counts, compile misses)."""
+    facts discovered mid-block (byte counts, compile misses).
 
-    __slots__ = ("name", "annotations", "_t0", "_span", "_token")
+    On the profiler's timeline (module docstring) a span is one event
+    on its thread, and events nest per thread: a block with an `await`
+    inside is an `await_span`, never a `span`."""
+
+    __slots__ = ("name", "annotations", "_t0", "_span", "_token", "_close")
+    _paired = False
 
     def __init__(self, name: str, **annotations):
         self.name = name
@@ -354,9 +382,16 @@ class span:
                 annotations=self.annotations,
             )
             self._token = _CURRENT.set((trace, self._span.span_id))
+        hook = TIMELINE
+        self._close = (
+            None if hook is None
+            else hook(self.name, self.annotations, self._paired)
+        )
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        if self._close is not None:
+            self._close()
         dur = time.perf_counter() - self._t0
         if self._token is not None:
             _CURRENT.reset(self._token)
@@ -377,6 +412,57 @@ class span:
                     else:
                         rec[2][k] = v
         _metrics.REQUEST_STAGE_SECONDS.labels(stage=self.name).observe(dur)
+
+
+class await_span(span):
+    """A `span` whose block awaits (`batch_dispatch` around
+    `asyncio.to_thread`, a streamed response body): recorded like any
+    span, and on the profiler's timeline a `:begin` / `:end` pair of
+    instant events, since other work runs on its thread in between."""
+
+    __slots__ = ()
+    _paired = True
+
+
+class interval:
+    """A section that exists on the profiler's timeline only, as a
+    `:begin` / `:end` pair: one request's stay in the server, one bulk
+    pipeline run — what tells "nothing asked for the device" from
+    "something did and the device idled".  The ring already holds the
+    request's trace, so nothing else is recorded, and with no capture
+    live the block costs the two tests a span pays and nothing more."""
+
+    __slots__ = ("name", "annotations", "_close")
+    _paired = True
+
+    def __init__(self, name: str, **annotations):
+        self.name = name
+        self.annotations = annotations
+
+    def __enter__(self) -> "interval":
+        hook = TIMELINE
+        self._close = (
+            None if hook is None
+            else hook(self.name, self.annotations, self._paired)
+        )
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        if self._close is not None:
+            self._close()
+
+
+class event(interval):
+    """Timeline only like `interval`, but one event on its thread: a
+    section with no `await` inside that runs too often to be a stage.
+    The bulk pipelines' per-batch sections are these: six spans a batch
+    on three threads cost `ec.encode` 2.6 % of its rate with no capture
+    live (PERF.md §6, "PR 24"), their seconds are counted in
+    ec_bulk_seconds / ec_bulk_codec_seconds already, and nothing read a
+    per-batch histogram."""
+
+    __slots__ = ()
+    _paired = False
 
 
 class stage_sink:

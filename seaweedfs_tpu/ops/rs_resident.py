@@ -249,9 +249,9 @@ def device_status(ec_backend: str, cache=None) -> dict:
     {"platform", "device_kind", "device_count"} as JAX reports them, the
     resolved EC backend of `ec_backend` (the -ec.backend flag) with the
     serving kernel and interpret mode the entry points will pick, AOT
-    registry occupancy, the count + last message of every pin / warm /
-    AOT-compile failure this process swallowed, and (with a `cache`)
-    what is resident where and how far each volume's warm plan got."""
+    registry, every swallowed pin / warm / AOT-compile failure (count +
+    last message), the allocator's bytes per local device ("memory") and
+    (with a `cache`) what is resident where and each warm plan's state."""
     from . import rs
 
     devices = mesh_mod.global_devices()  # == jax.devices(), pod order
@@ -267,7 +267,7 @@ def device_status(ec_backend: str, cache=None) -> dict:
         "interpret": interpret,
         "compile_cache": compile_cache_status(),
         "aot": aot_stats(),
-        "failures": failures,
+        "failures": failures, **_device_memory(),
     }
     if cache is not None:
         resident = cache.resident_by_vid()
@@ -3554,3 +3554,21 @@ def warm(
         )
     else:  # every shape already warm
         cache._set_aot_state(vid, "done")
+
+
+# At the end of the file on purpose: a Pallas executable's persistent-cache
+# key carries its kernel's source lines, so code added above the kernels
+# costs every warm shape a recompile.
+
+
+def _device_memory() -> dict:
+    """{"memory": [{"device", "bytes_in_use", "peak_bytes_in_use",
+    "bytes_limit"}, ...]} from each local device's allocator, or {} where
+    the backend reports none (the CPU's `memory_stats()` is None)."""
+    keys = ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")
+    memory = [
+        {"device": d.id, **{k: int(m[k]) for k in keys if k in m}}
+        for d in mesh_mod.local_devices()
+        if (m := d.memory_stats())
+    ]
+    return {"memory": memory} if memory else {}

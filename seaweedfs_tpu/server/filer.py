@@ -403,7 +403,7 @@ class FilerServer:
                     f"{view.offset_in_chunk + view.view_size - 1}"
                 )
             try:
-                with obs.span(
+                with obs.await_span(
                     "chunk_fetch", file_id=view.file_id,
                     bytes=view.view_size,
                 ):
@@ -433,7 +433,7 @@ class FilerServer:
         last_err: Exception | None = None
         for url in urls:
             try:
-                with obs.span("chunk_fetch", file_id=file_id):
+                with obs.await_span("chunk_fetch", file_id=file_id):
                     async with self._session.get(
                         url,
                         headers={

@@ -1120,7 +1120,10 @@ class VolumeServer:
                 stats.VOLUME_SERVER_REQUEST_COUNTER,
                 stats.VOLUME_SERVER_REQUEST_HISTOGRAM,
                 "get",
-            ):
+            ), obs.interval("get"):
+                # one GET's stay in the handler, on the profiler's
+                # timeline: a capture tells the seconds in which the
+                # device idled with no GET to serve from the rest
                 return await self.h_read(request)
         if request.method in ("POST", "PUT"):
             self._check_write_jwt(request)
@@ -1254,7 +1257,12 @@ class VolumeServer:
                     and n.last_modified + ttl_min * 60 < time.time()
                 ):
                     raise web.HTTPNotFound(text="needle expired")
-            return await self._respond_needle(request, n)
+            # headers, range and the body as far as this handler writes
+            # it: a streamed body whole, a small one only up to the
+            # web.Response (aiohttp writes that after the handler
+            # returned, where no span of the program can reach)
+            with obs.await_span("response_write", bytes=len(n.data)):
+                return await self._respond_needle(request, n)
 
     async def _respond_needle(
         self, request: web.Request, n: Needle

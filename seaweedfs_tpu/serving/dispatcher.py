@@ -138,8 +138,51 @@ class EcReadDispatcher:
         `tier` is the QoS tier (serving/qos.py; unknown values map to
         interactive); `origin` attributes the read's source in the
         read_route series ("s3" = the gateway's direct volume path)."""
+        # admission is synchronous — nothing else runs on the loop
+        # between its first line and its last — so it is one stage of
+        # the trace and one event on the profiler's timeline.  The
+        # request's context is taken before the span opens: the batch's
+        # stages are the request's children, not the admission's
+        ctx = obs.current()
+        with obs.span("get_admit", vid=vid):
+            req = self._admit(
+                vid, nid, cookie, normalize_tier(tier), origin, ctx
+            )
+        if req is None:
+            # use_device only where the dispatcher is disabled: the
+            # pre-batching per-read behavior, device reconstruct
+            # included (an idle device on a resident volume should
+            # still serve width-1 reads)
+            return await self._read_native(
+                vid, nid, cookie, use_device=not self.cfg.enabled
+            )
+        t_resident = time.perf_counter()
+        try:
+            with obs.interval("get_queued", vid=vid):
+                return await req.future
+        finally:
+            # the request's WHOLE dispatcher residency, enqueue ->
+            # waiter resume, as a low-priority queue_wait span
+            # (observe=False: the admission-window histogram sample is
+            # the drain loop's).  The batch stage spans outrank it in
+            # critical-path attribution, so all it claims is the slice
+            # nothing else covers — chiefly the future-resume gap where
+            # the batch is done but the event loop hasn't scheduled
+            # this coroutine yet, which under load is milliseconds a
+            # tail forensics answer must not call untraced.
+            obs.record_span(
+                req.obs_ctx, "queue_wait", t_resident,
+                time.perf_counter() - t_resident, observe=False,
+            )
+
+    def _admit(
+        self, vid: int, nid: int, cookie: int | None, tier: str,
+        origin: str, obs_ctx,
+    ) -> ReadRequest | None:
+        """Route one read: the queued ReadRequest its caller awaits, or
+        None for the native per-read path.  `obs_ctx` is the request's
+        trace context, which the batch's stages are replayed onto."""
         cfg = self.cfg
-        tier = normalize_tier(tier)
         # refuse doomed work early: a spent deadline budget raises here
         # (504 at the front door) instead of burning a queue slot and a
         # device dispatch on a client that already gave up — the
@@ -147,15 +190,9 @@ class EcReadDispatcher:
         remaining_s = faultpolicy.check_remaining("ec read admission")
         if self.tiering is not None:
             self.tiering.note_read(vid, tier)
-        if not cfg.enabled:
-            # dispatcher disabled = the pre-batching per-read behavior,
-            # device reconstruct included: an idle device on a resident
-            # volume should still serve width-1 reads
+        if not cfg.enabled or not self.store.ec_volume_is_resident(vid):
             self._route("native", origin)
-            return await self._read_native(vid, nid, cookie, use_device=True)
-        if not self.store.ec_volume_is_resident(vid):
-            self._route("native", origin)
-            return await self._read_native(vid, nid, cookie)
+            return None
         if cfg.qos and self.qos.admit(
             tier, len(self.coalescer), cfg.max_inflight,
             remaining_s=remaining_s,
@@ -165,11 +202,11 @@ class EcReadDispatcher:
             # would time out inside — reasons are counted per tier in
             # the qos_shed series by admit() itself
             self._route("native", origin)
-            return await self._read_native(vid, nid, cookie)
+            return None
         loop = asyncio.get_running_loop()
         req = ReadRequest(
             vid, nid, cookie, loop.create_future(), loop.time(),
-            obs_ctx=obs.current(), tier=tier,
+            obs_ctx=obs_ctx, tier=tier,
         )
         if not self.coalescer.offer(req):
             # saturated: shed to the native path rather than queue without
@@ -186,7 +223,7 @@ class EcReadDispatcher:
             if cfg.qos:
                 self.qos.saturated(tier)
             self._route("native", origin)
-            return await self._read_native(vid, nid, cookie)
+            return None
         if cfg.qos:
             # commit the admission (admitted counter, breaker success,
             # tier queue gauge).  Guarded so -ec.qos.disable really
@@ -199,23 +236,7 @@ class EcReadDispatcher:
         self._route("batched", origin)
         stats.VOLUME_SERVER_EC_QUEUE_DEPTH.set(len(self.coalescer))
         self._maybe_spawn()
-        t_resident = time.perf_counter()
-        try:
-            return await req.future
-        finally:
-            # the request's WHOLE dispatcher residency, enqueue ->
-            # waiter resume, as a low-priority queue_wait span
-            # (observe=False: the admission-window histogram sample is
-            # the drain loop's).  The batch stage spans outrank it in
-            # critical-path attribution, so all it claims is the slice
-            # nothing else covers — chiefly the future-resume gap where
-            # the batch is done but the event loop hasn't scheduled
-            # this coroutine yet, which under load is milliseconds a
-            # tail forensics answer must not call untraced.
-            obs.record_span(
-                req.obs_ctx, "queue_wait", t_resident,
-                time.perf_counter() - t_resident, observe=False,
-            )
+        return req
 
     async def _read_native(
         self, vid: int, nid: int, cookie: int | None, use_device: bool = False
@@ -278,7 +299,11 @@ class EcReadDispatcher:
                     and cfg.max_wait_us > 0
                     and len(self.coalescer) < cfg.max_batch
                 ):
-                    await asyncio.sleep(cfg.max_wait_s)
+                    # on the profiler's timeline the wait is the lane's
+                    # own choice, told apart from a loop too busy to
+                    # run the lane
+                    with obs.interval("batch_window"):
+                        await asyncio.sleep(cfg.max_wait_s)
                 first = False
                 now = asyncio.get_running_loop().time()
                 now_pc = time.perf_counter()
@@ -320,7 +345,7 @@ class EcReadDispatcher:
         )
         with obs.stage_sink() as sink:
             try:
-                with devledger.workload(wl), obs.span(
+                with devledger.workload(wl), obs.await_span(
                     "batch_dispatch", needles=len(items), vid=vid
                 ):
                     results = await asyncio.to_thread(
@@ -332,23 +357,27 @@ class EcReadDispatcher:
                     )
             except Exception as e:  # noqa: BLE001 — volume-level failure
                 results = [e] * len(items)
-        # feed the deadline estimator: per-needle service time of THIS
-        # batch (wall across the store call / width)
-        self.qos.observe_service(
-            (time.perf_counter() - t0) / max(1, len(items))
-        )
-        for r in items:
-            if r.obs_ctx is None:
-                continue
-            for stage, (dur, calls, ann) in sink.items():
-                obs.record_span(
-                    r.obs_ctx, stage, t0, dur, observe=False,
-                    annotations={"calls": calls, **ann},
-                )
-        for r, res in zip(items, results):
-            if r.future.done():  # client went away mid-batch
-                continue
-            if isinstance(res, Exception):
-                r.future.set_exception(res)
-            else:
-                r.future.set_result(res)
+        # what the loop does once the worker is back, before any waiter
+        # can resume: one stage of its own (histogram + timeline; no
+        # trace is current in a drain lane)
+        with obs.span("batch_resolve", needles=len(items)):
+            # feed the deadline estimator: per-needle service time of
+            # THIS batch (wall across the store call / width)
+            self.qos.observe_service(
+                (time.perf_counter() - t0) / max(1, len(items))
+            )
+            for r in items:
+                if r.obs_ctx is None:
+                    continue
+                for stage, (dur, calls, ann) in sink.items():
+                    obs.record_span(
+                        r.obs_ctx, stage, t0, dur, observe=False,
+                        annotations={"calls": calls, **ann},
+                    )
+            for r, res in zip(items, results):
+                if r.future.done():  # client went away mid-batch
+                    continue
+                if isinstance(res, Exception):
+                    r.future.set_exception(res)
+                else:
+                    r.future.set_result(res)

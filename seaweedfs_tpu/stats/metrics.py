@@ -317,6 +317,10 @@ TRACE_STAGES = (
     "bulk_read",         # bulk EC pipeline reader leg (stripe preads)
     "bulk_device",       # bulk EC pipeline codec leg (stage+H2D+kernel+D2H)
     "bulk_write",        # bulk EC pipeline writer leg (shard writes/compare)
+    "get_admit",         # dispatcher admission of a read, up to the queue
+    "batch_resolve",     # loop side of a finished batch: replay, futures
+    "needle_assemble",   # pieces joined + parsed + CRC-checked, per batch
+    "response_write",    # headers, range and the body the handler writes
 )
 # the FIXED bucket ladder the heartbeat stage digests ride on: volume
 # servers ship per-bucket count deltas over exactly these edges (+Inf
@@ -446,11 +450,20 @@ VOLUME_SERVER_EC_BULK_SECONDS = Counter(
     ["pipeline", "leg"],
     registry=REGISTRY,
 )
-VOLUME_SERVER_EC_BULK_BYTES = Counter(
-    "SeaweedFS_volumeServer_ec_bulk_bytes",
-    "Useful input bytes processed by the bulk EC pipelines (encode: .dat "
-    "bytes; rebuild/verify: survivor/data shard bytes read).",
-    ["pipeline"],
+# the device leg told apart at the boundaries the host crosses, per
+# batch as it runs (Codec._device_leg_tagged): on a device backend the
+# four parts of a pipeline sum to its ec_bulk_seconds{leg="device"}; a
+# CPU codec has no parts and leaves them at zero
+EC_BULK_CODEC_PARTS = ("stage", "enqueue", "fetch", "unstack")
+VOLUME_SERVER_EC_BULK_CODEC_SECONDS = Counter(
+    "SeaweedFS_volumeServer_ec_bulk_codec_seconds",
+    "Cumulative seconds of the bulk EC pipelines' device leg by part, "
+    "each named for what the host waits on (stage = the batch laid out "
+    "in one flat host buffer, enqueue = device_put + the kernel call, "
+    "which both return before the device is done, fetch = the blocking "
+    "copy back: H2D, kernel and D2H end inside it, unstack = the layout "
+    "undone); zero under a CPU codec.",
+    ["pipeline", "part"],
     registry=REGISTRY,
 )
 VOLUME_SERVER_EC_BULK_BATCHES = Counter(
@@ -470,7 +483,8 @@ VOLUME_SERVER_EC_BULK_OVERLAP_FRACTION = Gauge(
 for _p in ("encode", "rebuild", "verify"):
     for _leg in ("read", "device", "write"):
         VOLUME_SERVER_EC_BULK_SECONDS.labels(pipeline=_p, leg=_leg)
-    VOLUME_SERVER_EC_BULK_BYTES.labels(pipeline=_p)
+    for _part in EC_BULK_CODEC_PARTS:
+        VOLUME_SERVER_EC_BULK_CODEC_SECONDS.labels(pipeline=_p, part=_part)
     VOLUME_SERVER_EC_BULK_BATCHES.labels(pipeline=_p)
     VOLUME_SERVER_EC_BULK_OVERLAP_FRACTION.labels(pipeline=_p)
 

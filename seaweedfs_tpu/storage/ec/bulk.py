@@ -36,7 +36,10 @@ proof (the fsync tail follows the last write by definition, so it is
 excluded from the window on both sides of the claim) —
 the per-pipeline ``SeaweedFS_volumeServer_ec_bulk_*`` series and the
 ``bulk_read`` / ``bulk_device`` / ``bulk_write`` trace stages publish
-the same decomposition.
+the same decomposition; the device leg's own four parts (stage /
+enqueue / fetch / unstack, ``ec_bulk_codec_seconds``) are counted per
+batch as it runs, and while a profiler capture is live every batch's
+sections are events on their threads' lines.
 """
 from __future__ import annotations
 
@@ -51,7 +54,9 @@ from dataclasses import dataclass
 import numpy as np
 
 from ...obs import devledger
+from ...obs import trace as obs_trace
 from ...ops import rs
+from ...stats import metrics as _metrics
 from .layout import DATA_SHARDS, LARGE_BLOCK_SIZE
 
 # Per-shard stride fed to the codec in one device call.  4MB x 10 shards =
@@ -144,6 +149,7 @@ class Codec:
         backend: str,
         threaded: bool = False,
         workload: str = "bulk",
+        pipeline: str = "encode",
     ):
         self.backend = rs.resolve_backend(backend)
         self.matrix = np.asarray(matrix, dtype=np.uint8)
@@ -155,6 +161,15 @@ class Codec:
         # rides as an attribute (encode="bulk", rebuild="repair",
         # verify="scrub" — encoder.py sets it per pipeline)
         self.workload = workload
+        # the device leg's four parts, counted per batch under the
+        # pipeline's name (ec_bulk_codec_seconds): staging, enqueue,
+        # fetch, unstack
+        self._part_seconds = [
+            _metrics.VOLUME_SERVER_EC_BULK_CODEC_SECONDS.labels(
+                pipeline=pipeline, part=part
+            )
+            for part in _metrics.EC_BULK_CODEC_PARTS
+        ]
         self._pool = None
         if self.device:
             from ...ops import rs_tpu
@@ -206,52 +221,73 @@ class Codec:
         return parity
 
     def _device_leg_tagged(self, shards: np.ndarray) -> np.ndarray:
+        """The leg in four parts, each an event of a profiler capture and
+        a term of ec_bulk_codec_seconds, named for what the HOST waits on (the
+        device trace has the kernel's own time; nothing here
+        synchronises to tell them apart): `bulk_stage` lays the batch
+        out in one flat host buffer, `bulk_enqueue` is device_put plus
+        the kernel call (both return before the device is done),
+        `bulk_fetch` the blocking copy back — where the host waits for
+        H2D, kernel and D2H to finish — and `bulk_unstack` the layout
+        undone.  The boundaries are shared, so the parts sum to the leg."""
         import jax
 
         groups = self._tpu.BLOCKDIAG_GROUPS
         k, b = shards.shape
+        # block-diagonal fast path: host stages segment-stacked rows
+        # (free — same bytes) and the MXU runs with a full M dimension
+        # (~152 vs ~123 GB/s, see ops/rs_tpu.py header)
+        blockdiag = self.backend == "pallas" and b % (groups * 128) == 0
+        clock = time.perf_counter
         # the with-block tags the dispatch IN the leg thread — the pool
         # worker never inherits the submitter's ledger context (GL116's
         # lexical-tagging contract anchors here, not in _device_leg)
         with devledger.workload(self.workload):
-            if self.backend == "pallas" and b % (groups * 128) == 0:
-                # block-diagonal fast path: host stages segment-stacked
-                # rows (free — same bytes) and the MXU runs with a full M
-                # dimension (~152 vs ~123 GB/s, see ops/rs_tpu.py header)
-                stacked = np.ascontiguousarray(
-                    self._tpu.stack_segments(shards)
-                )
-                x = jax.device_put(stacked.reshape(-1))
-                out = self._tpu.apply_matrix_device_flat(
-                    self._a_blk,
-                    x,
-                    k=groups * k,
-                    m=groups * self.rows,
-                    tile=self._tpu.BLOCKDIAG_TILE,
-                    interpret=self._interpret,
-                )
-                seg = b // groups
-                parity = self._tpu.unstack_segments(
-                    # graftlint: allow(device-sync): the codec worker's
-                    # own D2H — fetched on the dedicated device leg,
-                    # timed busy_s
-                    np.asarray(out).reshape(groups * self.rows, seg),
-                    self.rows,
-                )
-            else:
-                x = jax.device_put(
-                    np.ascontiguousarray(shards).reshape(-1)
-                )
-                out = self._tpu.apply_matrix_device_flat(
-                    self._a_bm,
-                    x,
-                    k=k,
-                    m=self.rows,
-                    kernel=self.backend,
-                    interpret=self._interpret,
-                )
-                # graftlint: allow(device-sync): codec-leg D2H (see above)
-                parity = np.asarray(out).reshape(self.rows, b)
+            t0 = clock()
+            with obs_trace.event("bulk_stage", bytes=int(shards.nbytes)):
+                staged = np.ascontiguousarray(
+                    self._tpu.stack_segments(shards) if blockdiag else shards
+                ).reshape(-1)
+            t1 = clock()
+            with obs_trace.event("bulk_enqueue"):
+                x = jax.device_put(staged)
+                if blockdiag:
+                    out = self._tpu.apply_matrix_device_flat(
+                        self._a_blk,
+                        x,
+                        k=groups * k,
+                        m=groups * self.rows,
+                        tile=self._tpu.BLOCKDIAG_TILE,
+                        interpret=self._interpret,
+                    )
+                else:
+                    out = self._tpu.apply_matrix_device_flat(
+                        self._a_bm,
+                        x,
+                        k=k,
+                        m=self.rows,
+                        kernel=self.backend,
+                        interpret=self._interpret,
+                    )
+            t2 = clock()
+            with obs_trace.event("bulk_fetch"):
+                # graftlint: allow(device-sync): the codec worker's own
+                # D2H — fetched on the dedicated device leg, timed busy_s
+                flat = np.asarray(out)
+            t3 = clock()
+            with obs_trace.event("bulk_unstack"):
+                if blockdiag:
+                    parity = self._tpu.unstack_segments(
+                        flat.reshape(groups * self.rows, b // groups),
+                        self.rows,
+                    )
+                else:
+                    parity = flat.reshape(self.rows, b)
+            t4 = clock()
+        for counter, dur in zip(
+            self._part_seconds, (t1 - t0, t2 - t1, t3 - t2, t4 - t3)
+        ):
+            counter.inc(dur)
         return parity
 
     def resolve(self, handle) -> np.ndarray:
@@ -402,26 +438,48 @@ def run(
         "read_s": 0.0, "submit_s": 0.0, "wait_s": 0.0, "write_s": 0.0,
         "fsync_s": 0.0, "batches": 0, "overlap": overlap,
     }
+    # one run on the profiler's timeline: inside it an idle device is
+    # this pipeline's to explain, outside it nothing asked for the device
+    with obs_trace.interval("bulk_run", pipeline=name):
+        if overlap:
+            _run_overlapped(
+                name, plan, read_batch, codec, write_batch, pick, prefetch,
+                depth, t,
+            )
+        else:
+            _run_serial(plan, read_batch, codec, write_batch, pick, t)
+    t["device_busy_s"] = codec.busy_s
+    return t
+
+
+def _run_serial(plan, read_batch, codec, write_batch, pick, t: dict) -> None:
+    """Every leg on the caller thread, batch after batch."""
     clock = time.perf_counter
-
-    if not overlap:
-        for desc in plan:
-            t0 = clock()
+    for desc in plan:
+        t0 = clock()
+        with obs_trace.event("bulk_read"):
             payload = read_batch(desc)
-            t1 = clock()
-            handle = codec.submit(pick(payload))
-            t2 = clock()
-            result = codec.resolve(handle)
-            t3 = clock()
+        t1 = clock()
+        handle = codec.submit(pick(payload))
+        t2 = clock()
+        result = codec.resolve(handle)
+        t3 = clock()
+        with obs_trace.event("bulk_write"):
             write_batch(desc, payload, result)
-            t["read_s"] += t1 - t0
-            t["submit_s"] += t2 - t1
-            t["wait_s"] += t3 - t2
-            t["write_s"] += clock() - t3
-            t["batches"] += 1
-        t["device_busy_s"] = codec.busy_s
-        return t
+        t["read_s"] += t1 - t0
+        t["submit_s"] += t2 - t1
+        t["wait_s"] += t3 - t2
+        t["write_s"] += clock() - t3
+        t["batches"] += 1
 
+
+def _run_overlapped(
+    name: str, plan, read_batch, codec, write_batch, pick, prefetch: int,
+    depth: int, t: dict,
+) -> None:
+    """Reader and writer legs on their own threads around the caller's
+    submit / resolve loop."""
+    clock = time.perf_counter
     read_q: queue.Queue = queue.Queue(maxsize=max(1, prefetch))
     write_q: queue.Queue = queue.Queue(maxsize=max(1, prefetch))
     abort = threading.Event()
@@ -432,7 +490,8 @@ def run(
                 if abort.is_set():
                     return
                 r0 = clock()
-                payload = read_batch(desc)
+                with obs_trace.event("bulk_read"):
+                    payload = read_batch(desc)
                 t["read_s"] += clock() - r0
                 read_q.put((desc, payload))
         finally:
@@ -445,7 +504,8 @@ def run(
                 return
             desc, payload, result = item
             w0 = clock()
-            write_batch(desc, payload, result)
+            with obs_trace.event("bulk_write"):
+                write_batch(desc, payload, result)
             t["write_s"] += clock() - w0
 
     r_leg = _Leg(f"ec-bulk-{name}-read", reader)
@@ -512,18 +572,13 @@ def run(
         r_leg.join(timeout=5)
         w_leg.join(timeout=5)
         raise
-    t["device_busy_s"] = codec.busy_s
-    return t
 
 
-def publish(name: str, t: dict, input_bytes: int) -> None:
+def publish(name: str, t: dict) -> None:
     """Feed one finished run into the SeaweedFS_volumeServer_ec_bulk_*
     series and the bulk_read/bulk_device/bulk_write trace stages (the
     caller's active trace when the pipeline ran under a traced RPC, e.g.
     VolumeEcShardsGenerate).  Call after wall_s/fsync_s are filled."""
-    from ...obs import trace as obs_trace
-    from ...stats import metrics as _metrics
-
     wall = float(t.get("wall_s", 0.0))
     ctx = obs_trace.current()
     t0 = time.perf_counter() - wall
@@ -545,9 +600,6 @@ def publish(name: str, t: dict, input_bytes: int) -> None:
     )
     obs_trace.record_span(
         ctx, "bulk_write", t0, float(t.get("write_s", 0.0)), annotations=anns
-    )
-    _metrics.VOLUME_SERVER_EC_BULK_BYTES.labels(pipeline=name).inc(
-        max(0, int(input_bytes))
     )
     _metrics.VOLUME_SERVER_EC_BULK_BATCHES.labels(pipeline=name).inc(
         int(t.get("batches", 0))

@@ -123,7 +123,7 @@ def write_ec_files(
     use_overlap = cfg.overlap if overlap is None else bool(overlap)
     codec = bulk.Codec(
         rs.RSCodec().matrix[DATA_SHARDS:], backend, threaded=use_overlap,
-        workload="bulk",
+        workload="bulk", pipeline="encode",
     )
     _save_vif_from_superblock(dat_path, base_name)
 
@@ -160,7 +160,7 @@ def write_ec_files(
         for o in outputs:
             o.close()
     t["wall_s"] = time.perf_counter() - t_start
-    bulk.publish("encode", t, dat_size)
+    bulk.publish("encode", t)
     if stats is not None:
         stats.update(t)
     return dat_size
@@ -203,7 +203,10 @@ def rebuild_ec_files(
     stride = _resolve_stride(stride)
     cfg = bulk.DEFAULT
     use_overlap = cfg.overlap if overlap is None else bool(overlap)
-    codec = bulk.Codec(rmat, backend, threaded=use_overlap, workload="repair")
+    codec = bulk.Codec(
+        rmat, backend, threaded=use_overlap, workload="repair",
+        pipeline="rebuild",
+    )
 
     shard_size = os.path.getsize(base_name + to_ext(present[0]))
     inputs = {i: open(base_name + to_ext(i), "rb") for i in use}
@@ -236,7 +239,7 @@ def rebuild_ec_files(
     # head, so a missing .vif can be restored exactly like encode does
     _save_vif_from_superblock(base_name + to_ext(0), base_name)
     t["wall_s"] = time.perf_counter() - t_start
-    bulk.publish("rebuild", t, shard_size * len(use))
+    bulk.publish("rebuild", t)
     if stats is not None:
         stats.update(t)
     return missing
@@ -268,7 +271,7 @@ def verify_ec_files(
     use_overlap = cfg.overlap if overlap is None else bool(overlap)
     codec = bulk.Codec(
         rs.RSCodec().matrix[DATA_SHARDS:], backend, threaded=use_overlap,
-        workload="scrub",
+        workload="scrub", pipeline="verify",
     )
     mism = np.zeros(TOTAL_SHARDS - DATA_SHARDS, dtype=np.int64)
     handles = [open(p, "rb") for p in paths]
@@ -300,7 +303,7 @@ def verify_ec_files(
         for h in handles:
             h.close()
     t["wall_s"] = time.perf_counter() - t_start
-    bulk.publish("verify", t, shard_size * DATA_SHARDS)
+    bulk.publish("verify", t)
     if stats is not None:
         stats.update(t)
     return [int(v) for v in mism], shard_size

@@ -652,49 +652,58 @@ class EcVolume:
                 recon = None
 
         results: list[Needle | Exception] = []
-        for plan in plans:
-            if isinstance(plan, Exception):
-                results.append(plan)
-                continue
-            nid, parts = plan
-            try:
-                pieces: list = []
-                for p in parts:
-                    if p[0] == "local":
-                        _, sid, off, size = p
-                        staged = self._host_tier_read(sid, off, size)
-                        if staged is not None and len(staged) == size:
-                            pieces.append(staged)
-                            continue
-                        with obs_trace.span(
-                            "shard_read", shard=sid, bytes=size
-                        ):
-                            pieces.append(self.shards[sid].read_at(off, size))
-                    else:
-                        i = p[1]
-                        if recon is not None:
-                            pieces.append(recon[i])
-                        else:
-                            sid, off, size = requests[i]
-                            pieces.append(self._read_shard_interval(
-                                sid, off, size, remote_read, backend
-                            ))
-                # zero_copy: the parse keeps `data` a memoryview over the
-                # single source buffer (or the one join for multi-interval
-                # needles) instead of materializing bytes twice — the
-                # response writer streams it straight out
-                raw = pieces[0] if len(pieces) == 1 else b"".join(pieces)
-                n = Needle.from_bytes(
-                    raw, self.version, copy=not zero_copy
-                )
-                if n.id != nid:
-                    raise NeedleNotFound(
-                        f"ec batch read got needle {n.id:x}, expected {nid:x}"
-                    )
-                results.append(n)
-            except Exception as e:  # isolate per-needle failures
-                results.append(e)
+        # every needle of the batch put together from its pieces and
+        # parsed (the CRC check is in the parse); local preads are
+        # shard_read's, nested inside
+        with obs_trace.span("needle_assemble", needles=len(plans)):
+            for plan in plans:
+                if isinstance(plan, Exception):
+                    results.append(plan)
+                    continue
+                try:
+                    results.append(self._assemble_needle(
+                        *plan, requests, recon, remote_read, backend,
+                        zero_copy,
+                    ))
+                except Exception as e:  # isolate per-needle failures
+                    results.append(e)
         return results
+
+    def _assemble_needle(
+        self, nid: int, parts: list, requests: list, recon, remote_read,
+        backend: str, zero_copy: bool,
+    ) -> Needle:
+        """One needle of a batch from its planned pieces: local preads,
+        the batch's reconstructed intervals (`recon`), or where there
+        are none the per-interval host path."""
+        pieces: list = []
+        for p in parts:
+            if p[0] == "local":
+                _, sid, off, size = p
+                staged = self._host_tier_read(sid, off, size)
+                if staged is not None and len(staged) == size:
+                    pieces.append(staged)
+                    continue
+                with obs_trace.span("shard_read", shard=sid, bytes=size):
+                    pieces.append(self.shards[sid].read_at(off, size))
+            elif recon is not None:
+                pieces.append(recon[p[1]])
+            else:
+                sid, off, size = requests[p[1]]
+                pieces.append(self._read_shard_interval(
+                    sid, off, size, remote_read, backend
+                ))
+        # zero_copy: the parse keeps `data` a memoryview over the single
+        # source buffer (or the one join for multi-interval needles)
+        # instead of materializing bytes twice — the response writer
+        # streams it straight out
+        raw = pieces[0] if len(pieces) == 1 else b"".join(pieces)
+        n = Needle.from_bytes(raw, self.version, copy=not zero_copy)
+        if n.id != nid:
+            raise NeedleNotFound(
+                f"ec batch read got needle {n.id:x}, expected {nid:x}"
+            )
+        return n
 
     def read_needle(
         self,

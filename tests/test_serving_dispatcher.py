@@ -301,3 +301,36 @@ def test_dispatch_metrics_observed():
         assert stats.VOLUME_SERVER_EC_BATCH_INFLIGHT._value.get() == 0
 
     run(go())
+
+
+def test_admission_is_a_stage_and_the_batch_stages_stay_the_requests():
+    """`get_admit` times the synchronous admission as a child of the
+    request; the batch's stages, replayed from the sink, hang off the
+    request's root too, not off the admission that queued it; and the
+    native route (dispatcher disabled) is admitted under the same
+    stage."""
+    from seaweedfs_tpu import obs
+
+    class SpanningStore(FakeStore):
+        def read_ec_needles_batch(self, vid, requests, *a, **kw):
+            with obs.span("device_execute", requests=len(requests)):
+                return super().read_ec_needles_batch(vid, requests, *a, **kw)
+
+    async def go(disp):
+        trace, token = obs.start_trace("GET /7,01", "volume")
+        try:
+            assert await disp.read(7, 1, None) == b"needle-7-1"
+        finally:
+            obs.finish_trace(trace, token, 200)
+        return trace
+
+    trace = run(go(make(SpanningStore())))
+    spans = {sp.name: sp for sp in trace.spans}
+    assert {"get_admit", "queue_wait", "batch_dispatch",
+            "device_execute"} <= set(spans)
+    for name in ("get_admit", "batch_dispatch", "device_execute"):
+        assert spans[name].parent_id == trace.root_id, name
+    store = SpanningStore()
+    trace = run(go(make(store, enabled=False)))
+    assert store.native_calls == [1]
+    assert [sp.name for sp in trace.spans] == ["get_admit"]
