@@ -60,8 +60,12 @@ already sits AT its documented ~120 GB/s MXU roof):
     ~1229 -> measured 0.656 ms / 96MB = ~152 GB/s.  The catch: inputs
     must arrive segment-stacked ([g*k, B/g]); restacking ON DEVICE costs
     more than the win (byte transposes: 58 GB/s flat-to-flat), so the
-    HOST stages the layout (free — the encode pipeline writes the same
-    bytes either way).  apply_matrix_blockdiag below.
+    HOST stages the layout.  Not free: one copy of the batch per call,
+    measured on the v5e's host at 6.7 ms of a 28.6 ms rebuild batch
+    (40 MiB) and 1.6 ms of a 9.4 ms encode batch (10 MiB) into a kept
+    buffer — and 47 ms / 2.5 ms into a new array every batch, which is
+    what it cost until storage/ec/bulk.py pooled it (PERF.md, PR 24 and
+    PR 25).  apply_matrix_blockdiag below.
   * g=8 regresses (95 GB/s): longer contraction padding + VMEM pressure.
   * Feeding the flat layout via a 3-D BlockSpec block (gather inside the
     kernel) is rejected by Mosaic (compile-helper 500) — dead end, like
@@ -339,15 +343,23 @@ def _prepared_blockdiag(matrix_bytes: bytes, m: int, k: int, groups: int):
     )
 
 
-def stack_segments(shards: np.ndarray, groups: int = BLOCKDIAG_GROUPS) -> np.ndarray:
+def stack_segments(
+    shards: np.ndarray,
+    groups: int = BLOCKDIAG_GROUPS,
+    out: np.ndarray | None = None,
+) -> np.ndarray:
     """[k, B] -> [groups*k, B/groups]: segment g of every shard becomes
-    rows g*k..g*k+k-1 (the host-side staging that makes block-diagonal
-    free — same bytes, different row order)."""
+    rows g*k..g*k+k-1 (the host-side staging block-diagonal needs — same
+    bytes, different row order, so one copy of the batch).  With `out`,
+    a C-contiguous buffer of the batch's k*B bytes in any shape, the
+    copy lands there and no array is allocated."""
     k, b = shards.shape
     seg = b // groups
-    return (
-        shards.reshape(k, groups, seg).transpose(1, 0, 2).reshape(groups * k, seg)
-    )
+    stacked = shards.reshape(k, groups, seg).transpose(1, 0, 2)
+    if out is None:
+        return stacked.reshape(groups * k, seg)
+    np.copyto(out.reshape(groups, k, seg), stacked)
+    return out.reshape(groups * k, seg)
 
 
 def unstack_segments(out: np.ndarray, m: int, groups: int = BLOCKDIAG_GROUPS) -> np.ndarray:

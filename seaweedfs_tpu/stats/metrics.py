@@ -466,6 +466,19 @@ VOLUME_SERVER_EC_BULK_CODEC_SECONDS = Counter(
     ["pipeline", "part"],
     registry=REGISTRY,
 )
+# every batch-sized host buffer the pipelines' pool hands out (the reader
+# leg's payload, the codec worker's staging buffer): "fresh" is a new
+# allocation whose pages fault on first touch, "reused" a kept buffer
+EC_BULK_PIPELINES = ("encode", "rebuild", "verify")
+VOLUME_SERVER_EC_BULK_BUFFERS = Counter(
+    "SeaweedFS_volumeServer_ec_bulk_buffers",
+    "Batch-sized host buffers handed out by the bulk EC pipelines' pool "
+    "(storage/ec/bulk.py POOL), by where they came from: reused = a "
+    "kept buffer whose pages were touched before, fresh = a new "
+    "allocation (an empty pool, or a batch larger than what was kept).",
+    ["pipeline", "source"],
+    registry=REGISTRY,
+)
 VOLUME_SERVER_EC_BULK_BATCHES = Counter(
     "SeaweedFS_volumeServer_ec_bulk_batches",
     "Stripe batches pushed through the bulk EC pipelines' codec leg.",
@@ -480,9 +493,11 @@ VOLUME_SERVER_EC_BULK_OVERLAP_FRACTION = Gauge(
     ["pipeline"],
     registry=REGISTRY,
 )
-for _p in ("encode", "rebuild", "verify"):
+for _p in EC_BULK_PIPELINES:
     for _leg in ("read", "device", "write"):
         VOLUME_SERVER_EC_BULK_SECONDS.labels(pipeline=_p, leg=_leg)
+    for _source in ("reused", "fresh"):
+        VOLUME_SERVER_EC_BULK_BUFFERS.labels(pipeline=_p, source=_source)
     for _part in EC_BULK_CODEC_PARTS:
         VOLUME_SERVER_EC_BULK_CODEC_SECONDS.labels(pipeline=_p, part=_part)
     VOLUME_SERVER_EC_BULK_BATCHES.labels(pipeline=_p)

@@ -16,9 +16,18 @@ bounded queues, so wall-clock trends toward max(read, device, write):
 
 Reads use one vectored ``os.preadv`` per stripe where the platform has
 it and the stripe's rows are contiguous on disk (full-block batches),
-instead of DATA_SHARDS serial preads.  All staging buffers are
-``np.empty`` with tail-only zeroing — a full memset per stripe was ~10%
-of the read leg at device speeds (same fix DeviceShardCache.put got).
+instead of DATA_SHARDS serial preads; every other row is one ``preadv``
+straight into its row, no intermediate ``bytes``.
+
+The [k, stride]-sized host buffers of a batch — the reader leg's payload
+and the codec worker's staging buffer — come from ``POOL`` and go back
+to it: a rebuild batch's 40 MiB is above glibc's largest mmap threshold
+(32 MiB), so a new array a batch is a new mapping a batch, every page
+faulted on first touch (47 ms of a 68 ms batch on the chip host, PERF.md
+PR 24/25).  A recycled buffer holds the previous batch's bytes, so what
+keeps old bytes out of parity is the readers' zero fill past what was
+read (``_read_row``, ``_zero_tail``) — never a memset of the whole
+buffer, which was ~10% of the read leg at device speeds.
 
 Stats contract (the dict ``run()`` fills, same keys for all three
 pipelines):
@@ -65,12 +74,15 @@ from .layout import DATA_SHARDS, LARGE_BLOCK_SIZE
 DEFAULT_STRIDE = 4 * 1024 * 1024
 # In-flight codec batches: the caller may run this far ahead of the codec
 # worker before blocking on a resolve.  3 keeps one batch staging, one on
-# the wire, one landing.  NOTE the overlapped pipeline's true peak host
-# footprint is ~(2*prefetch + depth + 2) batches — the stripe queue, the
+# the wire, one landing.  NOTE the overlapped pipeline's peak host
+# footprint is 2*prefetch + depth + 2 payloads — the stripe queue, the
 # pending deque, the result queue (payloads ride along for the writer),
-# and one in each leg's hands — ~10 batches (~400MB at the default 4MB
-# stride) vs the serial mode's 1; size stride/prefetch down together on
-# memory-tight volume servers.
+# and one in each leg's hands — plus the codec worker's one staging
+# buffer: 12 buffers (480MB of [10, 4MB] rebuild batches at the default
+# stride) vs the serial mode's 2.  POOL keeps that many after the run
+# (see BufferPool), so the footprint is the process's from its first
+# bulk verb on; size stride/prefetch down together on memory-tight
+# volume servers.
 PIPELINE_DEPTH = 3
 
 # test seams / portability: the slow-IO fixtures in tests/test_ec_bulk.py
@@ -129,6 +141,59 @@ def configure(cfg: BulkConfig) -> None:
     DEFAULT = cfg.validated()
 
 
+class BufferPool:
+    """The batch-sized host buffers of the bulk pipelines, recycled: flat
+    uint8 arrays whose pages have been touched, kept between batches and
+    between verbs for the life of the process.
+
+    take() never waits: an empty pool, or a kept buffer smaller than the
+    batch, is a fresh allocation (the small one is let go, so the pool
+    settles on the largest batch the process runs).  What bounds the
+    buffers in flight is the pipeline (its queues hold 2*prefetch +
+    depth + 2 payloads and one staging buffer at most); `keep`, which
+    run() sets from those same numbers, bounds what stays here
+    afterwards.  A buffer that never comes back — a batch dropped on the
+    abort path — is the garbage collector's: nothing waits for it.
+    deque.pop / append are the only shared steps, one each per leg per
+    batch; neither takes a lock of this module's."""
+
+    def __init__(self) -> None:
+        self._free: deque = deque()
+        self.keep = 0
+        self._handed = {
+            (pipeline, source): _metrics.VOLUME_SERVER_EC_BULK_BUFFERS.labels(
+                pipeline=pipeline, source=source
+            )
+            for pipeline in _metrics.EC_BULK_PIPELINES
+            for source in ("reused", "fresh")
+        }
+
+    def take(self, pipeline: str, rows: int, width: int) -> np.ndarray:
+        """A C-contiguous [rows, width] uint8 view of a pooled buffer,
+        holding whatever its last user left there."""
+        need = rows * width
+        try:
+            flat = self._free.pop()
+        except IndexError:
+            flat = None
+        source = "reused"
+        if flat is None or flat.size < need:
+            flat = np.empty(need, dtype=np.uint8)
+            source = "fresh"
+        self._handed[pipeline, source].inc()
+        return flat[:need].reshape(rows, width)
+
+    def give(self, batch: np.ndarray) -> None:
+        """Return what take() handed out, once nothing reads or writes
+        it any more (`batch.base` is the pooled buffer: numpy gives a
+        view of a view its owner as base)."""
+        if len(self._free) < self.keep:
+            self._free.append(batch.base)
+
+
+POOL = BufferPool()
+
+
 class Codec:
     """Wraps RSCodec so the matrix-multiply leg can run pipelined.
     submit() returns an opaque handle; resolve() turns it into a numpy
@@ -164,6 +229,7 @@ class Codec:
         # the device leg's four parts, counted per batch under the
         # pipeline's name (ec_bulk_codec_seconds): staging, enqueue,
         # fetch, unstack
+        self.pipeline = pipeline
         self._part_seconds = [
             _metrics.VOLUME_SERVER_EC_BULK_CODEC_SECONDS.labels(
                 pipeline=pipeline, part=part
@@ -225,8 +291,8 @@ class Codec:
         a term of ec_bulk_codec_seconds, named for what the HOST waits on (the
         device trace has the kernel's own time; nothing here
         synchronises to tell them apart): `bulk_stage` lays the batch
-        out in one flat host buffer, `bulk_enqueue` is device_put plus
-        the kernel call (both return before the device is done),
+        out in one flat pooled host buffer, `bulk_enqueue` is device_put
+        plus the kernel call (both return before the device is done),
         `bulk_fetch` the blocking copy back — where the host waits for
         H2D, kernel and D2H to finish — and `bulk_unstack` the layout
         undone.  The boundaries are shared, so the parts sum to the leg."""
@@ -234,9 +300,10 @@ class Codec:
 
         groups = self._tpu.BLOCKDIAG_GROUPS
         k, b = shards.shape
-        # block-diagonal fast path: host stages segment-stacked rows
-        # (free — same bytes) and the MXU runs with a full M dimension
-        # (~152 vs ~123 GB/s, see ops/rs_tpu.py header)
+        # block-diagonal fast path: host stages segment-stacked rows (one
+        # copy of the batch at memory speed into a kept buffer) and the
+        # MXU runs with a full M dimension (~152 vs ~123 GB/s, see
+        # ops/rs_tpu.py header)
         blockdiag = self.backend == "pallas" and b % (groups * 128) == 0
         clock = time.perf_counter
         # the with-block tags the dispatch IN the leg thread — the pool
@@ -245,12 +312,21 @@ class Codec:
         with devledger.workload(self.workload):
             t0 = clock()
             with obs_trace.event("bulk_stage", bytes=int(shards.nbytes)):
-                staged = np.ascontiguousarray(
-                    self._tpu.stack_segments(shards) if blockdiag else shards
-                ).reshape(-1)
+                # device_put reads this memory until the transfer is done
+                # (the CPU backend may alias it for the life of `x`), so
+                # the buffer goes back to the pool only below, after the
+                # blocking fetch of the program that consumed `x`.  One
+                # worker runs one batch at a time, so one staging buffer
+                # circulates; a leg that staged batch n+1 while it
+                # fetched n would hold two, by the same take and give.
+                staged = POOL.take(self.pipeline, k, b)
+                if blockdiag:
+                    self._tpu.stack_segments(shards, out=staged)
+                else:
+                    np.copyto(staged, shards)
             t1 = clock()
             with obs_trace.event("bulk_enqueue"):
-                x = jax.device_put(staged)
+                x = jax.device_put(staged.reshape(-1))
                 if blockdiag:
                     out = self._tpu.apply_matrix_device_flat(
                         self._a_blk,
@@ -274,6 +350,7 @@ class Codec:
                 # graftlint: allow(device-sync): the codec worker's own
                 # D2H — fetched on the dedicated device leg, timed busy_s
                 flat = np.asarray(out)
+            POOL.give(staged)
             t3 = clock()
             with obs_trace.event("bulk_unstack"):
                 if blockdiag:
@@ -305,7 +382,8 @@ class Codec:
 
 def _zero_tail(out: np.ndarray, filled: int) -> None:
     """Zero every byte of a [rows, width] batch past the first `filled`
-    (row-major) — the tail-only half of the np.empty staging rule."""
+    (row-major): with _read_row, what keeps a recycled buffer's old
+    bytes out of a short batch."""
     rows, width = out.shape
     row, rem = divmod(filled, width)
     if rem:
@@ -315,19 +393,37 @@ def _zero_tail(out: np.ndarray, filled: int) -> None:
         out[row:] = 0
 
 
+def _read_row(fd: int, row: np.ndarray, n: int, off: int) -> None:
+    """Fill `row` with the file's bytes at [off, off+n) and zeros past
+    what was read (EOF, n < len(row)): read straight into the row where
+    the platform has preadv, no intermediate bytes."""
+    got = 0
+    if n > 0 and _preadv is not None:
+        got = _preadv(fd, [row[:n]], off)
+    elif n > 0:
+        buf = _pread(fd, n, off)
+        got = len(buf)
+        row[:got] = np.frombuffer(buf, dtype=np.uint8)
+    row[got:] = 0
+
+
 def read_stripe(
-    f, dat_size: int, row_start: int, block_size: int, stride_off: int, stride: int
+    f, dat_size: int, row_start: int, block_size: int, stride_off: int,
+    stride: int, out: np.ndarray | None = None,
 ) -> np.ndarray:
     """[DATA_SHARDS, stride] batch: shard i's bytes are the original volume
     at row_start + i*block_size + stride_off, zero-padded past EOF
-    (encodeDataOneBatch's zero-fill, ec_encoder.go:165-177).
+    (encodeDataOneBatch's zero-fill, ec_encoder.go:165-177).  Filled into
+    `out` (a pooled buffer: every byte of it is written) when given, else
+    into a new array (the ingest plane's reads, which keep theirs).
 
     Full-block batches (stride == block_size) cover one CONTIGUOUS byte
     range of the .dat — the rows are just a reshape — so a single
     vectored preadv scatters the whole stripe into the row buffers in one
     syscall.  Sub-block batches (stride < block_size) have strided row
-    offsets and fall back to one pread per row."""
-    out = np.empty((DATA_SHARDS, stride), dtype=np.uint8)
+    offsets and fall back to one read per row."""
+    if out is None:
+        out = np.empty((DATA_SHARDS, stride), dtype=np.uint8)
     fd = f.fileno()
     if _preadv is not None and stride == block_size and stride_off == 0:
         want = min(DATA_SHARDS * stride, max(0, dat_size - row_start))
@@ -339,28 +435,18 @@ def read_stripe(
         # whole stripe on the per-row path rather than resuming mid-iov
     for i in range(DATA_SHARDS):
         start = row_start + i * block_size + stride_off
-        n = min(stride, max(0, dat_size - start))
-        if n > 0:
-            buf = _pread(fd, n, start)
-            out[i, : len(buf)] = np.frombuffer(buf, dtype=np.uint8)
-            if len(buf) < stride:
-                out[i, len(buf) :] = 0
-        else:
-            out[i, :] = 0
+        _read_row(fd, out[i], min(stride, dat_size - start), start)
     return out
 
 
-def read_shard_rows(handles: dict, ids, n: int, off: int) -> np.ndarray:
-    """[len(ids), n] batch from per-shard FILES (rebuild/verify inputs):
-    row j is shard ids[j]'s bytes at [off, off+n), zero-padded on a short
-    read.  Separate files can't share a preadv, but each row is one
-    contiguous pread."""
-    out = np.empty((len(ids), n), dtype=np.uint8)
+def read_shard_rows(handles: dict, ids, off: int, out: np.ndarray) -> np.ndarray:
+    """Fill the [len(ids), n] batch `out` from per-shard FILES
+    (rebuild/verify inputs): row j is shard ids[j]'s bytes at
+    [off, off+n), zero-padded on a short read.  Separate files can't
+    share a preadv, but each row is one contiguous read."""
+    n = out.shape[1]
     for j, sid in enumerate(ids):
-        buf = _pread(handles[sid].fileno(), n, off)
-        out[j, : len(buf)] = np.frombuffer(buf, dtype=np.uint8)
-        if len(buf) < n:
-            out[j, len(buf) :] = 0
+        _read_row(handles[sid].fileno(), out[j], n, off)
     return out
 
 
@@ -429,10 +515,15 @@ def run(
     (device/CPU work lands on the codec's own worker), and
     `write_batch(desc, payload, result)` on the writer leg, in plan
     order.  With overlap disabled everything runs inline on the caller
-    thread — the serial baseline of the stats contract."""
+    thread — the serial baseline of the stats contract.
+
+    A payload that read_batch took from POOL is write_batch's to give
+    back when it has finished with it; POOL keeps as many buffers as
+    this run can have in flight (the PIPELINE_DEPTH note)."""
     cfg = DEFAULT
     overlap = cfg.overlap if overlap is None else bool(overlap)
     prefetch = cfg.prefetch if prefetch is None else prefetch
+    POOL.keep = 2 * max(1, prefetch) + depth + 3 if overlap else 2
     pick = to_codec if to_codec is not None else lambda payload: payload
     t = {
         "read_s": 0.0, "submit_s": 0.0, "wait_s": 0.0, "write_s": 0.0,

@@ -142,11 +142,15 @@ def write_ec_files(
 
             def read_batch(desc):
                 row_start, block_size, off, step = desc
-                return read_stripe(f, dat_size, row_start, block_size, off, step)
+                return read_stripe(
+                    f, dat_size, row_start, block_size, off, step,
+                    out=bulk.POOL.take("encode", DATA_SHARDS, step),
+                )
 
             def write_batch(desc, data, parity):
                 for i in range(DATA_SHARDS):
                     write_or_seek(outputs[i], data[i])
+                bulk.POOL.give(data)
                 for i in range(codec.rows):
                     write_or_seek(outputs[DATA_SHARDS + i], parity[i])
 
@@ -220,9 +224,12 @@ def rebuild_ec_files(
 
         def read_batch(desc):
             off, n = desc
-            return bulk.read_shard_rows(inputs, use, n, off)
+            return bulk.read_shard_rows(
+                inputs, use, off, bulk.POOL.take("rebuild", len(use), n)
+            )
 
         def write_batch(desc, payload, out):
+            bulk.POOL.give(payload)
             for j, shard_id in enumerate(missing):
                 write_or_seek(outputs[shard_id], out[j])
 
@@ -284,7 +291,10 @@ def verify_ec_files(
 
         def read_batch(desc):
             off, n = desc
-            return bulk.read_shard_rows(handles, range(TOTAL_SHARDS), n, off)
+            return bulk.read_shard_rows(
+                handles, range(TOTAL_SHARDS), off,
+                bulk.POOL.take("verify", TOTAL_SHARDS, n),
+            )
 
         def write_batch(desc, payload, parity):
             np.add(
@@ -292,6 +302,7 @@ def verify_ec_files(
                 (parity != payload[DATA_SHARDS:]).sum(axis=1),
                 out=mism,
             )
+            bulk.POOL.give(payload)
 
         t = bulk.run(
             "verify", plan, read_batch, codec, write_batch,

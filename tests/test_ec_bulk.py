@@ -329,6 +329,217 @@ class TestExecutorErrors:
             codec.shutdown()
 
 
+# ------------------------------------------------------ pooled buffers
+
+
+def _handed(pipeline):
+    """(reused, fresh): the buffers POOL has handed this pipeline."""
+    from seaweedfs_tpu.stats import metrics as m
+
+    return tuple(
+        m.VOLUME_SERVER_EC_BULK_BUFFERS.labels(
+            pipeline=pipeline, source=source
+        )._value.get()
+        for source in ("reused", "fresh")
+    )
+
+
+def _handed_since(pipeline, before):
+    return tuple(b - a for a, b in zip(before, _handed(pipeline)))
+
+
+class PoisonPool(bulk.BufferPool):
+    """Every buffer goes out all 0xFF, past the batch's view too: a byte
+    the reader or the staging copy does not write ends up in a file."""
+
+    def take(self, pipeline, rows, width):
+        batch = super().take(pipeline, rows, width)
+        batch.base[:] = 0xFF
+        return batch
+
+
+@pytest.fixture
+def pool(monkeypatch):
+    """A pool of the test's own in place of the process's."""
+    monkeypatch.setattr(bulk, "POOL", PoisonPool())
+    return bulk.POOL
+
+
+# one 8 KB-block row, then 1 KB-block rows of which the last ends 777
+# bytes into its first block: shards of 12 KB, so a 5 KB rebuild / verify
+# stride leaves a 2 KB last batch, and a 4 KB encode stride takes the
+# large row by sub-block reads and the small ones by one preadv each
+_LARGE, _SMALL = 8192, 1024
+_DAT_SIZE = 10 * _LARGE + 3 * 10 * _SMALL + 777
+_SHARD_SIZE = _LARGE + 4 * _SMALL
+
+
+def _host_codec_shards(payload):
+    """The 14 shard files of `payload` by the host codec over arrays of
+    this function's own (np.zeros, no pool)."""
+    codec = rs.RSCodec(backend="cpu")
+    shards = [[] for _ in range(14)]
+    for row_start, block in encoder._iter_rows(len(payload), _LARGE, _SMALL):
+        data = np.zeros((10, block), dtype=np.uint8)
+        for i in range(10):
+            chunk = payload[row_start + i * block:row_start + (i + 1) * block]
+            data[i, : len(chunk)] = chunk
+        parity = codec.apply_matrix(codec.matrix[10:], data)
+        for i in range(10):
+            shards[i].append(data[i].tobytes())
+        for i in range(4):
+            shards[10 + i].append(parity[i].tobytes())
+    return {i: b"".join(parts) for i, parts in enumerate(shards)}
+
+
+def _encode_short_tail(base, backend, overlap):
+    ec.write_ec_files(
+        base, backend=backend, stride=4096, large_block=_LARGE,
+        small_block=_SMALL, overlap=overlap, prefetch=2,
+    )
+
+
+class TestPooledBuffers:
+    @pytest.mark.parametrize("overlap", [True, False])
+    @pytest.mark.parametrize("backend", ["cpu", "pallas", "xla"])
+    def test_stale_bytes_stay_out_of_short_batches(
+        self, tmp_path, pool, backend, overlap
+    ):
+        base = str(tmp_path / "1")
+        want = _host_codec_shards(make_dat(base + ".dat", _DAT_SIZE, seed=25))
+        _encode_short_tail(base, backend, overlap)
+        assert shard_bytes(base) == want
+        for lost in (3, 11):
+            os.remove(base + to_ext(lost))
+        rebuilt = ec.rebuild_ec_files(
+            base, backend=backend, stride=5120, overlap=overlap, prefetch=2
+        )
+        assert rebuilt == [3, 11]
+        assert shard_bytes(base) == want
+        assert ec.verify_ec_files(
+            base, backend=backend, stride=5120, overlap=overlap, prefetch=2
+        ) == ([0, 0, 0, 0], _SHARD_SIZE)
+
+    @pytest.mark.parametrize("overlap", [True, False])
+    @pytest.mark.parametrize("backend", ["cpu", "xla"])
+    def test_fresh_up_to_the_bound_then_reused(
+        self, tmp_path, pool, backend, overlap
+    ):
+        """Two rebuild verbs of 24 batches each.  Overlapped, the
+        pipeline can hold 2*2 + 3 + 2 payloads and one staging buffer:
+        no more than that many are allocated over both verbs, however
+        the legs interleave.  Serial, one payload (and one staging
+        buffer) is allocated by the first verb and none by the second."""
+        base = str(tmp_path / "1")
+        make_dat(base + ".dat", _DAT_SIZE, seed=26)
+        _encode_short_tail(base, "cpu", overlap)
+        want = shard_bytes(base)
+        per_batch = 2 if backend == "xla" else 1  # payload (+ staging)
+        fresh_by_verb = []
+        for _verb in range(2):
+            os.remove(base + to_ext(5))
+            before = _handed("rebuild")
+            ec.rebuild_ec_files(
+                base, backend=backend, stride=512, overlap=overlap,
+                prefetch=2,
+            )
+            reused, fresh = _handed_since("rebuild", before)
+            assert reused + fresh == 24 * per_batch
+            fresh_by_verb.append(fresh)
+            assert len(pool._free) <= pool.keep
+            assert shard_bytes(base) == want
+        if overlap:
+            assert pool.keep == 2 * 2 + bulk.PIPELINE_DEPTH + 3
+            assert sum(fresh_by_verb) <= pool.keep
+        else:
+            assert pool.keep == 2
+            # the encode verb above left its payload buffer, 40 KB
+            assert fresh_by_verb == [per_batch - 1, 0]
+
+    @pytest.mark.parametrize("leg", ["reader", "writer"])
+    def test_failed_leg_ends_the_run_and_leaves_the_pool_usable(
+        self, tmp_path, pool, monkeypatch, leg
+    ):
+        base = str(tmp_path / "1")
+        want = _host_codec_shards(make_dat(base + ".dat", _DAT_SIZE, seed=27))
+        calls = {"n": 0}
+
+        def failing(real):
+            def wrapped(*args):
+                calls["n"] += 1
+                if calls["n"] == 6:
+                    raise OSError(f"boom-{leg}")
+                return real(*args)
+            return wrapped
+
+        with monkeypatch.context() as patch:
+            if leg == "reader":
+                patch.setattr(bulk, "_preadv", failing(bulk._preadv))
+            else:
+                patch.setattr(
+                    encoder, "write_or_seek", failing(bulk.write_or_seek)
+                )
+            t0 = time.monotonic()
+            with pytest.raises(OSError, match=f"boom-{leg}"):
+                _encode_short_tail(base, "cpu", True)
+            assert time.monotonic() - t0 < 10.0
+        _encode_short_tail(base, "cpu", True)
+        assert shard_bytes(base) == want
+        assert 0 < len(pool._free) <= pool.keep
+
+    @pytest.mark.parametrize("backend", ["pallas", "xla"])
+    def test_staging_buffer_is_kept_until_its_batch_is_fetched(
+        self, monkeypatch, backend
+    ):
+        """Six batches of distinct content queued on the worker at once:
+        the staging buffer goes back to the pool only after the blocking
+        fetch of the batch that staged it, and every parity is right."""
+        import contextlib
+
+        log = []
+
+        @contextlib.contextmanager
+        def logged_event(name, **_anns):
+            yield
+            log.append(name)
+
+        class LoggingPool(bulk.BufferPool):
+            def take(self, pipeline, rows, width):
+                log.append("take")
+                return super().take(pipeline, rows, width)
+
+            def give(self, batch):
+                log.append("give")
+                super().give(batch)
+
+        pool = LoggingPool()
+        pool.keep = 1
+        monkeypatch.setattr(bulk, "POOL", pool)
+        before = _handed("encode")
+        monkeypatch.setattr(bulk.obs_trace, "event", logged_event)
+        host = rs.RSCodec(backend="cpu")
+        matrix = host.matrix[10:]
+        rng = np.random.default_rng(28)
+        batches = [
+            rng.integers(0, 256, size=(10, 1024), dtype=np.uint8)
+            for _ in range(6)
+        ]
+        codec = bulk.Codec(matrix, backend, threaded=True)
+        try:
+            handles = [codec.submit(b) for b in batches]
+            for b, h in zip(batches, handles):
+                np.testing.assert_array_equal(
+                    codec.resolve(h), host.apply_matrix(matrix, b)
+                )
+        finally:
+            codec.shutdown()
+        assert log == [
+            "take", "bulk_stage", "bulk_enqueue", "bulk_fetch", "give",
+            "bulk_unstack",
+        ] * 6
+        assert _handed_since("encode", before) == (5, 1)
+
+
 # ------------------------------------------------- .vif + fsync satellite
 
 
