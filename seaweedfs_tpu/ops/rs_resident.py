@@ -689,6 +689,9 @@ class DeviceShardCache:
         # inline-compile behavior so direct callers are unaffected.
         self.shed_cold = True
         self._lock = threading.Lock()
+        # the staging buffer a TPU's puts share (see _put), and its turn
+        self._stage_lock = threading.Lock()
+        self._stage_buf: np.ndarray | None = None
         # vid -> "none" | "warming" | "done": whether an AOT warm plan
         # was started/finished for this volume (warm() maintains it)
         self._aot_states: dict[int, str] = {}
@@ -885,35 +888,97 @@ class DeviceShardCache:
         host = np.frombuffer(bytes(data), dtype=np.uint8) if isinstance(
             data, (bytes, bytearray, memoryview)
         ) else np.asarray(data, dtype=np.uint8)
-        # stage via np.empty + tail-only zeroing: np.zeros memsets the
-        # WHOLE padded buffer and then overwrites all but the tail — a
-        # redundant full-size host pass per shard when pinning a large
-        # volume.  A reused per-cache staging buffer would cut the
-        # allocation too, but the CPU PJRT client zero-copies aligned
-        # numpy arrays into jax Arrays, so reuse would alias (and
-        # corrupt) previously pinned shards; a fresh buffer per put is
-        # the safe form of the optimization (alloc is cheap, memset of
-        # gigabytes is not).  The padded buffer doubles as the blockdiag
-        # segment-stacked layout: its g segments are contiguous slices,
-        # staged by the host for free.
-        padded = np.empty(self._padded_len(host.size), dtype=np.uint8)
-        padded[: host.size] = host
-        padded[host.size :] = 0
+
+        def copy(out, start):
+            out[:] = host[start : start + out.size]
+
+        self._put(vid, shard_id, host.size, copy)
+
+    def put_file(self, vid: int, shard_id: int, path: str) -> None:
+        """put() of a shard file's bytes, read straight into their places
+        in the staging buffer: the pin of a 1.6 GiB shard pays one host
+        pass (file -> staging) where `put(np.fromfile(path))` pays the
+        read into a new array and the copy out of it."""
+        with open(path, "rb") as f:
+            size = os.fstat(f.fileno()).st_size
+
+            def read(out, start):
+                view = memoryview(out)
+                while len(view):
+                    got = os.preadv(f.fileno(), [view], start)
+                    if not got:
+                        raise OSError(f"{path}: short read at {start}")
+                    view, start = view[got:], start + got
+
+            self._put(vid, shard_id, size, read)
+
+    def release_staging(self) -> None:
+        """Give the kept staging buffer back (the pin loop's last act)."""
+        with self._stage_lock:
+            self._stage_buf = None
+
+    def _lay_out(self, padded, size: int, place, fill) -> None:
+        """`size` source bytes into the padded buffer, zeros after them.
+        For a lane-sharded volume in owner-major stripe order:
+        NamedSharding splits the 1-D buffer into n contiguous blocks, so
+        stripe c goes to position (c % n major, c // n minor) and device
+        d gets exactly its interleaved stripes {d, d+n, d+2n, ...}; the
+        bytes go straight to their permuted places, one host pass."""
+        if place != "mesh":
+            fill(padded[:size], 0)
+            padded[size:] = 0
+            return
+        n, stripe = self.n_devices, self.stripe
+        per_device = padded.size // n
+        for c in range(padded.size // stripe):
+            at = (c % n) * per_device + (c // n) * stripe
+            take = max(0, min(stripe, size - c * stripe))
+            if take:
+                fill(padded[at : at + take], c * stripe)
+            if take < stripe:
+                padded[at + take : at + stripe] = 0
+
+    def _put(self, vid: int, shard_id: int, size: int, fill) -> None:
+        """Stage `size` bytes (`fill(out, start)` writes the source's
+        bytes from `start` on into `out`) and ship them to the volume's
+        placement."""
         with self._lock:
-            place = self._claim_place_locked(vid, host.size)
-        if place == "mesh":
-            # owner-major stripe permutation: NamedSharding splits the
-            # 1-D buffer into n contiguous blocks, so reordering stripe
-            # c to position (c % n major, c // n minor) lands device d
-            # exactly its interleaved stripes {d, d+n, d+2n, ...}.  One
-            # extra host copy per shard, paid at pin time.
-            s_n = padded.size // self.stripe
-            perm = (
-                np.arange(s_n)
-                .reshape(s_n // self.n_devices, self.n_devices)
-                .T.ravel()
-            )
-            padded = padded.reshape(s_n, self.stripe)[perm].reshape(-1)
+            place = self._claim_place_locked(vid, size)
+        n_padded = self._padded_len(size)
+        # np.empty + zeroing of what the source does not cover: np.zeros
+        # would memset the WHOLE padded buffer first.  Where device_put
+        # copies (a TPU) one staging buffer is kept between puts and
+        # reused once the transfer has ended: a new 1.6 GiB array pays
+        # the first touch of every page.  The CPU PJRT client zero-copies
+        # aligned numpy arrays into jax Arrays, so there reuse would
+        # alias (and corrupt) previously pinned shards and every put
+        # takes a fresh buffer.  The padded buffer doubles as the
+        # blockdiag segment-stacked layout: its g segments are
+        # contiguous slices, staged by the host for free.
+        kept = rs_tpu.on_tpu()
+        with self._stage_lock if kept else contextlib.nullcontext():
+            t_stage = time.perf_counter()
+            if not kept:
+                padded = np.empty(n_padded, dtype=np.uint8)
+            else:
+                if self._stage_buf is None or self._stage_buf.size < n_padded:
+                    self._stage_buf = np.empty(n_padded, dtype=np.uint8)
+                padded = self._stage_buf[:n_padded]
+            self._lay_out(padded, size, place, fill)
+            t_h2d = time.perf_counter()
+            arr = self._ship(padded, place)
+            # the transfer is asynchronous: wait it out, so that the
+            # staging buffer is free again and the phase's seconds are
+            # the transfer's
+            arr.block_until_ready()
+        for phase, dt in (("stage", t_h2d - t_stage),
+                          ("h2d", time.perf_counter() - t_h2d)):
+            stats_metrics.VOLUME_SERVER_EC_PIN_SECONDS.labels(
+                volume=str(vid), phase=phase
+            ).inc(dt)
+        self._insert(vid, shard_id, place, arr, size)
+
+    def _ship(self, padded, place):
         # the H2D lands directly on the owning device(s): an explicit
         # sharding/device for every put (mesh puts split host-side and
         # ship each device its stripes; whole pins ship to the claimed
@@ -927,13 +992,16 @@ class DeviceShardCache:
             chunk = padded.size // self.n_devices
             lo = self._local_dev_indices[0] * chunk
             hi = (self._local_dev_indices[-1] + 1) * chunk
-            arr = jax.make_array_from_process_local_data(
+            return jax.make_array_from_process_local_data(
                 self._device_of(place), padded[lo:hi], (padded.size,)
             )
-        else:
-            arr = jax.device_put(padded, self._device_of(place))
+        return jax.device_put(padded, self._device_of(place))
+
+    def _insert(self, vid: int, shard_id: int, place, arr, size: int) -> None:
+        """Take the shipped array into the cache's books, evicting what
+        its devices' budgets ask for."""
         key = (vid, shard_id)
-        shares = self._shares(place, padded.size)
+        shares = self._shares(place, int(arr.size))
         budget = self.device_budget
         with self._lock:
             if self._vid_place.get(vid) != place:
@@ -1009,8 +1077,8 @@ class DeviceShardCache:
                 # explicit evict()/clear() (unmount, destroy) release
                 # the claim.
             self._arrays[key] = arr
-            self._true_sizes[key] = host.size
-            self._foot[key] = (place, padded.size)
+            self._true_sizes[key] = size
+            self._foot[key] = (place, int(arr.size))
             self._vid_counts[vid] = self._vid_counts.get(vid, 0) + 1
             for d, share in shares:
                 self._dev_bytes[d] += share
@@ -2461,6 +2529,11 @@ def _pack_calls_sharded(cache, requests, row_of, record_observed):
         by_dev: list[list] = [[] for _ in range(n_dev)]
         for i, s in group:
             by_dev[(s[1] // stripe) % n_dev].append((i, s))
+        if record_observed:  # live traffic only, as the observed shapes
+            for d, mine in enumerate(by_dev):
+                stats_metrics.VOLUME_SERVER_EC_MESH_LANE_REQUESTS.labels(
+                    device=str(d)
+                ).inc(len(mine))
         widest = max(len(b) for b in by_dev)
         n_bucket = _bucket(COUNT_BUCKETS, min(widest, _max_count(bucket)))
         for start in range(0, widest, n_bucket):
@@ -2515,9 +2588,10 @@ def _pack_calls(
         # planner routes every sub-request to the device owning its
         # gather window, so the fused single-device DMA kernels do not
         # apply (the sharded twin IS the batched gather)
-        calls, subs = _pack_calls_sharded(
-            cache, requests, row_of, record_observed
-        )
+        with obs_trace.span("mesh_pack", requests=len(requests)):
+            calls, subs = _pack_calls_sharded(
+                cache, requests, row_of, record_observed
+            )
         return calls, subs, survivors, a_prep, use, w_true, place
     fused = _use_fused(kernel, interpret)
     subs = _plan(requests)
@@ -2753,14 +2827,28 @@ def reconstruct_intervals(
         # the hot-shape view's latency sample: dispatch -> result ready
         # (pipelined calls include their wait behind siblings)
         _note_call_latency(key, time.perf_counter() - t_dispatch)
-        with obs_trace.span("d2h_copy", bytes=nbytes):
+        sharded = deltas is None and part and len(part[0]) == 3
+        # a sharded call's fetch is every device's [n_bucket, fetch]
+        # rows, padded slots too, one device after the other: its own
+        # stage inside d2h_copy
+        mesh_fetch = (
+            obs_trace.span("mesh_fetch", bytes=nbytes) if sharded
+            else contextlib.nullcontext()
+        )
+        with obs_trace.span("d2h_copy", bytes=nbytes), mesh_fetch:
             out = np.asarray(arr).reshape(-1, fetch)
         stats_metrics.VOLUME_SERVER_EC_D2H_BYTES.inc(nbytes)
+        if sharded:
+            mesh_d2h = stats_metrics.VOLUME_SERVER_EC_MESH_D2H_BYTES
+            mesh_d2h.labels(kind="wire").inc(wire_bytes)
+            mesh_d2h.labels(kind="useful").inc(
+                sum(sub[3] for _, sub, _ in part)
+            )
         if deltas is not None:  # fused: host trims the alignment delta
             for j, (sub_idx, (_, _, _, take, _)) in enumerate(part):
                 d = deltas[j]
                 sub_out[sub_idx] = out[j, d : d + take].tobytes()
-        elif part and len(part[0]) == 3:
+        elif sharded:
             # sharded: part entries carry their flat output row (the
             # call's [n_dev * n_bucket, fetch] layout is device-major,
             # with padded slots between devices); the host trims the

@@ -321,6 +321,8 @@ TRACE_STAGES = (
     "batch_resolve",     # loop side of a finished batch: replay, futures
     "needle_assemble",   # pieces joined + parsed + CRC-checked, per batch
     "response_write",    # headers, range and the body the handler writes
+    "mesh_pack",         # lane-sharded planning: stripe split + owner routing
+    "mesh_fetch",        # a sharded call's per-device rows fetched to the host
 )
 # the FIXED bucket ladder the heartbeat stage digests ride on: volume
 # servers ship per-bucket count deltas over exactly these edges (+Inf
@@ -380,6 +382,49 @@ VOLUME_SERVER_EC_DEVICE_D2H_BYTES = Counter(
     "SeaweedFS_volumeServer_ec_device_d2h_bytes",
     "Device->host bytes fetched by resident EC reconstruct calls "
     "(the reconstructed intervals).",
+    registry=REGISTRY,
+)
+# the lane-sharded (mesh) reconstruct path's own accounting: a sharded
+# call fetches every device's n_bucket rows of `fetch` bytes whether or
+# not a request fills them, so wire / useful is what the padding costs;
+# the per-lane request count shows whether the interleaved stripes keep
+# the devices evenly loaded
+VOLUME_SERVER_EC_MESH_D2H_BYTES = Counter(
+    "SeaweedFS_volumeServer_ec_mesh_d2h_bytes",
+    "Device->host bytes of lane-sharded reconstruct calls: wire = every "
+    "device's padded rows as fetched, useful = the interval bytes the "
+    "requests asked for.",
+    ["kind"],
+    registry=REGISTRY,
+)
+for _k in ("wire", "useful"):
+    VOLUME_SERVER_EC_MESH_D2H_BYTES.labels(kind=_k)
+VOLUME_SERVER_EC_MESH_LANE_REQUESTS = Counter(
+    "SeaweedFS_volumeServer_ec_mesh_lane_requests",
+    "Sub-requests of lane-sharded reconstruct batches by the mesh device "
+    "that owns their stripe (device = mesh index).",
+    ["device"],
+    registry=REGISTRY,
+)
+# which tier of the two-tier striping a served interval lay in: volumes
+# above 10 GB keep their first rows in 1 GB large blocks
+VOLUME_SERVER_EC_INTERVAL_ROWS = Counter(
+    "SeaweedFS_volumeServer_ec_interval_rows",
+    "Shard intervals located for EC needle reads by the kind of stripe "
+    "row they lie in (large = a 1 GB large-block row, small = a 1 MB "
+    "row).",
+    ["kind"],
+    registry=REGISTRY,
+)
+for _k in ("large", "small"):
+    VOLUME_SERVER_EC_INTERVAL_ROWS.labels(kind=_k)
+VOLUME_SERVER_EC_PIN_SECONDS = Counter(
+    "SeaweedFS_volumeServer_ec_pin_seconds",
+    "Seconds the pin thread spent bringing a volume's shards into the "
+    "device cache, by phase: stage = shard file (or host-tier array) "
+    "into the padded staging buffer, in the mesh layout's owner-major "
+    "stripe order, h2d = the transfer, warm = the AOT warm plan.",
+    ["volume", "phase"],
     registry=REGISTRY,
 )
 # per-device residency of the shard cache (r19 mesh layout): one series

@@ -48,11 +48,23 @@ class Interval:
         return self.block_index % DATA_SHARDS, off
 
 
+def large_rows(dat_size: int, large_block: int = LARGE_BLOCK_SIZE) -> int:
+    """Rows of large blocks the encoder wrote for a .dat of `dat_size`
+    bytes: its loop (shard_file_size below, ec_encoder.go:219-230) runs
+    while MORE than one large row remains, so a .dat that ends within
+    the last ten small blocks of a large row has that row in small
+    blocks.  Right for the true size and for the 10 x shard size an
+    EcVolume derives alike; the upstream's `datSize / largeRowSize`
+    takes the row for a large one whenever the shard file is a whole
+    number of large blocks long."""
+    return max(0, (dat_size - 1) // (large_block * DATA_SHARDS))
+
+
 def _locate_offset(
     large_block: int, small_block: int, dat_size: int, offset: int
 ) -> tuple[int, bool, int]:
     large_row = large_block * DATA_SHARDS
-    n_large_rows = dat_size // large_row
+    n_large_rows = large_rows(dat_size, large_block)
     if offset < n_large_rows * large_row:
         return offset // large_block, True, offset % large_block
     offset -= n_large_rows * large_row
@@ -67,14 +79,12 @@ def locate_data(
     small_block: int = SMALL_BLOCK_SIZE,
 ) -> list[Interval]:
     """Map a (offset, size) run of the original volume to shard intervals
-    (ec_locate.go:15-52).  `large_block_rows` is derived from dat_size the
-    same way the reference derives it so shard-file offsets agree."""
+    (ec_locate.go:15-52).  `large_block_rows` is what the encoder wrote
+    (`large_rows`), so shard-file offsets agree with the files."""
     block_index, is_large, inner = _locate_offset(
         large_block, small_block, dat_size, offset
     )
-    n_large_rows = (dat_size + DATA_SHARDS * small_block) // (
-        large_block * DATA_SHARDS
-    )
+    n_large_rows = large_rows(dat_size, large_block)
     intervals: list[Interval] = []
     while size > 0:
         block_remaining = (large_block if is_large else small_block) - inner

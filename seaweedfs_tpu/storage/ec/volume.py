@@ -182,6 +182,10 @@ class EcVolume:
         self._ecj = open(self.ecj_path, "ab")
         self._ecj_lock = threading.Lock()
         self.shards: dict[int, EcVolumeShard] = {}
+        # the two-tier striping's block sizes, as write_ec_files takes
+        # them: the upstream's constants, smaller only in tests
+        self.large_block = LARGE_BLOCK_SIZE
+        self.small_block = SMALL_BLOCK_SIZE
         info = load_volume_info(self.base_name + ".vif")
         if info:
             self.version = int(info.get("version", needle_mod.CURRENT_VERSION))
@@ -289,12 +293,12 @@ class EcVolume:
                     if self.host_cache is not None
                     else None
                 )
-                self.device_cache.put(
-                    self.id, sid,
-                    staged if staged is not None
-                    else np.fromfile(shard.path, dtype=np.uint8),
-                )
+                if staged is not None:
+                    self.device_cache.put(self.id, sid, staged)
+                else:
+                    self.device_cache.put_file(self.id, sid, shard.path)
                 n += 1
+        self.device_cache.release_staging()
         return n
 
     def stage_host_shards(self) -> dict[int, np.ndarray]:
@@ -358,8 +362,24 @@ class EcVolume:
         (LocateEcShardNeedle ec_volume.go:206-223)."""
         off, size = self.find_needle(needle_id)
         total = needle_mod.actual_size(size, self.version)
-        intervals = locate_data(self.dat_size(), off, total)
+        intervals = locate_data(
+            self.dat_size(), off, total, self.large_block, self.small_block
+        )
         return off, size, intervals
+
+    def _shard_and_offset(self, interval: Interval) -> tuple[int, int]:
+        return interval.to_shard_and_offset(self.large_block, self.small_block)
+
+    @staticmethod
+    def _count_row_kinds(large: int, total: int) -> None:
+        """`total` located intervals, `large` of them in large-block rows."""
+        from ... import stats as swfs_stats
+
+        rows = swfs_stats.VOLUME_SERVER_EC_INTERVAL_ROWS
+        if large:
+            rows.labels(kind="large").inc(large)
+        if total > large:
+            rows.labels(kind="small").inc(total - large)
 
     # -- interval reads (store_ec.go:176-393) --------------------------------
 
@@ -370,7 +390,7 @@ class EcVolume:
         backend: str = "cpu",
         use_device: bool = True,
     ) -> bytes:
-        shard_id, off = interval.to_shard_and_offset()
+        shard_id, off = self._shard_and_offset(interval)
         data = self._read_shard_interval(
             shard_id, off, interval.size, remote_read, backend, use_device
         )
@@ -586,6 +606,9 @@ class EcVolume:
         # the .ecx binary search is a real disk read serving the request
         with obs_trace.span("shard_read", op="locate"):
             _, _, intervals = self.locate_needle(needle_id)
+        self._count_row_kinds(
+            sum(iv.is_large_block for iv in intervals), len(intervals)
+        )
         parts = [
             self.read_interval(iv, remote_read, backend, use_device)
             for iv in intervals
@@ -615,6 +638,7 @@ class EcVolume:
         rather than aborting the rest of the burst."""
         plans: list[tuple[int, list] | Exception] = []
         requests: list[tuple[int, int, int]] = []
+        n_large = n_located = 0
         # locate = one .ecx binary search (disk preads) per needle: the
         # batch's index-lookup cost, visible as its own trace stage
         with obs_trace.span(
@@ -626,9 +650,11 @@ class EcVolume:
                 except (NeedleNotFound, OSError) as e:
                     plans.append(e)
                     continue
+                n_located += len(intervals)
                 parts: list = []
                 for iv in intervals:
-                    sid, off = iv.to_shard_and_offset()
+                    n_large += iv.is_large_block
+                    sid, off = self._shard_and_offset(iv)
                     shard = self.shards.get(sid)
                     if shard is not None:
                         parts.append(("local", sid, off, iv.size))
@@ -636,6 +662,7 @@ class EcVolume:
                         parts.append(("recon", len(requests)))
                         requests.append((sid, off, iv.size))
                 plans.append((nid, parts))
+        self._count_row_kinds(n_large, n_located)
 
         recon: list[bytes] | None = None
         if requests and self.device_cache is not None:

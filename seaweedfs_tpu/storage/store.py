@@ -542,6 +542,7 @@ class Store:
             return
 
         def pin():
+            from .. import stats
             from ..ops import rs_resident
 
             stage = "pin"
@@ -550,6 +551,7 @@ class Store:
                     cache, should_stop=self._closing.is_set
                 )
                 stage = "warm"
+                t_warm = time.perf_counter()
                 # aot follows the shed knob: with the shed armed the
                 # plan MUST be ahead-of-time (state != "none" routes
                 # cold shapes to host while the executor compiles);
@@ -562,6 +564,9 @@ class Store:
                     should_stop=self._closing.is_set,
                     aot=cache.shed_cold,
                 )
+                stats.VOLUME_SERVER_EC_PIN_SECONDS.labels(
+                    volume=str(ev.id), phase="warm"
+                ).inc(time.perf_counter() - t_warm)
             except Exception as e:
                 logging.getLogger(__name__).exception(
                     "ec device-cache %s failed for volume %d", stage, ev.id
