@@ -409,40 +409,71 @@ LAYOUTS = ("flat", "blockdiag")
 
 
 class StagingArena:
-    """Per-slot preallocated host staging buffer for a batch's packed
-    offset/row vectors: one [3, COUNT_BUCKETS[-1]] int32 block covers
-    the widest device call of either kernel family (fused uses one
-    packed row, the XLA fallback all three), so a slot's calls stage
-    into reused memory instead of allocating fresh np arrays per batch.
-    Two slots -> two arenas: a slot's arena is never touched by the
-    other slot's in-flight batch.  Only safe where device_put COPIES
-    (TPU/GPU): the CPU PJRT client zero-copies aligned numpy, so an
-    arena there would alias (and corrupt) an asynchronously executing
-    call's input — reconstruct_intervals gates arena use on on_tpu()."""
+    """Per-slot preallocated host staging rows for a batch's packed
+    offset/row vectors: BLOCKS row-blocks of [3, COUNT_BUCKETS[-1]]
+    int32, each wide enough for the widest device call of either kernel
+    family (fused uses one packed row, the XLA fallback all three).
+    Every call of a batch in flight stages into a block of its own
+    (`take`), so its put can be left asynchronous: the transfer may
+    read the rows at any time until the call's result is ready, and
+    reconstruct_intervals gives a block back (`give`) only after it
+    has waited for that result.  A batch with more calls than blocks
+    collects its oldest call first and stages into the rows that call
+    gave back.  The pipeline slot, and with it the arena, is held until
+    every result of the batch is fetched, and a released slot frees all
+    blocks (`reset`), so the other slot's in-flight batch never touches
+    these rows.  Only safe where device_put COPIES (TPU/GPU): the CPU
+    PJRT client zero-copies aligned numpy, so an arena there would
+    alias (and corrupt) an asynchronously executing call's input —
+    reconstruct_intervals gates arena use on on_tpu()."""
 
-    # rows of the arena block, by kernel family
+    # rows of a block, by kernel family
     ROWS_FUSED = 1   # packed (offset_units << META_ROW_BITS | row)
     ROWS_XLA = 3     # offsets / rows / deltas
+    # a batch is one call a size bucket present (more only where a group
+    # outgrows its count bucket, _max_count): one block a bucket lets
+    # every put of such a batch fly
+    BLOCKS = len(SIZE_BUCKETS)
 
     def __init__(self, width: int | None = None):
         self.width = width or COUNT_BUCKETS[-1]
-        self._buf = np.empty((self.ROWS_XLA, self.width), dtype=np.int32)
+        buf = np.empty(
+            (self.BLOCKS, self.ROWS_XLA, self.width), dtype=np.int32
+        )
+        # one [3, width] view a block, kept: every staged view of a block
+        # derives from the same object
+        self.blocks = list(buf)
+        self.reset()
 
-    def stage_fused(self, packed: list[int], pad: int) -> np.ndarray:
-        """-> [n] int32 view of the arena holding the packed meta."""
+    def reset(self) -> None:
+        """Every block is free again (the slot was released)."""
+        self._free = list(range(len(self.blocks) - 1, -1, -1))
+
+    def take(self) -> int | None:
+        """-> a free block's index, None while all are in flight."""
+        return self._free.pop() if self._free else None
+
+    def give(self, block: int) -> None:
+        """The call staged in `block` has its result: rows reusable."""
+        self._free.append(block)
+
+    def stage_fused(
+        self, packed: list[int], pad: int, block: int = 0
+    ) -> np.ndarray:
+        """-> [n] int32 view of `block` holding the packed meta."""
         n = len(packed) + pad
-        view = self._buf[0, :n]
+        view = self.blocks[block][0, :n]
         view[: len(packed)] = packed
         view[len(packed):] = 0
         return view
 
     def stage_xla(
         self, offsets: list[int], rows: list[int], deltas: list[int],
-        pad: int,
+        pad: int, block: int = 0,
     ) -> np.ndarray:
-        """-> [3, n] int32 view of the arena (offsets/rows/deltas)."""
+        """-> [3, n] int32 view of `block` (offsets/rows/deltas)."""
         n = len(offsets) + pad
-        view = self._buf[:, :n]
+        view = self.blocks[block][:, :n]
         for i, col in enumerate((offsets, rows, deltas)):
             view[i, : len(col)] = col
             view[i, len(col):] = 0
@@ -467,14 +498,15 @@ class DevicePipeline:
     and ship+execute (inside it) while batch N drains its D2H — only
     N's fetch blocks N's completion.  `slots=1` is the serial baseline
     (bench.py's overlap-off axis).  Each slot owns a preallocated
-    StagingArena so a held slot's host vectors stage into reused pinned
-    memory (no per-batch np alloc churn; the r11 donation work).  The
-    overlap-fraction gauge is device-busy seconds / wall seconds over
-    the current batch window (a window opens when the pipeline leaves
-    idle; the ratio refreshes at EVERY batch completion — a drain-only
-    update would go stale under exactly the sustained load it exists to
-    measure), so 1.0 means the device section ran the whole window and
-    >1 means the staging slots genuinely overlapped."""
+    StagingArena so a held slot's host vectors stage into reused
+    memory, a row-block a call in flight (no per-batch np alloc churn;
+    the r11 donation work).  The overlap-fraction gauge is device-busy
+    seconds / wall seconds over the current batch window (a window
+    opens when the pipeline leaves idle; the ratio refreshes at EVERY
+    batch completion — a drain-only update would go stale under exactly
+    the sustained load it exists to measure), so 1.0 means the device
+    section ran the whole window and >1 means the staging slots
+    genuinely overlapped."""
 
     def __init__(self, slots: int = 2):
         self._cond = threading.Condition()
@@ -527,6 +559,7 @@ class DevicePipeline:
             dur = time.perf_counter() - t0
             with self._cond:
                 self._active -= 1
+                self._arenas[arena_idx].reset()
                 self._free_arenas.append(arena_idx)
                 self._busy_s += dur
                 self.total_busy_s += dur
@@ -2630,19 +2663,21 @@ def _pack_calls(
     return calls, subs, survivors, a_prep, use, w_true, place
 
 
-def _stage_call_vec(kind, cols, pad, arena=None) -> np.ndarray:
+def _stage_call_vec(kind, cols, pad, arena=None, block=0) -> np.ndarray:
     """Materialize one call's host staging vector — [n] packed int32
-    (fused) or [3, n] int32 (xla fallback) — into the held slot's arena
-    when one is supplied (TPU: device_put copies, so the pinned arena
-    block is reused batch after batch with zero host allocs) or a fresh
-    array otherwise (CPU PJRT zero-copies aligned numpy into the jax
-    Array, so a reused buffer would alias an asynchronously executing
-    call's input)."""
+    (fused) or [3, n] int32 (xla fallback) — into row-block `block` of
+    the held slot's arena when one is supplied (TPU: device_put copies,
+    so the arena's rows are reused batch after batch with zero host
+    allocs; the caller took the block and keeps it until the call's
+    result is ready) or a fresh array otherwise (CPU PJRT zero-copies
+    aligned numpy into the jax Array, so a reused buffer would alias an
+    asynchronously executing call's input)."""
     if kind == "sharded":
         # [n_dev, 2, width] per-device (local offset, wanted row)
         # slots: the NamedSharding put splits this host-side and ships
         # each device exactly its own requests — never through the
-        # arena (one pinned block cannot back a device-sharded put)
+        # arena (one block cannot back a device-sharded put), so a
+        # fresh array a call, which the asynchronous put keeps alive
         dev_cols, width = cols
         vec = np.zeros((len(dev_cols), 2, width), dtype=np.int32)
         for d, (offs, rows) in enumerate(dev_cols):
@@ -2651,11 +2686,11 @@ def _stage_call_vec(kind, cols, pad, arena=None) -> np.ndarray:
         return vec
     if kind == "fused":
         if arena is not None:
-            return arena.stage_fused(cols, pad)
+            return arena.stage_fused(cols, pad, block)
         return np.array(cols + [0] * pad, dtype=np.int32)
     offsets, rows, deltas = cols
     if arena is not None:
-        return arena.stage_xla(offsets, rows, deltas, pad)
+        return arena.stage_xla(offsets, rows, deltas, pad, block)
     return np.array(
         [col + [0] * pad for col in (offsets, rows, deltas)],
         dtype=np.int32,
@@ -2737,13 +2772,24 @@ def reconstruct_intervals(
     than `data_shards` non-wanted shards of `vid` are resident.
 
     `layout` (None = the cache's active layout) picks the kernel family:
-    "blockdiag" serves through the block-diagonal g-group system (the
-    ~157 GB/s round-3 kernel), "flat" the plain one.  The call is staged
-    pack -> H2D -> execute -> D2H: packing runs before a staging slot is
-    taken (cache.pipeline, 2 slots = double buffering), so a concurrent
-    batch packs and ships while the previous one executes and only each
-    batch's own D2H blocks it.  Every stage is a trace span feeding
-    SeaweedFS_request_stage_seconds."""
+    "blockdiag" serves through the block-diagonal g-group system,
+    "flat" the plain one.  Packing runs before a staging slot is taken
+    (cache.pipeline, 2 slots = double buffering), so a concurrent batch
+    packs while the previous one holds the device section.  Inside the
+    section every call is enqueued before any is collected: its vector
+    put is issued and not waited for (a staging row-block of its own on
+    a TPU, a fresh vector elsewhere), its program dispatched, and its
+    result's copy to the host asked for at dispatch; a lane-sharded
+    call asks only for the shards of devices that hold asked-for rows.
+    The calls go out largest output first, so that the longest program
+    and copy run behind the staging of all the others.  The section
+    then collects in that order: it blocks once a call, for that call's
+    result, and reads a host copy that has been streaming out since the
+    program ended.  It waits earlier only where a batch has more calls
+    than the arena has row-blocks, or more un-fetched output than
+    _MAX_PENDING_OUT: then the oldest call is collected first.
+    Every stage is a trace span feeding SeaweedFS_request_stage_seconds;
+    ec_device_transfers_total{kind} says how the transfers went."""
     if not requests:
         return []
     kernel, interpret = _kernel_mode(kernel, interpret)
@@ -2805,39 +2851,58 @@ def reconstruct_intervals(
     dev_calls = dev_misses = dev_h2d = dev_d2h = 0
     sub_out: list[bytes | None] = [None] * len(subs)
 
-    # PIPELINE: dispatch device calls ahead of fetching results (jax
-    # dispatch is async — each call's H2D and compute start immediately).
-    # This overlaps the per-call dispatch and D2H of call N with the
-    # compute of call N+1 instead of paying them serially per size
-    # bucket.  Aggregate un-fetched output is bounded: every
-    # pending call holds its [n, fetch] result in HBM, so a huge batch
-    # must drain the oldest call before dispatching more.
+    # PIPELINE: enqueue everything, then collect.  jax dispatch is
+    # async: a call's vector put is issued and left in flight (its
+    # program orders itself behind the transfer), the program is
+    # dispatched, and the result's copy to the host is asked for at
+    # once, so it streams out as soon as the program ends while later
+    # calls are still being staged; the section waits for nothing until
+    # it collects, in order.  Aggregate un-fetched output is bounded:
+    # every pending call holds its [n, fetch] result in HBM, so a huge
+    # batch must collect the oldest call before dispatching more.
     pending: list[tuple] = []
     pending_bytes = 0
+    n_dev = cache.n_devices
+    # ec_device_transfers_total{kind}, counted once a batch
+    transfers = {
+        "h2d_waited": 0, "d2h_shard_fetched": 0, "d2h_shard_skipped": 0,
+    }
 
     def _finish(entry) -> int:
-        part, arr, fetch, deltas, key, t_dispatch, wire_bytes = entry
-        nbytes = int(arr.size)  # padded rows ride the fetch too
+        (part, arr, fetch, n_bucket, deltas, key, t_dispatch, wire_bytes,
+         block, shards) = entry
         # completion boundary BEFORE the d2h span: jax dispatch is
         # async, so without it the fetch would absorb the kernel's
         # remaining execute time and an MXU/compile regression would
         # read as "transfer-bound fetch" in the stage histogram — the
         # blocking wait lands in device_execute, where it belongs
         arr.block_until_ready()
+        if block is not None:
+            # the program has consumed its vector: the rows are free
+            arena.give(block)
         # the hot-shape view's latency sample: dispatch -> result ready
         # (pipelined calls include their wait behind siblings)
         _note_call_latency(key, time.perf_counter() - t_dispatch)
         sharded = deltas is None and part and len(part[0]) == 3
-        # a sharded call's fetch is every device's [n_bucket, fetch]
-        # rows, padded slots too, one device after the other: its own
-        # stage inside d2h_copy
+        # a sharded call's fetch is its own stage inside d2h_copy: the
+        # n_bucket rows, padded slots too, of every device that holds an
+        # asked-for row (`shards`), or of every device, in one array,
+        # from the replicated result of a multi-process mesh
         mesh_fetch = (
-            obs_trace.span("mesh_fetch", bytes=nbytes) if sharded
+            obs_trace.span("mesh_fetch", bytes=wire_bytes) if sharded
             else contextlib.nullcontext()
         )
-        with obs_trace.span("d2h_copy", bytes=nbytes), mesh_fetch:
-            out = np.asarray(arr).reshape(-1, fetch)
-        stats_metrics.VOLUME_SERVER_EC_D2H_BYTES.inc(nbytes)
+        # the copy was asked for at dispatch: what is paid here is what
+        # of it is still on its way, and numpy's view of the host copy
+        with obs_trace.span("d2h_copy", bytes=wire_bytes), mesh_fetch:
+            if shards is not None:
+                # device -> its [n_bucket, fetch] rows
+                out = {d: np.asarray(shard)[0] for d, shard in shards.items()}
+            else:
+                out = np.asarray(arr).reshape(
+                    (-1, n_bucket, fetch) if sharded else (-1, fetch)
+                )
+        stats_metrics.VOLUME_SERVER_EC_D2H_BYTES.inc(wire_bytes)
         if sharded:
             mesh_d2h = stats_metrics.VOLUME_SERVER_EC_MESH_D2H_BYTES
             mesh_d2h.labels(kind="wire").inc(wire_bytes)
@@ -2851,10 +2916,12 @@ def reconstruct_intervals(
         elif sharded:
             # sharded: part entries carry their flat output row (the
             # call's [n_dev * n_bucket, fetch] layout is device-major,
-            # with padded slots between devices); the host trims the
-            # delta — backward-aligned windows fold theirs into it
+            # with padded slots between devices), which names the
+            # owner's shard and the row in it; the host trims the delta
+            # — backward-aligned windows fold theirs into it
             for sub_idx, (_, _, delta, take, _), row in part:
-                sub_out[sub_idx] = out[row, delta : delta + take].tobytes()
+                d, j = divmod(row, n_bucket)
+                sub_out[sub_idx] = out[d][j, delta : delta + take].tobytes()
         else:  # XLA fallback: delta was shifted on device iff narrowed
             bucket = part[0][1][4]
             for j, (sub_idx, (_, _, delta, take, _)) in enumerate(part):
@@ -2875,16 +2942,37 @@ def reconstruct_intervals(
         # the slot's preallocated arena only where device_put COPIES
         # (TPU/GPU); the CPU PJRT client zero-copies aligned numpy, so a
         # reused block would alias an asynchronously executing call's
-        # input (see StagingArena)
-        arena = pslot.arena if rs_tpu.on_tpu() else None
-        for call, key in zip(calls, call_keys):
+        # input (see StagingArena); a sharded call's vector is split
+        # over the mesh and never rides the arena (_stage_call_vec)
+        arena = (
+            pslot.arena if rs_tpu.on_tpu() and place != "mesh" else None
+        )
+        # the calls with the most output first: their programs and
+        # their copies to the host take longest, and run while the
+        # smaller calls are staged and dispatched behind them
+        by_output = sorted(
+            zip(calls, call_keys), reverse=True,
+            key=lambda ck: ck[0][6] * ck[0][4],  # n_bucket * fetch
+        )
+        for call, key in by_output:
             kind, part, cols, pad, fetch, tile, n_bucket, deltas = call
             # H2D: stage + ship this call's packed host vector (ONE
             # int32 array per call — fused meta is a single packed row,
             # the r09 [2, N]/three-vector forms are gone).  Tiny, but
             # making it a named stage is what lets the stage histogram
             # show whether h2d or execute owns a regression.
-            vec_np = _stage_call_vec(kind, cols, pad, arena)
+            block = None
+            if arena is not None:
+                block = arena.take()
+                if block is None:
+                    # more calls than row-blocks: every block backs a
+                    # put that may still be read, so collect the oldest
+                    # call (its result proves its put landed) and stage
+                    # into the rows it gives back
+                    pending_bytes -= _finish(pending.pop(0))
+                    block = arena.take()
+                    transfers["h2d_waited"] += 1
+            vec_np = _stage_call_vec(kind, cols, pad, arena, block)
             h2d_bytes = int(vec_np.nbytes)
             with obs_trace.span("h2d_copy", bytes=h2d_bytes):
                 # sharding-aware staging: the vector lands directly on
@@ -2916,11 +3004,12 @@ def reconstruct_intervals(
                     )
                 else:
                     dev_vec = jnp.asarray(vec_np)
-                # the put is async too: wait it out INSIDE the span so
-                # the stage measures the transfer, not the enqueue —
-                # and so the arena rows are safe to reuse for the next
-                # call once the copy has landed
-                dev_vec.block_until_ready()
+                # the put is left in flight: the span is its enqueue,
+                # and the program that consumes dev_vec orders itself
+                # behind the transfer.  The transfer may read vec_np
+                # until then: an arena block stays taken until _finish
+                # has the call's result, and a fresh vector is kept
+                # alive by the put itself
             stats_metrics.VOLUME_SERVER_EC_H2D_BYTES.inc(h2d_bytes)
             dev_h2d += h2d_bytes
             # the call key tracks the prepared matrix's shape EXACTLY
@@ -2937,15 +3026,39 @@ def reconstruct_intervals(
                 mesh=cache.mesh if kind == "sharded" else None,
                 replicate_out=cache.multiprocess,
             )
+            # D2H asked for at dispatch: the copy to the host starts
+            # when the program ends, while later calls are still being
+            # staged, and all calls' copies are in flight together
+            shards = None
+            wire_rows = n_bucket
+            if kind == "sharded" and not cache.multiprocess:
+                # one [1, n_bucket, fetch] shard a device: only the
+                # devices that hold asked-for rows are fetched, each
+                # into a host array of its own (a whole-array fetch
+                # would bring every device's padded rows and copy them
+                # once more into one block)
+                dev_cols = cols[0]
+                shards = {}
+                for shard in arr.addressable_shards:
+                    d = shard.index[0].start or 0
+                    if dev_cols[d][0]:
+                        shard.data.copy_to_host_async()
+                        shards[d] = shard.data
+                transfers["d2h_shard_fetched"] += len(shards)
+                transfers["d2h_shard_skipped"] += n_dev - len(shards)
+                wire_rows *= len(shards)
+            else:
+                # one array: a single device's result, or the result a
+                # multi-process mesh replicates, of which every process
+                # reads all rows
+                arr.copy_to_host_async()
+                if kind == "sharded":
+                    wire_rows *= n_dev
             # the padded rows ride the wire too: count what the fetch
-            # actually moves, not just the useful subset (a sharded
-            # call fetches every device's n_bucket rows)
-            wire_rows = n_bucket * (
-                cache.n_devices if kind == "sharded" else 1
-            )
+            # actually moves, not just the useful subset
             pending.append(
-                (part, arr, fetch, deltas, key, t_dispatch,
-                 wire_rows * fetch)
+                (part, arr, fetch, n_bucket, deltas, key, t_dispatch,
+                 wire_rows * fetch, block, shards)
             )
             pending_bytes += wire_rows * fetch
             dev_calls += 1
@@ -2954,6 +3067,12 @@ def reconstruct_intervals(
                 pending_bytes -= _finish(pending.pop(0))
         for entry in pending:
             _finish(entry)
+        transfers["h2d_async"] = dev_calls - transfers["h2d_waited"]
+        for how, n in transfers.items():
+            if n:
+                stats_metrics.VOLUME_SERVER_EC_DEVICE_TRANSFERS.labels(
+                    kind=how
+                ).inc(n)
         dev_span.annotate(
             device_calls=dev_calls, compile_misses=dev_misses,
             h2d_bytes=dev_h2d, d2h_bytes=dev_d2h,

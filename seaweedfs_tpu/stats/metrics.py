@@ -385,20 +385,37 @@ VOLUME_SERVER_EC_DEVICE_D2H_BYTES = Counter(
     registry=REGISTRY,
 )
 # the lane-sharded (mesh) reconstruct path's own accounting: a sharded
-# call fetches every device's n_bucket rows of `fetch` bytes whether or
-# not a request fills them, so wire / useful is what the padding costs;
+# call fetches all n_bucket rows of `fetch` bytes of every device that
+# holds an asked-for row, so wire / useful is what the padding costs;
 # the per-lane request count shows whether the interleaved stripes keep
 # the devices evenly loaded
 VOLUME_SERVER_EC_MESH_D2H_BYTES = Counter(
     "SeaweedFS_volumeServer_ec_mesh_d2h_bytes",
-    "Device->host bytes of lane-sharded reconstruct calls: wire = every "
-    "device's padded rows as fetched, useful = the interval bytes the "
-    "requests asked for.",
+    "Device->host bytes of lane-sharded reconstruct calls: wire = the "
+    "padded rows of every shard that was fetched, useful = the interval "
+    "bytes the requests asked for.",
     ["kind"],
     registry=REGISTRY,
 )
 for _k in ("wire", "useful"):
     VOLUME_SERVER_EC_MESH_D2H_BYTES.labels(kind=_k)
+# how the device section of a degraded-read batch moved its transfers
+# (ops/rs_resident.py reconstruct_intervals): a call's vector put left
+# asynchronous or issued only after the oldest call was collected, and a
+# sharded call's per-device result shards fetched or left on the device
+VOLUME_SERVER_EC_DEVICE_TRANSFERS = Counter(
+    "SeaweedFS_volumeServer_ec_device_transfers",
+    "Transfers of resident EC reconstruct calls by how they were issued: "
+    "h2d_async = a call's vector put left in flight behind its program, "
+    "h2d_waited = the staging arena was full and the oldest call was "
+    "collected first, d2h_shard_fetched / d2h_shard_skipped = a sharded "
+    "call's per-device result shards with / without asked-for rows.",
+    ["kind"],
+    registry=REGISTRY,
+)
+for _k in ("h2d_async", "h2d_waited", "d2h_shard_fetched",
+           "d2h_shard_skipped"):
+    VOLUME_SERVER_EC_DEVICE_TRANSFERS.labels(kind=_k)
 VOLUME_SERVER_EC_MESH_LANE_REQUESTS = Counter(
     "SeaweedFS_volumeServer_ec_mesh_lane_requests",
     "Sub-requests of lane-sharded reconstruct batches by the mesh device "

@@ -162,8 +162,27 @@ def test_mesh_holds_the_survivors_in_even_quarters(mesh_volume):
         s for s in range(14) if s not in LOST]
 
 
-def test_degraded_reads_agree_with_what_was_written(mesh_volume):
+@pytest.mark.parametrize("width", [16, 3])
+def test_degraded_reads_agree_with_what_was_written(
+        mesh_volume, monkeypatch, width):
+    """Batches of `width` needles through the mesh: 16 as the serving
+    dispatcher cuts them, 3 so that most bucket groups leave devices
+    without rows."""
     ev, cache, blobs, dat_size = mesh_volume
+    # what every sharded call of the test asks of the mesh, as packed:
+    # (devices with rows, n_bucket, fetch)
+    asked = []
+    real_pack = rs_resident._pack_calls
+
+    def pack(*args, **kw):
+        out = real_pack(*args, **kw)
+        asked.extend(
+            (sum(bool(offs) for offs, _ in cols[0]), n_bucket, fetch)
+            for kind, _, cols, _, fetch, _, n_bucket, _ in out[0]
+            if kind == "sharded")
+        return out
+
+    monkeypatch.setattr(rs_resident, "_pack_calls", pack)
     extents = {}
     for key in blobs:
         off, size = ev.find_needle(key)
@@ -183,7 +202,12 @@ def test_degraded_reads_agree_with_what_was_written(mesh_volume):
     lanes = stats.VOLUME_SERVER_EC_MESH_LANE_REQUESTS
     rows = stats.VOLUME_SERVER_EC_INTERVAL_ROWS
     d2h = stats.VOLUME_SERVER_EC_MESH_D2H_BYTES
+    moved = stats.VOLUME_SERVER_EC_DEVICE_TRANSFERS
     before = {
+        "fetched": counter(moved, kind="d2h_shard_fetched"),
+        "skipped": counter(moved, kind="d2h_shard_skipped"),
+        "async": counter(moved, kind="h2d_async"),
+        "waited": counter(moved, kind="h2d_waited"),
         "lanes": [counter(lanes, device=str(d)) for d in range(N_DEV)],
         "large": counter(rows, kind="large"),
         "small": counter(rows, kind="small"),
@@ -191,8 +215,8 @@ def test_degraded_reads_agree_with_what_was_written(mesh_volume):
         "useful": counter(d2h, kind="useful"),
     }
     keys = sorted(blobs)
-    for start in range(0, len(keys), 16):
-        batch = keys[start:start + 16]
+    for start in range(0, len(keys), width):
+        batch = keys[start:start + width]
         for key, needle in zip(batch, ev.read_needles_batch(batch)):
             assert not isinstance(needle, Exception), (key, needle)
             assert bytes(needle.data) == blobs[key], key
@@ -207,7 +231,20 @@ def test_degraded_reads_agree_with_what_was_written(mesh_volume):
         not large for *_, large in pieces)
     useful = counter(d2h, kind="useful") - before["useful"]
     assert useful == sum(n for s, _, n, _ in pieces if s == 3)
-    assert counter(d2h, kind="wire") - before["wire"] >= useful
+    # a device is fetched if and only if it holds an asked-for row, and
+    # wire is the fetched shards' n_bucket rows of fetch bytes
+    wire = counter(d2h, kind="wire") - before["wire"]
+    assert wire == sum(n * n_bucket * fetch for n, n_bucket, fetch in asked)
+    assert wire >= useful
+    fetched = counter(moved, kind="d2h_shard_fetched") - before["fetched"]
+    skipped = counter(moved, kind="d2h_shard_skipped") - before["skipped"]
+    assert fetched == sum(n for n, *_ in asked) > 0
+    assert skipped == sum(N_DEV - n for n, *_ in asked)
+    if width == 3:
+        assert skipped > fetched, (skipped, fetched)
+    # the CPU mesh stages a fresh vector a call: every put flies
+    assert counter(moved, kind="h2d_async") - before["async"] == len(asked)
+    assert counter(moved, kind="h2d_waited") == before["waited"]
 
 
 # ----------------------------------------------------------- the pin path

@@ -240,6 +240,61 @@ def test_sharded_reconstruct_matches_oracle(encoded_big, layout):
         )
 
 
+def _counts(family, kinds):
+    return {k: family.labels(kind=k)._value.get() for k in kinds}
+
+
+@pytest.mark.parametrize("idle", [0, 1, 2, 3])
+def test_sharded_fetch_reads_only_devices_with_rows(encoded_big, idle):
+    """One mixed batch of five size buckets on a 2x2 mesh whose bucket
+    groups leave `idle` of the four devices without rows: the answers
+    equal the host codec's, a device without an asked-for row is never
+    fetched, and `wire` is the fetched shards' padded rows."""
+    from seaweedfs_tpu.stats import metrics as stats
+
+    c = _sharded_cache(mesh_devices=4, shard_quantum=1 << 21)
+    for sid in range(14):
+        if sid not in (3, 11):
+            c.put(23, sid, encoded_big[sid])
+    assert c.n_devices == 4 and c.stripe == 512 * 1024
+    owners = range(4 - idle)
+    sizes = [700, 5000, 20000, 100000, 400000]
+    # stripe k lives on device k % 4: every size asks each owner once
+    reqs = [
+        (3 if (d + i) % 2 else 11, (d + 4 * (i % 2)) * c.stripe + 900 + i,
+         size)
+        for i, size in enumerate(sizes) for d in owners
+    ]
+    packed = rs_resident._pack_calls(
+        c, 23, reqs, "xla", True, c.layout, 10, 14, record_observed=False
+    )[0]
+    assert [call[0] for call in packed] == ["sharded"] * len(sizes)
+    assert all(
+        sum(bool(offs) for offs, _ in call[2][0]) == len(owners)
+        for call in packed
+    )
+    transfers = stats.VOLUME_SERVER_EC_DEVICE_TRANSFERS
+    d2h = stats.VOLUME_SERVER_EC_MESH_D2H_BYTES
+    kinds = ("h2d_async", "h2d_waited", "d2h_shard_fetched",
+             "d2h_shard_skipped")
+    before = _counts(transfers, kinds) | _counts(d2h, ("wire", "useful"))
+    got = rs_resident.reconstruct_intervals(c, 23, reqs)
+    after = _counts(transfers, kinds) | _counts(d2h, ("wire", "useful"))
+    for (sid, off, size), piece in zip(reqs, got):
+        assert piece == encoded_big[sid][off : off + size].tobytes()
+    moved = {k: after[k] - before[k] for k in after}
+    assert moved["h2d_async"] == len(sizes) and moved["h2d_waited"] == 0
+    assert moved["d2h_shard_fetched"] == len(sizes) * len(owners)
+    assert moved["d2h_shard_skipped"] == len(sizes) * idle
+    # call = (kind, part, cols, pad, fetch, tile, n_bucket, deltas)
+    assert moved["wire"] == sum(
+        len(owners) * call[6] * call[4] for call in packed
+    )
+    assert moved["useful"] == sum(size for _, _, size in reqs)
+    assert moved["wire"] >= moved["useful"]
+    c.clear()
+
+
 def test_sharded_multi_chunk_large_read(encoded_big):
     c = _sharded_cache(layout="blockdiag")
     for sid in range(14):

@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 
 from seaweedfs_tpu.ops import rs, rs_resident
+from seaweedfs_tpu.stats import metrics as stats_metrics
 from seaweedfs_tpu.storage import ec
 
 from test_ec import encode_volume, make_volume
@@ -66,19 +67,119 @@ class TestCache:
         assert cache.bytes_used == 0
 
 
+TRANSFER_KINDS = ("h2d_async", "h2d_waited", "d2h_shard_fetched",
+                  "d2h_shard_skipped")
+
+
+def transfers():
+    family = stats_metrics.VOLUME_SERVER_EC_DEVICE_TRANSFERS
+    return {k: family.labels(kind=k)._value.get() for k in TRANSFER_KINDS}
+
+
+def five_buckets(length):
+    """One mixed batch with one request in each of five size buckets
+    (2 KiB .. 512 KiB), on both lost shards, offsets unaligned."""
+    sizes = [700, 5000, 20000, 100000, 250000]
+    assert [rs_resident._bucket(rs_resident.SIZE_BUCKETS, n)
+            for n in sizes] == list(rs_resident.SIZE_BUCKETS[:5])
+    return [(3 if i % 2 else 11, 17 + i * 9001, n)
+            for i, n in enumerate(sizes) if 17 + i * 9001 + n <= length]
+
+
+class TestStagingArena:
+    def test_two_calls_of_a_batch_get_disjoint_rows(self):
+        arena = rs_resident.StagingArena(width=32)
+        assert len(arena.blocks) == len(rs_resident.SIZE_BUCKETS)
+        first, second = arena.take(), arena.take()
+        assert first != second
+        a = arena.stage_fused([5, 6, 7], 1, first)
+        b = arena.stage_xla([1, 2], [3, 4], [5, 6], 0, second)
+        assert not np.shares_memory(a, b)
+        # the second call's staging leaves the first call's rows as its
+        # put may still be reading them
+        assert a.tolist() == [5, 6, 7, 0]
+        assert b.tolist() == [[1, 2], [3, 4], [5, 6]]
+
+    def test_blocks_run_out_and_come_back(self, monkeypatch):
+        monkeypatch.setattr(rs_resident.StagingArena, "BLOCKS", 2)
+        arena = rs_resident.StagingArena(width=8)
+        taken = [arena.take(), arena.take()]
+        assert sorted(taken) == [0, 1] and arena.take() is None
+        arena.give(taken[0])
+        assert arena.take() == taken[0] and arena.take() is None
+        arena.reset()  # a released slot frees every block
+        assert sorted([arena.take(), arena.take()]) == [0, 1]
+
+    def test_a_released_slot_hands_out_a_free_arena(self):
+        pipe = rs_resident.DevicePipeline(slots=1)
+        with pipe.slot() as held:
+            while held.arena.take() is not None:
+                pass
+        with pipe.slot() as again:
+            assert again.arena is held.arena
+            assert again.arena.take() is not None
+
+
 class TestReconstruct:
-    def test_oracle_mixed_sizes(self, coded):
+    @pytest.mark.parametrize("mode", [
+        {}, {"kernel": "pallas", "interpret": True},
+    ], ids=["xla", "fused"])
+    @pytest.mark.parametrize("batch", ["mixed", "five_buckets"])
+    def test_oracle_mixed_sizes(self, coded, batch, mode):
         cache = fill_cache(coded, missing=(3, 11))
         length = coded.shape[1]
-        reqs = [
+        reqs = five_buckets(length) if batch == "five_buckets" else [
             (3, 5, 4096),        # unaligned offset
             (11, 131000, 70000),  # parity shard, spans buckets
             (3, 0, 1),
             (11, length - 1000, 1000),  # tail
         ]
-        outs = rs_resident.reconstruct_intervals(cache, 7, reqs)
+        before = transfers()
+        outs = rs_resident.reconstruct_intervals(cache, 7, reqs, **mode)
         for (sid, off, size), out in zip(reqs, outs):
             assert out == coded[sid][off : off + size].tobytes()
+        moved = {k: n - before[k] for k, n in transfers().items()}
+        # one call a size bucket present, every put left in flight; no
+        # mesh, so no shard is counted either way
+        buckets = {rs_resident._bucket(rs_resident.SIZE_BUCKETS, n)
+                   for _, _, n in reqs}
+        assert moved["h2d_async"] >= len(buckets)
+        if batch == "five_buckets":
+            assert moved["h2d_async"] == len(buckets) == 5
+        assert moved["h2d_waited"] == 0
+        assert moved["d2h_shard_fetched"] == moved["d2h_shard_skipped"] == 0
+
+    @pytest.mark.parametrize("mode", [
+        {"kernel": "xla", "interpret": True},
+        {"kernel": "pallas", "interpret": True},
+    ], ids=["xla", "fused"])
+    def test_more_calls_than_arena_blocks(self, coded, monkeypatch, mode):
+        """Five calls through an arena of two row-blocks (the arena is a
+        TPU's, so the test claims to be one for the batch): the third,
+        fourth and fifth call each collect the oldest call first and
+        stage into the rows it gave back; the answers are the codec's."""
+        cache = fill_cache(coded, missing=(3, 11))
+        reqs = five_buckets(coded.shape[1])
+        assert len(reqs) == 5
+        monkeypatch.setattr(rs_resident.StagingArena, "BLOCKS", 2)
+        monkeypatch.setattr(rs_resident.rs_tpu, "on_tpu", lambda: True)
+        staged = []
+        real_stage = rs_resident._stage_call_vec
+
+        def stage(kind, cols, pad, arena=None, block=0):
+            staged.append(block)
+            return real_stage(kind, cols, pad, arena, block)
+
+        monkeypatch.setattr(rs_resident, "_stage_call_vec", stage)
+        before = transfers()
+        outs = rs_resident.reconstruct_intervals(cache, 7, reqs, **mode)
+        for (sid, off, size), out in zip(reqs, outs):
+            assert out == coded[sid][off : off + size].tobytes()
+        moved = {k: n - before[k] for k, n in transfers().items()}
+        assert moved["h2d_async"] == 2 and moved["h2d_waited"] == 3
+        # the first two calls took a block each; every later call took
+        # the one the oldest call in flight had just given back
+        assert staged == [0, 1, 0, 1, 0]
 
     def test_oracle_chunk_split(self, coded):
         # larger than the biggest size bucket: must split and reassemble

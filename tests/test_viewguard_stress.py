@@ -80,6 +80,28 @@ def test_guard_arena_reuse_with_outstanding_export_fails():
     assert g.violations
 
 
+def test_guard_calls_of_one_batch_stage_into_their_own_blocks():
+    """Two calls of a batch in flight hold a row-block each: staging
+    the second is clean, restaging the first's block before it is given
+    back is the scribble, and after `give` the block is free."""
+    with viewguard.watch() as g:
+        arena = rs_resident.StagingArena(width=64)
+        first, second = arena.take(), arena.take()
+        arena.stage_fused([1, 2, 3], 1, first)
+        arena.stage_fused([4, 5], 0, second)  # its own rows: clean
+        assert g.outstanding == 2 and not g.violations
+        with pytest.raises(viewguard.ViewGuardViolation, match="reuses"):
+            arena.stage_fused([6], 0, first)
+    with viewguard.watch() as g:
+        arena = rs_resident.StagingArena(width=64)
+        first = arena.take()
+        arena.stage_fused([1, 2, 3], 1, first)
+        arena.give(first)  # the call has its result
+        assert g.outstanding == 0
+        arena.stage_fused([6], 0, arena.take())
+    g.assert_clean()
+
+
 def test_guard_slot_scoped_arena_exports_release_cleanly():
     with viewguard.watch() as g:
         pipe = rs_resident.DevicePipeline(slots=1)

@@ -10,12 +10,15 @@ harness closes that gap at test time:
 
   * every zero-copy `Needle.from_bytes(copy=False)` payload view is
     registered with a content fingerprint at export;
-  * every `StagingArena.stage_*` view is registered against its arena,
-    and REUSING an arena (the next `stage_*` on it) while a previous
-    export is still outstanding is a violation — that is exactly the
-    aliasing scribble the two-slot pipeline exists to prevent;
-  * arena exports auto-release when their `DevicePipeline` slot is
-    returned (the device call that consumed them has completed);
+  * every `StagingArena.stage_*` view is registered against its
+    row-block, and REUSING a block (the next `stage_*` on it) while a
+    previous export is still outstanding is a violation — that is
+    exactly the aliasing scribble the arena's blocks and the two-slot
+    pipeline exist to prevent;
+  * a block's export is released (and its bytes verified) when the
+    block is given back (`StagingArena.give`: the call staged there has
+    its result), and every block's when the `DevicePipeline` slot is
+    returned (the device section that used them has completed);
   * `vacuum.commit` triggers an immediate re-verification of every
     outstanding view: a vacuum that mutated bytes under a live zero-copy
     response fails HERE, not as interleaved bytes on a client socket;
@@ -183,7 +186,8 @@ def watch() -> Iterator[ViewGuard]:
     """Instrument the view sources for the duration of the context:
 
       Needle.from_bytes(copy=False)  -> export payload views
-      StagingArena.stage_fused/xla   -> reuse check + export
+      StagingArena.stage_fused/xla   -> reuse check + export, a block
+      StagingArena.give              -> release the block's export
       DevicePipeline.slot            -> auto-release the slot arena's
                                         exports when the slot returns
       vacuum.commit                  -> verify outstanding views after
@@ -197,6 +201,7 @@ def watch() -> Iterator[ViewGuard]:
     real_from_bytes = needle_mod.Needle.from_bytes.__func__
     real_stage_fused = rs_resident.StagingArena.stage_fused
     real_stage_xla = rs_resident.StagingArena.stage_xla
+    real_give = rs_resident.StagingArena.give
     real_slot = rs_resident.DevicePipeline.slot
     real_commit = vacuum_mod.commit
     real_dispatch = rs_resident._dispatch_call
@@ -223,21 +228,43 @@ def watch() -> Iterator[ViewGuard]:
             g.export(n.data, buf, f"needle {n.id:x} payload")
         return n
 
-    def stage_fused(self, packed, pad):
+    # an arena's unit of reuse is the row-block: each call of a batch
+    # in flight stages into a block of its own, so the guarded source is
+    # the block (arena.blocks[b], a kept object), not the arena
+    def stage_fused(self, packed, pad, block=0):
         if _mine():
-            g.check_reuse(self, "StagingArena.stage_fused reuses the arena")
-        view = real_stage_fused(self, packed, pad)
+            g.check_reuse(
+                self.blocks[block],
+                f"StagingArena.stage_fused reuses block {block}",
+            )
+        view = real_stage_fused(self, packed, pad, block)
         if _mine():
-            g.export(view, self, f"arena fused meta [{len(packed)}+{pad}]")
+            g.export(
+                view, self.blocks[block],
+                f"arena fused meta [{len(packed)}+{pad}] block {block}",
+            )
         return view
 
-    def stage_xla(self, offsets, rows, deltas, pad):
+    def stage_xla(self, offsets, rows, deltas, pad, block=0):
         if _mine():
-            g.check_reuse(self, "StagingArena.stage_xla reuses the arena")
-        view = real_stage_xla(self, offsets, rows, deltas, pad)
+            g.check_reuse(
+                self.blocks[block],
+                f"StagingArena.stage_xla reuses block {block}",
+            )
+        view = real_stage_xla(self, offsets, rows, deltas, pad, block)
         if _mine():
-            g.export(view, self, f"arena xla meta [{len(offsets)}+{pad}]")
+            g.export(
+                view, self.blocks[block],
+                f"arena xla meta [{len(offsets)}+{pad}] block {block}",
+            )
         return view
+
+    def give(self, block):
+        # the call staged in this block has its result: the export is
+        # dead, and its bytes must be what was staged (a sibling call
+        # of the same batch that scribbled on them fails HERE)
+        g.release_source(self.blocks[block])
+        real_give(self, block)
 
     @contextlib.contextmanager
     def slot(self):
@@ -245,9 +272,10 @@ def watch() -> Iterator[ViewGuard]:
             try:
                 yield s
             finally:
-                # the device call holding this slot has returned: its
-                # arena exports are dead (verified on the way out)
-                g.release_source(s.arena)
+                # the device section holding this slot has returned:
+                # its arena exports are dead (verified on the way out)
+                for blk in s.arena.blocks:
+                    g.release_source(blk)
 
     def dispatch_call(kind, vec, *args, **kw):
         # donation boundary: the staged vec rides donate_argnums into
@@ -305,6 +333,7 @@ def watch() -> Iterator[ViewGuard]:
     needle_mod.Needle.from_bytes = classmethod(from_bytes)
     rs_resident.StagingArena.stage_fused = stage_fused
     rs_resident.StagingArena.stage_xla = stage_xla
+    rs_resident.StagingArena.give = give
     rs_resident.DevicePipeline.slot = slot
     vacuum_mod.commit = commit
     rs_resident._dispatch_call = dispatch_call
@@ -320,6 +349,7 @@ def watch() -> Iterator[ViewGuard]:
         needle_mod.Needle.from_bytes = classmethod(real_from_bytes)
         rs_resident.StagingArena.stage_fused = real_stage_fused
         rs_resident.StagingArena.stage_xla = real_stage_xla
+        rs_resident.StagingArena.give = real_give
         rs_resident.DevicePipeline.slot = real_slot
         vacuum_mod.commit = real_commit
         rs_resident._dispatch_call = real_dispatch
