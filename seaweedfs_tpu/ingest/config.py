@@ -31,8 +31,8 @@ class IngestConfig:
     backpressure_ms: int = 2000
     # group-commit durability: writers wait for an fsync batch instead
     # of acking from the page cache.  Off by default like the
-    # reference's volume server; the ingest bench turns it on for
-    # honest throughput numbers (-ec.ingest.fsync)
+    # reference's volume server; a run that reports write throughput
+    # turns it on (-ec.ingest.fsync)
     fsync: bool = False
     # group-commit batch bounds: an fsync fires when this many writers
     # are waiting or the oldest has waited this long

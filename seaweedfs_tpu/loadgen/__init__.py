@@ -1,23 +1,21 @@
 """Concurrent-load harness for the volume-server / S3 front door.
 
-ROADMAP item 2: every serving number so far came from one in-process
-bench sweep — this package is the real front door test.  It drives
-thousands of closed-loop HTTP and S3 readers with zipf-skewed keys,
+It drives closed-loop HTTP and S3 readers with zipf-skewed keys,
 hot-volume contention, slow-client dribble, and connection churn against
-a RUNNING cluster, byte-verifies every read, and reports
-reads/s-vs-connections curves plus client-side and stage-histogram
-latency percentiles.  Consumed three ways:
+a RUNNING cluster, byte-verifies every read, and reports counts plus
+client-side latency percentiles.  Consumed two ways:
 
-  * `bench.py bench_load_sweep` — the archived reads/s-vs-connections
-    curve (load_headline), pre-PR config vs QoS+zero-copy;
   * `python -m seaweedfs_tpu loadtest` — the weed-benchmark-style CLI
     against any live cluster;
-  * `__graft_entry__.py` dryrun step 7 / tier-1 smoke — a seconds-scale
-    sweep so the harness itself can't rot.
+  * tests/test_loadgen.py — the drivers in-process against the tests'
+    degraded cluster, so the harness itself can't rot.
+
+The benchmark (`benchmark/generators/`) has its own closed-loop driver and
+does not use this one (ROADMAP C1).
 
 Reference: weed/command/benchmark.go ships the same kind of driver
 (`weed benchmark`); this one adds the adversarial client behaviors the
-serving fixes of this PR exist for.
+front door's stall budget and QoS admission exist for.
 """
 from .workload import LoadScenario, ZipfPicker, zipf_ranks
 from .driver import (

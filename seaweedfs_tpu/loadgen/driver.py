@@ -254,7 +254,7 @@ async def run_mixed_http_load(
     worker rng and byte-verified on later reads like any seed key.
     `written`, when passed, collects every successful write as
     fid -> (holder_url, payload) so the caller can read back EVERY
-    written byte after the sweep (the bench's readback verdict)."""
+    written byte after the run (a caller's readback check)."""
     import aiohttp
 
     from ..operation import assign, upload_data

@@ -46,8 +46,8 @@ class LoadScenario:
     # working-set multiplier: how many times the device (HBM) budget
     # the key space is meant to span.  The sizing hook for
     # oversubscribed sweeps — `loadtest -oversubscribe N` scales its
-    # fill phase by it, and bench.py's tiering pass shrinks the cache
-    # budget to working_set/oversubscribe — so a 4x-over-budget sweep
+    # fill phase by it (a caller may instead shrink the cache budget
+    # to working_set/oversubscribe) — so a 4x-over-budget sweep
     # needs no hand-edited volume counts.  1.0 = the working set fits.
     oversubscribe: float = 1.0
     # byte-verify every response against the expected blob

@@ -23,9 +23,9 @@ black-box recorder half of the incident plane (obs/slo.py is the judge):
     (-obs.incident.minIntervalSeconds) and ring-capped
     (-obs.incident.keep) so a flapping SLO can't fill the disk.
 
-Recording is a lock-guarded deque append (no IO, no serialization) —
-the steady-state overhead bench_incident_smoke bounds at <2% of the
-load sweep's reads/s.
+Recording is a lock-guarded deque append (no IO, no serialization).
+With every obs plane on against -obs.disable the GET cell read +0.1 %
+and +2.0 %, inside its spread (PERF.md section 6, PR 24).
 """
 from __future__ import annotations
 
@@ -51,8 +51,7 @@ class IncidentConfig:
     master-only)."""
 
     # record decision events into the in-memory ring at all
-    # (-obs.incident.disable); the off state is the recorder-overhead
-    # comparison axis bench_incident_smoke measures
+    # (-obs.incident.disable)
     enabled: bool = True
     # events kept in the per-process ring, newest win
     # (-obs.incident.events)

@@ -18,7 +18,7 @@ This module adds the second retention class:
     bucketing, so SeaweedFS_critpath_seconds{route,segment} and
     SeaweedFS_critpath_route_seconds{route} accumulate the per-route
     critical-path composition (segments sum to the route total by
-    construction — the bench asserts it);
+    construction — tests/test_tailpath.py asserts it);
   * `tail_handler` serves GET /debug/tail: per-route stats + pin
     summaries, `?id=` resolves one pinned tree (404 on a miss, same
     contract as /debug/traces), and the shell's `cluster.tail` view and
@@ -155,7 +155,7 @@ class TailStore:
     # ------------------------------------------------------------ tuning
 
     def set_floor_ms(self, floor_ms: float) -> None:
-        """Retune the absolute pin floor at runtime — the bench anchors
+        """Retune the absolute pin floor at runtime — a caller anchors
         it to a calm p99 it can only measure after the store installs."""
         if floor_ms < 0:
             raise ValueError("floor_ms must be >= 0")

@@ -142,7 +142,7 @@ jax.monitoring.register_event_listener(_count_compile_cache_event)
 
 # Where compiled kernels persist when JAX_COMPILATION_CACHE_DIR does not
 # say: ONE fixed directory inside the checkout (git-ignored), shared by
-# `volume`, `server`, bench.py and chip_smoke.py.  The directory is part
+# `volume`, `server`, chip_smoke.py and benchmark/.  The directory is part
 # of the cache key, so a path that moved with -dir, a temporary name, a
 # pid or a time would never hit.
 COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
@@ -497,7 +497,7 @@ class DevicePipeline:
     reconstruct calls: `slots=2` lets batch N+1 pack (outside the slot)
     and ship+execute (inside it) while batch N drains its D2H — only
     N's fetch blocks N's completion.  `slots=1` is the serial baseline
-    (bench.py's overlap-off axis).  Each slot owns a preallocated
+    (-ec.serving.overlap.disable).  Each slot owns a preallocated
     StagingArena so a held slot's host vectors stage into reused
     memory, a row-block a call in flight (no per-batch np alloc churn;
     the r11 donation work).  The overlap-fraction gauge is device-busy
@@ -1718,8 +1718,8 @@ def _gather_reconstruct_blockdiag(
     interpret,
     k_true,
 ):
-    """Block-diagonal twin of _gather_reconstruct (the XLA fallback and
-    bench path), same single donated [3, N] vecs contract: each
+    """Block-diagonal twin of _gather_reconstruct (the XLA fallback,
+    what the CPU mesh runs), same single donated [3, N] vecs contract: each
     request's tile splits into `groups` contiguous segments gathered
     into segment-stacked [g*k, N*seg] rows, one apply of the
     block-diagonal matrix reconstructs every segment, and the per-group
@@ -2702,10 +2702,10 @@ def _dispatch_call(
     fetch, kernel, interpret, key=None, mesh=None, replicate_out=False,
 ):
     """Route one packed call's staged vector to its kernel — the single
-    home of the fused/xla x flat/blockdiag dispatch, shared by
-    reconstruct_intervals' drain loop and make_batched_call's bench
-    thunk so the benchmark can never measure a different compiled shape
-    than the serving path dispatches.  An AOT-compiled executable for
+    home of the fused/xla x flat/blockdiag dispatch for
+    reconstruct_intervals' drain loop, so that the shape a call
+    dispatches and the shape _call_key names (the shed gate's, the warm
+    plan's) cannot drift apart.  An AOT-compiled executable for
     the call's shape takes precedence: the jit wrappers' caches never
     see AOT-warmed shapes, so routing through the registry is what makes
     the background compile actually serve.  `key` is the call's
@@ -3090,116 +3090,6 @@ def reconstruct_intervals(
     # order survives restarts) — off the device path, after the batch
     _maybe_persist_observed()
     return [b"".join(parts) for parts in outputs]
-
-
-def make_batched_call(
-    cache: DeviceShardCache,
-    vid: int,
-    requests: list[tuple[int, int, int]],
-    kernel: str | None = None,
-    interpret: bool | None = None,
-    layout: str | None = None,
-):
-    """Zero-arg thunk running the ONE device call a homogeneous batch of
-    requests (same size bucket, count <= COUNT_BUCKETS[-1]) maps to,
-    returning the un-copied device array — bench.py profiler-times the
-    serving call with this, without host copies in the measured region.
-    `layout` follows the cache's active layout by default."""
-    kernel, interpret = _kernel_mode(kernel, interpret)
-    if layout is None:
-        layout = cache.layout
-    groups = cache.groups if layout == "blockdiag" else 1
-    a_prep, survivors, row_of, use, w_true, place = _resolve_codec(
-        cache, vid, requests, DATA_SHARDS, TOTAL_SHARDS, layout
-    )
-    if place == "mesh":
-        # lane-sharded volume: the bench thunk runs the same ONE-call
-        # contract through the sharded twin (the serving path's calls
-        # route per-device; a homogeneous batch is one call there too)
-        calls, _subs = _pack_calls_sharded(
-            cache, requests, row_of, record_observed=False
-        )
-        if len(calls) != 1:
-            raise ValueError(
-                "bench batch must be one homogeneous bucket group"
-            )
-        kind, _p, cols, pad, fetch, tile, n_bucket, _d = calls[0]
-        key = _call_key(
-            kind, kernel, groups, w_true, tile, fetch, n_bucket,
-            len(use), a_prep.shape, int(survivors[0].size), interpret,
-            _key_place(cache, place),
-        )
-
-        def sharded_thunk():
-            vec_np = _stage_call_vec(kind, cols, pad)
-            sharding = NamedSharding(
-                cache.mesh, P(mesh_mod.SHARD_AXIS, None, None)
-            )
-            if cache.multiprocess:
-                lo = cache._local_dev_indices[0]
-                hi = cache._local_dev_indices[-1] + 1
-                vec = jax.make_array_from_process_local_data(
-                    sharding, vec_np[lo:hi], vec_np.shape
-                )
-            else:
-                vec = jax.device_put(vec_np, sharding)
-            # graftlint: allow(untagged-device-dispatch): bench thunk —
-            # the profiler times this measured region externally; ledger
-            # tagging inside it would bill bench time to a serving class
-            return _dispatch_call(
-                kind, vec, a_prep, survivors, len(use), w_true, groups,
-                tile, fetch, kernel, interpret, key=key, mesh=cache.mesh,
-                replicate_out=cache.multiprocess,
-            )
-
-        return sharded_thunk
-    subs = _plan(requests)
-    buckets = {s[4] for s in subs}
-    if len(buckets) != 1 or len(subs) > COUNT_BUCKETS[-1]:
-        raise ValueError("bench batch must be one homogeneous bucket group")
-    bucket = buckets.pop()
-    part = list(enumerate(subs))
-    # NOTE: deliberately NOT _pack_calls — the bench thunk keeps the
-    # whole homogeneous batch in ONE device call (its contract), while
-    # _pack_calls would split wide large-size batches at _max_count.
-    pad = _bucket(COUNT_BUCKETS, len(part)) - len(part)
-    if _use_fused(kernel, interpret):
-        kind = "fused"
-        cols, _deltas, fetch = _fused_vectors(part, requests, row_of)
-        fetch, tile = _fused_fetch_tile(fetch, groups)
-    else:
-        kind = "xla"
-        cols = _group_vectors(part, requests, row_of)
-        max_take = max(s[3] for _, s in part)
-        fetch = min(bucket, 1 << (max_take - 1).bit_length())
-        tile = bucket
-
-    # the staging vector is built FRESH inside the thunk: the kernels
-    # DONATE it, so a captured device array would be invalid on the
-    # second timed invocation — and shipping per call is exactly what
-    # the serving path pays per batch, so the bench measures that too
-    key = _call_key(
-        kind, kernel, groups, w_true, tile, fetch,
-        pad + len(part), len(use), a_prep.shape,
-        int(survivors[0].size), interpret, _key_place(cache, place),
-    )
-
-    def thunk():
-        vec_np = _stage_call_vec(kind, cols, pad)
-        if cache.mesh is not None:
-            vec = jax.device_put(
-                vec_np, cache.mesh.devices.reshape(-1)[int(place)]
-            )
-        else:
-            vec = jnp.asarray(vec_np)
-        # graftlint: allow(untagged-device-dispatch): bench thunk — see
-        # sharded_thunk above; the measured region stays ledger-free
-        return _dispatch_call(
-            kind, vec, a_prep, survivors, len(use), w_true, groups,
-            tile, fetch, kernel, interpret, key=key,
-        )
-
-    return thunk
 
 
 # Scrub runs over BOUNDED LANE WINDOWS: one compiled program verifies

@@ -27,7 +27,7 @@ Convergence is measured: the first cycle that observes ANY missing or
 corrupt shard starts the clock, and the first cycle after that where
 the census is fully redundant again observes wall seconds into
 `SeaweedFS_master_repair_time_to_healthy_seconds` — the recovery SLO
-bench_chaos_sweep asserts.
+(tests/test_repair_e2e.py holds that it is observed; no cell times it).
 """
 from __future__ import annotations
 

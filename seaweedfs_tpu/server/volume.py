@@ -1776,8 +1776,8 @@ class VolumeServer:
         thread would deadlock against our own servers.  Every fetch
         carries a hard per-call timeout (the remaining deadline budget,
         capped at _SHARD_READ_TIMEOUT_S): a peer that accepts the RPC
-        and never answers — the gray failure bench_netchaos_sweep
-        injects — frees this worker thread at the timeout instead of
+        and never answers — the gray failure ChaosInjector's
+        hang_shard_reads plants — frees this worker at the timeout instead of
         pinning it forever.  `read.peer_of` exposes the shard's primary
         holder so the hedged gather can key its latency EWMAs per peer."""
 

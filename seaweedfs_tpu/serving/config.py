@@ -29,8 +29,8 @@ class ServingConfig:
     # never waits.  0 disables the window.  (-ec.serving.maxWaitUs)
     max_wait_us: int = 200
     # pipelined batches in flight: batch N+1's device dispatch overlaps
-    # batch N's D2H + response fan-out; bench.py sweeps 2/4/8 and
-    # publishes the curve (-ec.serving.maxInflight)
+    # batch N's D2H + response fan-out; 4 was chosen before the chip
+    # and no ledger line compares depths (-ec.serving.maxInflight)
     max_inflight: int = 4
     # backpressure: queued requests beyond this fall back to the native
     # per-read path (counted in the fallback metric) instead of growing
@@ -43,7 +43,7 @@ class ServingConfig:
     layout: str = "blockdiag"
     # double-buffered device staging: 2 slots let batch N+1 pack and
     # ship while batch N executes (only N's D2H blocks N); False = one
-    # slot, the serial baseline bench.py's overlap-off axis measures
+    # slot, one batch in the device section at a time
     # (-ec.serving.overlap.disable)
     overlap: bool = True
     # AOT serving grid + cold-shape shed (-ec.serving.aot.disable):
@@ -87,7 +87,7 @@ class ServingConfig:
     # zero-copy response writes (-ec.serving.zerocopy.disable): needle
     # payloads stay memoryviews over the reconstruct/pread buffers all
     # the way into the aiohttp body write; False restores the legacy
-    # bytes-materializing path (the r13 load bench's comparison axis).
+    # bytes-materializing path.
     # SeaweedFS_volumeServer_response_copy_bytes_total measures the
     # difference.
     zero_copy: bool = True

@@ -419,8 +419,8 @@ class TieringController:
     def _promote_hbm(self, ev, now: float) -> bool:
         """Pin `ev` into the device cache (host-cache bytes first, disk
         otherwise) and re-arm its AOT warm plan from the observed-shape
-        ranking — stall-free promotion is the contract the bench's
-        `promotion_stall_free` verdict checks."""
+        ranking — a promotion must put no compile and no shed on the
+        serving path (tests/test_heat_tiering.py)."""
         cache = self.store.ec_device_cache
         try:
             n = ev.load_shards_to_device(cache)

@@ -291,7 +291,7 @@ async def collect_ec_volume_shards(env) -> dict[int, dict[int, TopoNode]]:
 def _fmt_scrub_row(env, vid, mism, backend, bytes_verified, seconds):
     bad = sum(mism)
     # ONE byte basis for both figures: data bytes covered (shard span
-    # x DATA_SHARDS, the same basis bench.py's scrub GB/s uses), so
+    # x DATA_SHARDS), so
     # the printed rate actually equals size/seconds
     data_bytes = bytes_verified * DATA_SHARDS
     mb = data_bytes / 1e6

@@ -761,8 +761,8 @@ class ClusterTelemetry:
         # r20 pod table: multi-controller pods as first-class rows.  A
         # pod is "degraded" when fewer live members than its declared
         # process_count — one member down stalls the whole SPMD mesh,
-        # so this is the signal the repair plane (and the kill bench
-        # phase) keys on.
+        # so this is the signal the repair plane (and
+        # tests/test_podscale.py) keys on.
         pods: dict[str, dict[str, Any]] = {}
         for url, nt in sorted(nodes.items()):
             if not nt.mesh_pod:

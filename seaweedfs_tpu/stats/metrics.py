@@ -606,8 +606,8 @@ VOLUME_SERVER_EC_DEGRADED_MEMO = Counter(
     "SeaweedFS_volumeServer_ec_degraded_memo",
     "Degraded-read reconstructed-interval memo outcomes: a 'hit' "
     "serves a previously reconstructed interval without re-gathering "
-    ">=10 survivor shards (the repair-window hot-needle fast path "
-    "bench_chaos_sweep measures); 'miss' pays the full gather + "
+    ">=10 survivor shards (the repair-window hot-needle fast path, "
+    "tests/test_ec.py holds it); 'miss' pays the full gather + "
     "reconstruct and populates the memo.",
     ["result"],
     registry=REGISTRY,
@@ -800,30 +800,6 @@ MQ_FENCE_CONFLICT = Counter(
     "KvGet->append window; offsets were resynced).",
     registry=REGISTRY,
 )
-
-
-def stage_breakdown() -> dict:
-    """{stage: {count, total_s, mean_us}} from the stage histogram —
-    bench.py's per-stage section and ops tooling read this instead of
-    re-parsing the text exposition."""
-    out: dict = {}
-    for family in REQUEST_STAGE_SECONDS.collect():
-        sums: dict = {}
-        counts: dict = {}
-        for s in family.samples:
-            stage = s.labels.get("stage")
-            if s.name.endswith("_sum"):
-                sums[stage] = s.value
-            elif s.name.endswith("_count"):
-                counts[stage] = s.value
-        for stage, c in counts.items():
-            if c:
-                out[stage] = {
-                    "count": int(c),
-                    "total_s": round(sums.get(stage, 0.0), 6),
-                    "mean_us": round(sums.get(stage, 0.0) / c * 1e6, 1),
-                }
-    return out
 
 
 def stage_histogram_snapshot() -> dict:

@@ -100,8 +100,8 @@ class BulkConfig:
     store-level maintenance verbs, not per-request serving state."""
 
     # run the reader/writer legs on dedicated threads; False = the
-    # serial baseline (every leg on the caller thread) the bench sweep's
-    # overlap-off axis measures (-ec.bulk.overlap.disable)
+    # serial baseline, every leg on the caller thread
+    # (-ec.bulk.overlap.disable)
     overlap: bool = True
     # bounded stripe-queue depth: how many read batches the reader leg
     # may run ahead of the codec (and results ahead of the writer)

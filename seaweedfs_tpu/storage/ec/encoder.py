@@ -10,7 +10,7 @@ staged executor in bulk.py: a prefetching reader leg (vectored preadv), the
 codec worker (device H2D/kernel/D2H or the CPU kernel), and a dedicated
 writer leg, so host read, matrix math, and shard write all overlap —
 measured overlap, not just async dispatch (see the stats contract in
-bulk.py; bench.py's bulk sweep publishes the proof).
+bulk.py; benchmark/layer_metrics/bulk_*_leg_pct.*.json read the legs).
 
 File formats are byte-identical to the reference, so `.ec00-.ec13` produced
 here can be mounted by a Go volume server and vice versa.

@@ -217,9 +217,9 @@ class EcVolume:
         # reconstructed-interval memo: while a shard is missing, the
         # zipf-hot needles hit the SAME (sid, off, size) interval over
         # and over, and every reconstruct pays a >=10-shard survivor
-        # gather (remote under spread placement).  bench_chaos_sweep
-        # measured that as a sustained ~3x read-p99 cliff for the whole
-        # repair window.  Shard content is immutable once encoded
+        # gather (remote under spread placement), for the whole repair
+        # window (an earlier rig's CPU sweep saw ~3x read p99; no ledger
+        # line measures it).  Shard content is immutable once encoded
         # (deletes are .ecj tombstones, never byte rewrites), so ADDING
         # a shard never invalidates the memo — repair re-mounting a
         # shard mid-window must NOT wipe the hot set (the re-gather

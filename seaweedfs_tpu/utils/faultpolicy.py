@@ -36,8 +36,8 @@ Every decision is observable: the five
 `SeaweedFS_volumeServer_ec_{hedge_sent,hedge_wins,hedge_cancelled,
 deadline_exceeded,retry_budget_exhausted}_total` series, r17
 flight-recorder events (`hedge`, `deadline_exceeded`,
-`retry_budget`), and process-local `totals()` the netchaos bench
-reads.  Reference: SeaweedFS guards every gRPC hop with
+`retry_budget`), and process-local `totals()` the netchaos tests
+read.  Reference: SeaweedFS guards every gRPC hop with
 per-RPC timeouts (wdclient/operation, SURVEY §1); the hedging is the
 classic erasure-coded tail-latency play (Dean & Barroso, "The Tail at
 Scale").
@@ -114,7 +114,7 @@ class FaultPolicyConfig:
 CONFIG = FaultPolicyConfig()
 
 # process-local decision totals, mirrored to the Prometheus series;
-# the netchaos bench reads these (LocalCluster is in-process)
+# the netchaos tests read these (LocalCluster is in-process)
 _TOTALS_LOCK = threading.Lock()
 _TOTALS = {
     "hedge_sent": 0,

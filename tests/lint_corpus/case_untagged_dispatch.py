@@ -50,7 +50,7 @@ def attribution_aware_by_consult(a_blk, flat):
     return _scrub_call_blockdiag(a_blk, flat, groups=8)  # noqa: F821
 
 
-def waived_bench_thunk(vec, a_prep, survivors):
-    # graftlint: allow(untagged-device-dispatch): bench measured region
-    # — timed externally, deliberately unattributed
+def waived_probe(vec, a_prep, survivors):
+    # graftlint: allow(untagged-device-dispatch): a probe's measured
+    # region — timed externally, deliberately unattributed
     return _dispatch_call("xla", vec, a_prep, survivors)  # noqa: F821

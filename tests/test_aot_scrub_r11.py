@@ -8,9 +8,8 @@ the host path — byte-equal, counted, and never blocked behind a
 compile — while the background executor compiles the shape,
 scrub_all_resident matching the per-volume verdicts in one device pass,
 the packed [N] meta halving the staged H2D bytes, and the
-observed-shape / compile-cache persistence satellites.  The real-TPU
-numbers ride bench.py (scrub_all_vs_per_volume sweep, timed
-compile-miss guard, donation H2D verdict).
+observed-shape / compile-cache persistence satellites.  On the chip the
+scrub runs in chip_smoke.py only; no cell times it yet (PERF.md).
 """
 import json
 import os

@@ -6,8 +6,8 @@ interpret) against the numpy oracle, the blockdiag parity scrub, the
 DevicePipeline's staging-slot semantics and overlap accounting,
 eviction/unmount racing an in-flight batch, warm()'s observed-bucket
 prioritization, and the e2e three-way byte equality (blockdiag vs flat
-vs host reconstruct) through the real volume server.  The real-TPU
-numbers come from bench.py's serving sweep layout/overlap matrix.
+vs host reconstruct) through the real volume server.  On the chip only
+blockdiag with overlap on has been measured (PERF_LEDGER.jsonl).
 """
 import asyncio
 import os
@@ -414,12 +414,11 @@ def test_e2e_blockdiag_flat_host_byte_equal(tmp_path):
     pipeline's new series are live on /metrics."""
     import aiohttp
 
-    from bench import build_degraded_cluster
+    from degraded_cluster import build_degraded_cluster
 
     async def go():
         cluster, vs, blobs, _vid = await build_degraded_cluster(
-            str(tmp_path), n_blobs=8, device_cache=True,
-            cache_budget=1 << 30, warm_sizes=(),
+            str(tmp_path), n_blobs=8, device_cache=True
         )
         try:
             cache = vs.store.ec_device_cache

@@ -3,9 +3,9 @@ dispatcher / stage-digest telemetry on every heartbeat pulse; the master
 aggregates it into /cluster/health.json and SeaweedFS_cluster_* gauges,
 flagging nodes that miss heartbeats as stale.
 
-The e2e uses bench.build_degraded_cluster (the canonical degrade
-choreography) with warm_sizes=() per CI convention, so the XLA-fallback
-kernels compile in milliseconds at first use.
+The e2e uses degraded_cluster.build_degraded_cluster (the canonical
+degrade choreography, no warm plan), so the XLA-fallback kernels compile
+in milliseconds at first use.
 """
 import asyncio
 import time
@@ -243,14 +243,22 @@ def test_cluster_health_e2e(tmp_path):
     whose p99 estimate matches the per-server request_stage_seconds
     histogram; a node that stops heartbeating flags stale within 2
     intervals; the shell renders the same view."""
-    from bench import build_degraded_cluster
+    from degraded_cluster import build_degraded_cluster
+
+    def _device_failures():
+        from seaweedfs_tpu.ops import rs_resident
+
+        with rs_resident._device_failures_lock:
+            return {
+                k: v["count"] for k, v in rs_resident._device_failures.items()
+            }
 
     async def go():
         from seaweedfs_tpu.repair import RepairConfig
 
+        failures_before = _device_failures()
         cluster, vs, blobs, vid = await build_degraded_cluster(
             str(tmp_path), n_blobs=8, device_cache=True,
-            cache_budget=1 << 30, warm_sizes=(),
             # the master's autonomous repair rebuilds the two dropped
             # shards within its 5 s scan: the 12-resident-shard
             # assertions below would race it on a loaded box
@@ -294,10 +302,21 @@ def test_cluster_health_e2e(tmp_path):
                 reg_cum, _ = reg_snap[stage]
                 deadline = time.time() + 15
                 health = await fetch_health()
-                while time.time() < deadline:
+
+                def landed(health):
+                    # the pulse that carries the reads' stage samples AND
+                    # the cache as the dropped shards left it: a pulse
+                    # built before drop_shards still says 14
                     stages = health["cluster"]["stages"]
-                    if stages.get(stage, {}).get("count", 0) >= reg_cum[-1]:
-                        break
+                    dev = health["nodes"].get(vs.url, {}).get("device", {})
+                    residency = health["cluster"]["ec_volume_residency"]
+                    return (
+                        stages.get(stage, {}).get("count", 0) >= reg_cum[-1]
+                        and dev.get("resident_shards") == 12
+                        and residency.get(str(vid), {}).get(vs.url) == 12
+                    )
+
+                while time.time() < deadline and not landed(health):
                     await asyncio.sleep(0.5)
                     health = await fetch_health()
 
@@ -366,7 +385,13 @@ def test_cluster_health_e2e(tmp_path):
                 # the node's /status Device block: which accelerator,
                 # what the backend resolved to, swallowed failures
                 assert "device: platform=cpu kind='cpu'" in out
-                assert "device failures: pin=0 warm=0 aot=0" in out
+                # (process-lifetime counters, and under xdist another
+                # file's synthetic compile failure may have run in this
+                # process: this cluster must have added none)
+                assert _device_failures() == failures_before
+                assert "device failures: " + " ".join(
+                    f"{k}={n}" for k, n in failures_before.items()
+                ) in out
 
                 # node goes silent: heartbeats stop, the master flags it
                 # stale within 2 intervals (pulse=1s -> stale_after=2s)

@@ -104,31 +104,34 @@ def test_disabled_ledger_records_nothing():
 # --------------------------------------------------------- conservation
 
 
-def test_pipeline_slot_conserves_into_ledger():
+@pytest.mark.parametrize(
+    "wl", [w for w in devledger.WORKLOADS if w != devledger.UNTAGGED]
+)
+def test_pipeline_slot_conserves_into_ledger(wl):
     """slot() records the identical duration into total_busy_s and the
-    ledger, so the per-class sum equals the pipeline clock exactly."""
+    ledger, so the per-class sum equals the pipeline clock exactly —
+    under every named class: none falls to `untagged`."""
     from seaweedfs_tpu.ops.rs_resident import DevicePipeline
 
     pipe = DevicePipeline(slots=2)
-    with devledger.workload("serving_interactive", device="default"):
+    with devledger.workload(wl, device="default"):
         for _ in range(3):
             with pipe.slot():
                 time.sleep(0.002)
     busy = devledger.LEDGER.busy_by_workload()
-    assert set(busy) == {"serving_interactive"}
-    assert busy["serving_interactive"] == pytest.approx(
-        pipe.total_busy_s, rel=1e-9
-    )
+    assert set(busy) == {wl}
+    assert busy[wl] == pytest.approx(pipe.total_busy_s, rel=1e-9)
     assert pipe.total_busy_s > 0
     # and total_busy_s is cumulative across overlap windows (never the
     # windowed _busy_s the gauge resets)
     before = pipe.total_busy_s
-    with devledger.workload("scrub"):
+    other = "scrub" if wl != "scrub" else "repair"
+    with devledger.workload(other):
         with pipe.slot():
             time.sleep(0.001)
     assert pipe.total_busy_s > before
     busy = devledger.LEDGER.busy_by_workload()
-    assert busy["serving_interactive"] + busy["scrub"] == pytest.approx(
+    assert busy[wl] + busy[other] == pytest.approx(
         pipe.total_busy_s, rel=1e-9
     )
 

@@ -181,6 +181,56 @@ class TestEncodeDecode:
         for f in files.values():
             f.close()
 
+    def test_degraded_memo_serves_repeat_reads_without_regather(
+        self, tmp_path
+    ):
+        """While a shard is lost cluster-wide, a repeat read of the same
+        interval is a memo hit (ec_degraded_memo{result}): the same
+        bytes, no second survivor gather off the peers — and a shard
+        mounting mid-window does not wipe the hot set (shard content is
+        immutable once encoded)."""
+        from seaweedfs_tpu import stats
+
+        def memo(result):
+            return stats.REGISTRY.get_sample_value(
+                "SeaweedFS_volumeServer_ec_degraded_memo_total",
+                {"result": result},
+            ) or 0
+
+        v, blobs = make_volume(tmp_path, count=6)
+        base = encode_volume(v)
+        files = {i: open(base + ec.to_ext(i), "rb") for i in range(1, 14)}
+        calls = []
+
+        def remote(shard_id, off, size):
+            calls.append(shard_id)
+            if shard_id not in files:  # shard 0: lost everywhere
+                return None
+            return os.pread(files[shard_id].fileno(), size, off)
+
+        ev = ec.EcVolume(str(tmp_path), v.id)
+        for i in range(1, 6):
+            ev.add_shard(i)
+        try:
+            hit0, miss0 = memo("hit"), memo("miss")
+            for nid, (cookie, data) in blobs.items():
+                got = ev.read_needle(nid, cookie=cookie, remote_read=remote)
+                assert got.data == data
+            assert memo("miss") > miss0 and memo("hit") == hit0
+            misses, gathered = memo("miss"), len(calls)
+            ev.add_shard(6)  # a repair re-mounts a shard mid-window
+            for nid, (cookie, data) in blobs.items():
+                got = ev.read_needle(nid, cookie=cookie, remote_read=remote)
+                assert got.data == data
+            assert memo("miss") == misses, "a repeat interval re-gathered"
+            assert memo("hit") - hit0 == misses - miss0
+            # the only peer traffic left is the probe of lost shard 0
+            assert set(calls[gathered:]) <= {0}
+        finally:
+            ev.close()
+            for f in files.values():
+                f.close()
+
     def test_rebuild_byte_equivalence(self, tmp_path):
         v, _ = make_volume(tmp_path)
         base = encode_volume(v)

@@ -74,6 +74,45 @@ class TestDeadline:
         t = fp.totals()
         assert t["deadline_exceeded"] == 2
 
+    def test_doomed_read_is_refused_at_the_front_door(
+        self, tmp_path, fresh_policy
+    ):
+        """End to end: a degraded GET whose X-Seaweed-Deadline-Ms budget
+        is already spent when it reaches EC read admission is refused
+        with a 504 that carries its trace id, counted once in
+        deadline_exceeded; the same GET without a budget of its own
+        comes back byte-equal."""
+        import aiohttp
+
+        from degraded_cluster import build_degraded_cluster
+        from seaweedfs_tpu.obs.trace import TRACE_HEADER
+
+        async def go():
+            cluster, vs, blobs, _vid = await build_degraded_cluster(
+                str(tmp_path), n_blobs=6
+            )
+            try:
+                fid, data = next(iter(blobs.items()))
+                url = f"http://{vs.url}/{fid}"
+                async with aiohttp.ClientSession() as sess:
+                    refused0 = fp.totals()["deadline_exceeded"]
+                    # 100 ns: spent before any handler code has run
+                    async with sess.get(
+                        url, headers={fp.DEADLINE_HEADER: "0.0001"}
+                    ) as r:
+                        assert r.status == 504, await r.text()
+                        assert r.headers.get(TRACE_HEADER)
+                    assert (
+                        fp.totals()["deadline_exceeded"] == refused0 + 1
+                    )
+                    async with sess.get(url) as r:
+                        assert r.status == 200
+                        assert await r.read() == data
+            finally:
+                await cluster.stop()
+
+        asyncio.run(go())
+
     def test_parse_deadline_ms_rejects_garbage(self, fresh_policy):
         assert fp.parse_deadline_ms("250") == 250.0
         assert fp.parse_deadline_ms("") is None

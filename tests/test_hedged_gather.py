@@ -71,11 +71,13 @@ class TestHedgePolicy:
             hedge_quantile=0.95, hedge_budget_pct=100.0
         ))
         peers = {s: f"p{s}" for s in range(6)}
-        _prime(peers.values())
+        # the history says 200 ms a fetch and these return at once: a
+        # loaded worker that schedules a fetch late is still a fifth of
+        # a second under every peer's quantile
+        _prime(peers.values(), 0.2)
         pool = ThreadPoolExecutor(8)
 
         def fast(sid):
-            time.sleep(0.003)
             return b"d%d" % sid
 
         res = fp.hedged_gather(
@@ -94,19 +96,25 @@ class TestHedgePolicy:
         # pin the cheapest-first ordering: the soon-to-be-slow peer
         # looks CHEAP (a tail event, not a known-slow peer) and the
         # spares look dearer, so sid 0 is deterministically a primary
-        # and sids 3-5 are the spares
-        _prime(["p0", "p1", "p2"], 0.003)
-        _prime(["p3", "p4", "p5"], 0.006)
+        # and sids 3-5 are the spares.  The history says 50 ms a fetch
+        # and the healthy ones return at once, so on a loaded worker it
+        # is still sid 0 alone that crosses its quantile; sid 0 hangs
+        # until the gather is over
+        _prime(["p0", "p1", "p2"], 0.05)
+        _prime(["p3", "p4", "p5"], 0.1)
         pool = ThreadPoolExecutor(8)
+        gathered = threading.Event()
 
         def one_slow(sid):
-            time.sleep(0.25 if sid == 0 else 0.003)
+            if sid == 0:
+                gathered.wait(10)
             return b"d%d" % sid
 
         res = fp.hedged_gather(
             3, [0, 1, 2, 3, 4, 5], one_slow, pool=pool,
             peer_of=peers.get,
         )
+        gathered.set()
         assert len(res.got) == 3 and 0 not in res.got
         assert res.hedges_sent >= 1
         assert res.hedge_wins >= 1  # the spare beat the slow primary
@@ -119,11 +127,13 @@ class TestHedgePolicy:
         fp.configure(fp.FaultPolicyConfig(
             hedge_quantile=0.95, hedge_budget_pct=100.0
         ))
-        _prime([f"p{s}" for s in range(4)], 0.02)
+        # 0.3 s against a history of 0.4 s (quantile ~0.49 s): slow all
+        # alike, and far enough under the threshold for a loaded worker
+        _prime([f"p{s}" for s in range(4)], 0.4)
         pool = ThreadPoolExecutor(4)
 
         def fetch(sid):
-            time.sleep(0.02)
+            time.sleep(0.3)
             return b"d%d" % sid
 
         res = fp.hedged_gather(

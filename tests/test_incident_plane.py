@@ -4,8 +4,8 @@ serves them, the master's SLO engine burns against live telemetry, and
 a violation writes ONE correlated incident bundle — plus the on-demand
 device endpoints (/debug/device/hot, SWFS_DEBUG-gated /debug/profile).
 
-The e2e rides the same LocalCluster + EC spread choreography as the
-bench (warm-free native backend: no device compiles) with second-scale
+The e2e rides degraded_cluster's LocalCluster + EC spread choreography
+(warm-free native backend: no device compiles) with second-scale
 SLO windows so the burn fires within a few pulses.
 """
 from __future__ import annotations
@@ -127,7 +127,7 @@ async def _encode_spread(cluster, vid):
     small volume's every needle) to the OTHER volume server, so reads
     against the holder must fetch remote shards over gRPC: the genuine
     cross-server trace the correlation check wants."""
-    from bench import _chaos_encode_spread
+    from degraded_cluster import chaos_encode_spread
 
     holder = next(
         vs for vs in cluster.volume_servers if vs.store.has_volume(vid)
@@ -136,7 +136,7 @@ async def _encode_spread(cluster, vid):
         i for i, vs in enumerate(cluster.volume_servers)
         if vs is not holder
     )
-    await _chaos_encode_spread(cluster, vid, victim_idx=victim_idx)
+    await chaos_encode_spread(cluster, vid, victim_idx=victim_idx)
     return holder
 
 

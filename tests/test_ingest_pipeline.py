@@ -231,6 +231,35 @@ class _Counting:
         self.syncs += 1
 
 
+def test_stream_encoder_sheds_a_cold_row_then_serves_it_from_the_device():
+    """A live write never compiles inline: a row width the AOT registry
+    does not hold raises ColdShape (the caller encodes that row on the
+    host) and arms the compile off the write path; once parked, the same
+    width encodes on the device, byte-equal to the host codec."""
+    from seaweedfs_tpu.ops import rs, rs_ingest, rs_resident
+
+    enc = rs_ingest.StreamEncoder(backend="xla")
+    block = 5 * 1024 + 384  # a width no other test warms
+    rows = np.random.default_rng(23).integers(
+        0, 256, size=(10, block), dtype=np.uint8
+    )
+    want = rs.RSCodec(backend="numpy").encode_all(rows)[10:]
+    assert not enc.shape_is_warm(block)
+    with pytest.raises(rs_resident.ColdShape):
+        enc.encode(rows)
+    assert enc.device_rows == 0
+    np.testing.assert_array_equal(enc.encode_host(rows), want)
+    assert enc.host_rows == 1
+    # the shed armed the compile on the background executor: wait for
+    # the registry, not for a clock
+    deadline = time.time() + 120
+    while not enc.shape_is_warm(block) and time.time() < deadline:
+        time.sleep(0.05)
+    assert enc.shape_is_warm(block), rs_resident.aot_stats()
+    np.testing.assert_array_equal(enc.encode(rows), want)
+    assert (enc.device_rows, enc.host_rows) == (1, 1)
+
+
 def test_group_commit_batches_and_dedups_per_volume():
     """12 writers over 2 volumes pile into shared batches: every writer
     is acked, but the flusher issued FEWER syncs than writers (one per
@@ -312,6 +341,60 @@ def test_doomed_upload_refused_at_the_door():
             plane2.close()
     finally:
         plane.close()
+
+
+def test_s3_put_under_a_write_tier_rides_the_ingest_plane(tmp_path):
+    """The front doors' wiring, end to end: an S3 PutObject stamped
+    X-Seaweed-QoS: bulk reaches the volume server's ingest plane (its
+    bytes are counted in ingest_bytes_total, its admission under the
+    bulk write tier) and reads back byte-equal."""
+    import asyncio
+
+    import aiohttp
+
+    from seaweedfs_tpu.server.cluster import LocalCluster
+
+    payload = np.random.default_rng(31).integers(
+        0, 256, 40_000, dtype=np.uint8
+    ).tobytes()
+
+    async def go():
+        cluster = LocalCluster(
+            base_dir=str(tmp_path), n_volume_servers=1, with_s3=True
+        )
+        await cluster.start()
+        try:
+            plane = cluster.volume_servers[0].ingest
+            admitted = []
+            admit = plane.admit
+
+            def spy(tier, nbytes, remaining_s):
+                admitted.append(plane._normalize(tier))
+                return admit(tier, nbytes, remaining_s)
+
+            plane.admit = spy
+            bytes0 = _sample("SeaweedFS_volumeServer_ingest_bytes_total")
+            base = f"http://{cluster.s3.url}/ingest"
+            async with aiohttp.ClientSession() as sess:
+                async with sess.put(base) as r:
+                    assert r.status == 200
+                async with sess.put(
+                    f"{base}/obj", data=payload,
+                    headers={"X-Seaweed-QoS": "bulk"},
+                ) as r:
+                    assert r.status == 200
+                async with sess.get(f"{base}/obj") as r:
+                    assert r.status == 200
+                    assert await r.read() == payload
+            assert admitted and set(admitted) == {"bulk"}
+            assert (
+                _sample("SeaweedFS_volumeServer_ingest_bytes_total") - bytes0
+                >= len(payload)
+            )
+        finally:
+            await cluster.stop()
+
+    asyncio.run(go())
 
 
 def test_bulk_write_tier_binds_first_under_pressure():

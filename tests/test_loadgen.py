@@ -1,21 +1,26 @@
-"""Load-harness suite (seaweedfs_tpu/loadgen): the workload math unit-
-tested without sockets, and the r13 front-door smoke sweep — the
-seconds-scale CPU run of `bench.py bench_load_sweep --smoke` — invoked
-from tier-1 so the harness (cluster build, loadgen drivers, QoS +
-zero-copy toggles, S3 leg, headline contract) can't rot between the
-real benchmarked runs."""
-import json
-import os
-import subprocess
-import sys
+"""Load-harness suite (seaweedfs_tpu/loadgen, what `weed loadtest`
+drives): the workload math without sockets, and the three drivers
+(`run_http_load`, `run_mixed_http_load`, `run_s3_load`) in process against
+the tests' degraded cluster.  Counts and bytes only: a CPU run gives no
+rate and no percentile, so none is compared."""
+import asyncio
 
+import aiohttp
 import numpy as np
 import pytest
 
-from seaweedfs_tpu.loadgen import LoadScenario, zipf_ranks
+from degraded_cluster import build_degraded_cluster
+from seaweedfs_tpu import stats
+from seaweedfs_tpu.loadgen import (
+    LoadResult,
+    LoadScenario,
+    run_http_load,
+    run_mixed_http_load,
+    run_s3_load,
+    zipf_ranks,
+)
 from seaweedfs_tpu.loadgen.workload import percentile_ms, plan_keys
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from seaweedfs_tpu.repair import RepairConfig
 
 
 # ----------------------------------------------------------- workload math
@@ -66,59 +71,206 @@ def test_percentile_ms():
     assert percentile_ms(xs, 99) == pytest.approx(100.0, abs=2)
 
 
-# ------------------------------------------------------------- smoke sweep
+# ------------------------------------------------- drivers, over sockets
+
+BIG = 192 * 1024  # over the 64 KB streaming threshold of the front door
 
 
-def test_bench_load_sweep_smoke_contract():
-    """`bench.py bench_load_sweep --smoke` must complete in seconds on
-    CPU and emit the full load_headline contract: a >=4-point
-    reads/s-vs-connections curve per config, every read byte-verified,
-    zero copy-bytes on the zero-copy route, and S3 GETs attributed on
-    the resident device path."""
-    proc = subprocess.run(
-        [sys.executable, "bench.py", "bench_load_sweep", "--smoke"],
-        cwd=REPO,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"},
-        capture_output=True,
-        text=True,
-        timeout=300,
+def _counter(name, labels=None):
+    return stats.REGISTRY.get_sample_value(name, labels or {}) or 0.0
+
+
+def _degraded_cluster(tmp_path, **kwargs):
+    """One volume of twelve 4 KB blobs (the first two BIG), EC-encoded,
+    pinned in the device cache, shards 0 and 11 destroyed: every read is
+    a degraded read the resident dispatcher may take."""
+    return build_degraded_cluster(
+        str(tmp_path), n_blobs=12, device_cache=True,
+        blob_size=lambda i: BIG if i < 2 else 4096,
+        # the master would rebuild the destroyed shards by itself
+        master_kwargs={"ec_repair": RepairConfig(enabled=False)},
+        **kwargs,
     )
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    line = proc.stdout.strip().splitlines()[-1]
-    out = json.loads(line)
-    head = out["headline"]
-    assert len(out["levels"]) >= 4
-    for mode in ("pre", "qos_zero_copy"):
-        curve = out["curves"][mode]
-        assert len(curve) >= 4
-        for level in curve.values():
-            assert level["verify_failures"] == 0
-            assert level["reads_per_s"] > 0
-    assert head["load_verified"] is True
-    assert head["zero_copy_is_zero_copy"] is True
-    assert head["copy_bytes_zero_copy"] == 0
-    assert head["copy_bytes_pre"] > 0
-    assert head["s3_rides_resident_path"] is True
-    assert head["s3_resident_route_reads"] > 0
-    # the adversarial pass actually ran its adversaries
-    assert out["adversarial"]["qos_zero_copy"]["slow_connections"] >= 1
-    assert out["adversarial"]["qos_zero_copy"]["churns"] >= 1
-    # p50/p99 from the r07 stage histograms made it into the artifact
-    assert "queue_wait" in out["stage_percentiles"]
-    assert out["stage_percentiles"]["queue_wait"]["p99_us"] is not None
-    # r15 oversubscribed tiering pass: working set ~4x the shrunken
-    # device budget, heat ladder vs static pin + blind LRU
-    tier = out["tiering_headline"]
-    assert tier["oversubscribe"] == 4.0
-    assert tier["working_set_bytes"] >= 3 * tier["device_budget_bytes"]
-    assert len(tier["tier_levels"]) >= 2
-    assert tier["tiering_beats_static"] is True
-    assert tier["no_cliff"] is True
-    assert tier["tier_verified"] is True
-    # promotions happened under live load with zero compile misses and
-    # no cold-shape shed spike — stall-free by measurement, not claim
-    assert tier["tier_promotions"] > 0
-    assert tier["timed_compile_misses"] == 0
-    assert tier["promotion_stall_free"] is True
-    # the warm tier actually served bytes out of host RAM
-    assert tier["host_tier_reads"] > 0
+
+
+@pytest.mark.parametrize(
+    "qos,zero_copy", [(False, False), (True, True)],
+    ids=["copying", "qos_zero_copy"],
+)
+def test_run_http_load_in_both_front_door_modes(tmp_path, qos, zero_copy):
+    """Closed-loop readers over real sockets, zipf keys on a hot volume:
+    every planned read comes back byte-equal, and
+    response_copy_bytes_total says which body path served them: it
+    stays put with zero-copy responses and grows with the
+    bytes-materializing ones."""
+
+    async def go():
+        cluster, vs, blobs, _vid = await _degraded_cluster(tmp_path)
+        try:
+            cfg = vs.ec_dispatcher.cfg
+            cfg.qos, cfg.zero_copy = qos, zero_copy
+            copied0 = _counter(
+                "SeaweedFS_volumeServer_response_copy_bytes_total"
+            )
+            res = await run_http_load(
+                vs.url, dict(blobs),
+                LoadScenario(connections=4, reads=48, hot_volume_frac=0.5),
+            )
+            copied = _counter(
+                "SeaweedFS_volumeServer_response_copy_bytes_total"
+            ) - copied0
+        finally:
+            await cluster.stop()
+        assert (res.reads_ok, res.errors, res.verify_failures) == (48, 0, 0)
+        assert res.bytes_read >= 48 * 4096
+        assert len(res.latencies_s) == 48
+        assert res.slow_connections == 0 and res.churns == 0
+        assert (copied == 0) if zero_copy else (copied > 0), copied
+
+    asyncio.run(go())
+
+
+def test_run_http_load_adversarial_clients_still_verify(tmp_path):
+    """Half of the connections dribble their bodies and a quarter of the
+    reads reconnect first, with the streamed BIG bodies on the hot
+    ranks: the adversaries ran, a read the server cut short is an error
+    and never a wrong byte, and every read is accounted for."""
+
+    async def go():
+        cluster, vs, blobs, _vid = await _degraded_cluster(tmp_path)
+        try:
+            res = await run_http_load(
+                vs.url, dict(blobs),
+                LoadScenario(
+                    connections=4, reads=48, slow_client_frac=0.5,
+                    churn=0.25, dribble_delay_s=0.002,
+                ),
+            )
+        finally:
+            await cluster.stop()
+        assert res.slow_connections == 2 and res.churns >= 1
+        assert res.verify_failures == 0
+        assert res.reads_ok > 0 and res.reads_ok + res.errors == 48
+
+    asyncio.run(go())
+
+
+def test_run_mixed_http_load_reads_back_every_written_byte(tmp_path):
+    """Half of the ops are uploads of fresh fids that join the read key
+    stream: no write is refused, no read is wrong, the written bytes are
+    counted by the ingest plane, and every written payload reads back
+    byte-equal from its holder afterwards."""
+
+    async def go():
+        cluster, vs, blobs, _vid = await _degraded_cluster(tmp_path)
+        written: dict = {}
+        try:
+            ingested0 = _counter("SeaweedFS_volumeServer_ingest_bytes_total")
+            res = await run_mixed_http_load(
+                cluster.master.advertise_url, vs.url, dict(blobs),
+                LoadScenario(
+                    connections=4, reads=64, write_frac=0.5,
+                    write_sizes=[1024, 4096, 70_000],
+                ),
+                written=written,
+            )
+            ingested = _counter(
+                "SeaweedFS_volumeServer_ingest_bytes_total"
+            ) - ingested0
+            async with aiohttp.ClientSession() as sess:
+                for fid, (holder, data) in written.items():
+                    async with sess.get(f"http://{holder}/{fid}") as r:
+                        assert r.status == 200, fid
+                        assert await r.read() == data, fid
+        finally:
+            await cluster.stop()
+        assert res.writes_ok > 0 and res.write_errors == 0
+        assert res.writes_ok == len(written)
+        assert res.bytes_written == sum(len(d) for _, d in written.values())
+        # the writes went through the ingest plane's door, not around it
+        assert ingested >= res.bytes_written
+        assert (res.errors, res.verify_failures) == (0, 0)
+        assert res.reads_ok + res.writes_ok == 64
+
+    asyncio.run(go())
+
+
+def test_run_s3_load_rides_the_resident_route(tmp_path):
+    """The S3 leg `weed loadtest -s3` drives: GetObject through the
+    gateway against one-chunk objects on the degraded EC volumes, every
+    body byte-equal, and each read attributed to the device-resident
+    path under the s3 origin (ec_read_route_total{route="s3_batched"})."""
+    rng = np.random.default_rng(5)
+    objects = {
+        f"o{i:03d}": rng.integers(0, 256, 4096 + 977 * i, dtype=np.uint8)
+        .tobytes()
+        for i in range(4)
+    }
+
+    async def put_objects(cluster):
+        async with aiohttp.ClientSession() as sess:
+            base = f"http://{cluster.s3.url}/loadtest"
+            async with sess.put(base) as r:
+                assert r.status == 200
+            for key, data in objects.items():
+                async with sess.put(f"{base}/{key}", data=data) as r:
+                    assert r.status == 200
+
+    def s3_batched():
+        return _counter(
+            "SeaweedFS_volumeServer_ec_read_route_total",
+            {"route": "s3_batched"},
+        )
+
+    async def go():
+        cluster, _vs, _blobs, _vid = await _degraded_cluster(
+            tmp_path, with_s3=True, fill=put_objects
+        )
+        try:
+            routed0 = s3_batched()
+            res = await run_s3_load(
+                cluster.s3.url, "loadtest", dict(objects),
+                LoadScenario(connections=4, reads=32),
+            )
+            routed = s3_batched() - routed0
+        finally:
+            await cluster.stop()
+        assert (res.reads_ok, res.errors, res.verify_failures) == (32, 0, 0)
+        assert routed == 32
+
+    asyncio.run(go())
+
+
+def test_load_result_summary_carries_the_counts_loadtest_prints():
+    """`weed loadtest` prints `summary()` a level: the read counts
+    always, the write block only for a mixed run, the slowest trace ids
+    by worker, slowest first."""
+    res = LoadResult(
+        connections=2, reads_ok=3, errors=1, verify_failures=0,
+        slow_connections=1, churns=2, bytes_read=12288, wall_s=1.5,
+        latencies_s=[0.001, 0.002, 0.003],
+    )
+    res.note_trace(res.slow_read_trace, 0, 0.002, "aaaa-01")
+    res.note_trace(res.slow_read_trace, 0, 0.001, "bbbb-02")  # faster: kept out
+    res.note_trace(res.slow_read_trace, 1, 0.003, "cccc-03")
+    res.note_trace(res.slow_read_trace, 1, 0.009, "")  # no header: kept out
+    d = res.summary()
+    assert {k: d[k] for k in (
+        "connections", "reads_ok", "errors", "verify_failures",
+        "slow_connections", "churns", "bytes_read", "reads_per_s",
+    )} == {
+        "connections": 2, "reads_ok": 3, "errors": 1, "verify_failures": 0,
+        "slow_connections": 1, "churns": 2, "bytes_read": 12288,
+        "reads_per_s": 2.0,
+    }
+    assert [t["trace_id"] for t in d["slowest_read_traces"]] == [
+        "cccc", "aaaa",
+    ]
+    assert not any(k.startswith("write") for k in d)
+    res.writes_ok, res.bytes_written = 2, 3 << 20
+    res.write_latencies_s = [0.004, 0.005]
+    d = res.summary()
+    assert (d["writes_ok"], d["write_errors"], d["bytes_written"]) == (
+        2, 0, 3 << 20,
+    )
+    assert d["ingest_mb_per_s"] == 2.0

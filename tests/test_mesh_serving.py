@@ -240,6 +240,51 @@ def test_sharded_reconstruct_matches_oracle(encoded_big, layout):
         )
 
 
+@pytest.mark.parametrize("times_one_device", [2, 4])
+def test_working_set_beyond_one_device_stays_resident_only_when_sharded(
+    encoded, times_one_device
+):
+    """The capacity contrast the mesh exists for, in counts: with one
+    device's budget sized for ONE volume's twelve survivors, a working
+    set of 2x / 4x that stays whole lane-sharded over the mesh (no
+    eviction, every degraded read byte-equal) while whole-volume
+    pinning on one device evicts and a read of an evicted volume is a
+    CacheMiss (the store's route to the host)."""
+    down = (3, 11)
+    vids = list(range(1, times_one_device + 1))
+    reqs = [(3, 1000, 4096), (11, 200_000, 3000)]
+
+    def fill(c):
+        for vid in vids:
+            for sid in range(14):
+                if sid not in down:
+                    c.put(vid, sid, encoded[sid])
+
+    sharded = _sharded_cache()
+    one_device = 12 * sharded._padded_len(len(encoded[0]))
+    sharded.budget = sharded.n_devices * one_device
+    fill(sharded)
+    assert sharded.evictions == 0
+    assert all(sharded.resident_count(v) == 12 for v in vids)
+    assert all(b <= one_device for b in sharded._dev_bytes)
+    for vid in vids:
+        got = rs_resident.reconstruct_intervals(sharded, vid, reqs)
+        for (sid, off, size), piece in zip(reqs, got):
+            assert piece == encoded[sid][off : off + size].tobytes()
+
+    single = rs_resident.DeviceShardCache(
+        budget_bytes=one_device, shard_quantum=1 << 20
+    )
+    single.warm_sizes = ()
+    fill(single)
+    assert single.evictions > 0
+    assert single.bytes_used <= one_device
+    shed = [v for v in vids if single.resident_count(v) < 10]
+    assert shed, "one device held a working set it has no room for"
+    with pytest.raises(rs_resident.CacheMiss):
+        rs_resident.reconstruct_intervals(single, shed[0], reqs)
+
+
 def _counts(family, kinds):
     return {k: family.labels(kind=k)._value.get() for k in kinds}
 
@@ -485,8 +530,13 @@ def test_cold_sharded_shape_sheds_instead_of_compiling(encoded_big):
         rs_resident.reconstruct_intervals(c, 32, [(3, 0, 400000)])
 
 
-def test_make_batched_call_sharded_thunk_matches_oracle(encoded_big):
-    from seaweedfs_tpu.ops import rs_tpu
+def test_homogeneous_sharded_batch_is_one_call_and_matches_oracle(
+    encoded_big,
+):
+    """Eight 4 KiB requests of one size bucket against a lane-sharded
+    volume are ONE sharded device call (a hit or a miss of
+    ec_device_compile_total), byte-equal to the oracle."""
+    from test_rs_resident import _device_calls as calls
 
     c = _sharded_cache(layout="blockdiag")
     for sid in range(14):
@@ -495,14 +545,11 @@ def test_make_batched_call_sharded_thunk_matches_oracle(encoded_big):
     rng = np.random.default_rng(6)
     L = encoded_big[1].shape[0]
     reqs = [(1, int(rng.integers(0, L - 8192)), 4096) for _ in range(8)]
-    thunk = rs_resident.make_batched_call(c, 33, reqs)
-    out = np.asarray(thunk()).reshape(-1)
-    # cross-check through the serving path (same compiled shape)
+    calls0 = calls()
     got = rs_resident.reconstruct_intervals(c, 33, reqs)
+    assert calls() - calls0 == 1
     for (sid, off, size), piece in zip(reqs, got):
         assert piece == encoded_big[sid][off : off + size].tobytes()
-    assert out.size > 0
-    assert rs_tpu is not None
 
 
 # ----------------------------------------------- tiering per-device fit

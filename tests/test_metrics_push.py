@@ -12,6 +12,7 @@ import asyncio
 import aiohttp
 from aiohttp import web
 
+from seaweedfs_tpu.operation.ready import wait_cluster_ready
 from seaweedfs_tpu.s3api import S3ApiServer
 from seaweedfs_tpu.server.filer import FilerServer
 from seaweedfs_tpu.server.master import MasterServer
@@ -91,6 +92,10 @@ def test_all_server_roles_push_metrics(tmp_path):
         )
         await s3.start()
         try:
+            # the volume server registers on its first heartbeat, some
+            # time after its ports answer: a write sent before that
+            # fails at assign with a 500
+            await wait_cluster_ready(master.url)
             # generate some traffic so counters are non-empty
             async with aiohttp.ClientSession() as s:
                 async with s.put(

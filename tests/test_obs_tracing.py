@@ -3,9 +3,9 @@ the filer's inbound request, its chunk fan-out to the volume server, and
 the volume server's EC serving stages (dispatcher queue hop included),
 all visible in /debug/traces; the per-stage histograms ride /metrics.
 
-The degraded cluster comes from bench.build_degraded_cluster (the one
-choreography shared with the benchmark, warm_sizes=() per CI convention
-so the XLA-fallback kernels compile in milliseconds at first use).
+The degraded cluster comes from degraded_cluster.build_degraded_cluster
+(the one choreography the tests share; no warm plan, so the XLA-fallback
+kernels compile in milliseconds at first use).
 """
 import asyncio
 import time
@@ -194,12 +194,11 @@ def test_trace_propagation_filer_to_volume(tmp_path):
     trace (chunk_fetch span) and a volume-role trace (queue_wait +
     device_execute + shard_read spans) under the SAME trace id, and
     /metrics exposes every stage histogram."""
-    from bench import build_degraded_cluster
+    from degraded_cluster import build_degraded_cluster
 
     async def go():
         cluster, vs, blobs, _vid = await build_degraded_cluster(
-            str(tmp_path), n_blobs=6, device_cache=True,
-            cache_budget=1 << 30, warm_sizes=(), with_filer=True,
+            str(tmp_path), n_blobs=6, device_cache=True, with_filer=True,
         )
         try:
             fs = cluster.filer

@@ -13,7 +13,7 @@
   * host-aware placement — with device_host split 4|4, whole pins land
     only on THIS process's lanes while the mesh claim for big shards
     stays a pure function of size (identical on every host);
-  * a real 2-process boundary — two `bench.py _podscale_worker`
+  * a real 2-process boundary — two `tests/podscale_worker.py`
     subprocesses join over `jax.distributed.initialize` on a CPU mesh
     and each byte-verifies the lanes it owns; a killed pod member then
     escalates the repair planner (pod_exposed), `_avoid_pods` spreads
@@ -23,10 +23,11 @@
 """
 import json
 import os
+import signal
 import socket
 import subprocess
 import sys
-import time
+import threading
 from concurrent.futures import ThreadPoolExecutor
 from types import SimpleNamespace
 
@@ -204,57 +205,114 @@ def _worker_env(n_local_devices: int) -> dict:
     return env
 
 
-def test_two_process_mesh_spans_hosts_and_byte_verifies():
-    """Two real `bench.py _podscale_worker` processes join over
-    `jax.distributed.initialize` (4 forced CPU devices each), stage the
-    same seeded working set in SPMD lockstep, and each byte-verifies
-    every lane it owns.  Together they must present one 8-lane pod:
-    disjoint local lanes covering the full mesh, zero mismatches."""
-    bench = os.path.join(os.path.dirname(__file__), "..", "bench.py")
+N_POD_VOLUMES = 2
+
+
+def _spawn_pod(process_count: int, hold: bool) -> list:
+    """Start `process_count` real `tests/podscale_worker.py` processes
+    (4 forced CPU devices each) that join over
+    `jax.distributed.initialize` and stage the same seeded working set
+    in SPMD lockstep; with `hold` each keeps its lanes after reporting."""
+    worker = os.path.join(os.path.dirname(__file__), "podscale_worker.py")
     port = _free_port()
-    procs = []
-    for rank in range(2):
-        cfg = {
-            "process_id": rank,
-            "process_count": 2,
-            "coordinator": f"127.0.0.1:{port}",
-            "n_volumes": 2,
-            "shard_kb": 16,
-            "seed": 20260808,
-            "hold": False,
-        }
-        procs.append(
-            subprocess.Popen(
-                [sys.executable, bench, "_podscale_worker", json.dumps(cfg)],
-                stdout=subprocess.PIPE,
-                stderr=subprocess.PIPE,
-                env=_worker_env(4),
-                cwd=os.path.dirname(bench),
-                text=True,
-            )
+    return [
+        subprocess.Popen(
+            [
+                sys.executable,
+                worker,
+                json.dumps({
+                    "process_id": rank,
+                    "process_count": process_count,
+                    "coordinator": f"127.0.0.1:{port}",
+                    "n_volumes": N_POD_VOLUMES,
+                    "shard_kb": 16,
+                    "seed": 20260808,
+                    "hold": hold,
+                }),
+            ],
+            stdout=subprocess.PIPE,
+            stderr=subprocess.PIPE,
+            env=_worker_env(4),
+            text=True,
         )
-    outs = []
+        for rank in range(process_count)
+    ]
+
+
+def _reports(procs: list) -> dict:
+    """rank -> the one JSON line each worker prints.  A worker that dies
+    first fails the test with its stderr; one that hangs is killed at
+    240 s, which ends its stdout and fails the same way."""
+    watchdog = threading.Timer(240, lambda: [p.kill() for p in procs])
+    watchdog.start()
     try:
+        by_rank = {}
         for p in procs:
-            out, err = p.communicate(timeout=240)
-            assert p.returncode == 0, f"worker failed:\n{err[-2000:]}"
-            outs.append(json.loads(out.strip().splitlines()[-1]))
-    finally:
-        for p in procs:
-            if p.poll() is None:
+            line = p.stdout.readline()
+            if not line.strip():
                 p.kill()
-    by_rank = {o["rank"]: o for o in outs}
-    assert set(by_rank) == {0, 1}
-    for o in outs:
+                _, err = p.communicate()
+                raise AssertionError(f"worker failed:\n{err[-2000:]}")
+            report = json.loads(line)
+            by_rank[report["rank"]] = report
+    finally:
+        watchdog.cancel()
+    assert set(by_rank) == set(range(len(procs)))
+    return by_rank
+
+
+def _reap(procs: list) -> None:
+    for p in procs:
+        if p.poll() is None:
+            p.kill()
+        p.communicate()
+
+
+def test_two_process_mesh_spans_hosts_and_byte_verifies():
+    """Two processes, each byte-verifying every lane it owns, must
+    present one 8-lane pod: disjoint local lanes covering the full
+    mesh, zero mismatches — and the pod holds the whole working set
+    (the per-chip budget is sized so that eight lanes hold exactly it)
+    without one eviction.  Rank 1 is then SIGKILLed while it holds its
+    lanes: it dies of the signal, not of a fault of its own."""
+    procs = _spawn_pod(2, hold=True)
+    try:
+        by_rank = _reports(procs)
+        procs[1].kill()
+        assert procs[1].wait(timeout=60) == -signal.SIGKILL
+        assert procs[0].poll() is None, "the survivor went down with it"
+    finally:
+        _reap(procs)
+    for o in by_rank.values():
         assert o["n_devices"] == N_DEV, "each member sees the POD mesh"
         assert o["n_hosts"] == 2 and o["multiprocess"]
         assert o["all_mesh_placed"]
         assert o["lanes_checked"] > 0
         assert o["lane_mismatches"] == 0, "cross-host lane bytes wrong"
+        assert o["resident_volumes"] == N_POD_VOLUMES
+        assert o["evictions"] == 0
     lanes0 = set(by_rank[0]["local_lanes"])
     lanes1 = set(by_rank[1]["local_lanes"])
     assert lanes0 | lanes1 == set(range(N_DEV))
     assert not (lanes0 & lanes1), "hosts must own disjoint lanes"
+
+
+def test_one_process_sheds_the_working_set_the_pod_holds():
+    """Capacity scales with process count: the same working set under
+    the same per-chip budget on ONE process (four lanes) cannot stay
+    resident — it evicts and ends with fewer whole volumes — while its
+    lanes still byte-verify."""
+    procs = _spawn_pod(1, hold=False)
+    try:
+        (o,) = _reports(procs).values()
+        assert procs[0].wait(timeout=60) == 0
+    finally:
+        _reap(procs)
+    assert o["n_devices"] == N_DEV // 2 and o["n_hosts"] == 1
+    assert not o["multiprocess"]
+    assert o["evictions"] > 0
+    assert o["resident_volumes"] < N_POD_VOLUMES
+    assert o["lane_mismatches"] == 0
 
 
 # ------------------------------------------- killed member -> repair plane
@@ -331,20 +389,26 @@ def test_hedge_prefers_spare_outside_the_slow_pod(fresh_policy):
     pods = {0: "podA", 1: "podB", 2: "podA", 3: "podB"}
     rng = np.random.default_rng(9)
     # primaries (0, 1) look cheap, spares (2, 3) dearer — sid 0 is
-    # deterministically a primary and 2/3 are the spare pool
-    for p, base in (("p0", 0.003), ("p1", 0.003), ("p2", 0.006), ("p3", 0.006)):
+    # deterministically a primary and 2/3 are the spare pool.  The
+    # history says 50 ms a fetch and the healthy ones return at once,
+    # so on a loaded worker it is still sid 0 alone that crosses its
+    # quantile; sid 0 hangs until the gather is over
+    for p, base in (("p0", 0.05), ("p1", 0.05), ("p2", 0.1), ("p3", 0.1)):
         for _ in range(30):
             fp.PEER_LATENCY.observe(p, base * (0.75 + 0.5 * rng.random()))
     pool = ThreadPoolExecutor(8)
+    gathered = threading.Event()
 
     def one_slow(sid):
-        time.sleep(0.3 if sid == 0 else 0.003)
+        if sid == 0:
+            gathered.wait(10)
         return b"d%d" % sid
 
     res = fp.hedged_gather(
         2, [0, 1, 2, 3], one_slow, pool=pool,
         peer_of=peers.get, pod_of=pods.get,
     )
+    gathered.set()
     pool.shutdown(wait=True)
     assert len(res.got) == 2 and 0 not in res.got
     assert 3 in res.got, "spare must come from outside the slow pod"

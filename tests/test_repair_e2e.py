@@ -183,14 +183,24 @@ def test_kill_volume_server_autonomous_reconvergence(tmp_path):
             await chaos.kill_volume_server(victim_idx)
             await asyncio.sleep(0.3)
             front._ec_locations.clear()
-            # degraded but recoverable (the victim held < 4 shards)
+            # degraded but recoverable (the victim held < 4 shards),
+            # and every read is byte-exact while it is
             assert len(_held_sids(cluster.master, vid)) >= 10
+            await _verify_reads(front, blobs)
 
             # the repair plane converges on its own
             await _wait_full_redundancy(
                 cluster.master, vid, exclude_urls=(victim_url,)
             )
             sched = cluster.master.repair
+            # the census is whole as soon as the rebuilt shards are
+            # mounted; the job is counted when its executor returns
+            deadline = time.monotonic() + 10
+            while (
+                sched.totals["completed"] < 1
+                and time.monotonic() < deadline
+            ):
+                await asyncio.sleep(0.1)
             assert sched.totals["completed"] >= 1
             front._ec_locations.clear()
             await _verify_reads(front, blobs)

@@ -1,8 +1,8 @@
 """Lockwatch + viewguard stress for the repair plane: the executor's
 shard lifecycle (unmount/delete -> rebuilt re-mount, what a repair job
 does to a holder) racing zero-copy batched reads and tier-style device
-evict/re-pin cycles — the exact interleaving the chaos harness creates
-when `bench_chaos_sweep` repairs a volume WHILE the load sweep reads it.
+evict/re-pin cycles — the exact interleaving a repair creates when it
+rebuilds a volume WHILE front-door load reads it.
 
 Invariants under the race (the sanitizers earn their keep on a real
 schedule, per ROADMAP item 3):

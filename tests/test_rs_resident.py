@@ -2,8 +2,8 @@
 
 Validates ops/rs_resident.py against the numpy oracle and the EcVolume
 wiring (resident fast path + read_needles_batch coalescing).  Runs on the
-CPU test mesh (Pallas interpret / XLA); the real-TPU latency claim is
-measured by bench.py's degraded_p99_ms_device_resident config.
+CPU test mesh (Pallas interpret / XLA); the chip's numbers are the
+benchmark's GET cells (PERF_LEDGER.jsonl).
 """
 import random
 
@@ -74,6 +74,14 @@ TRANSFER_KINDS = ("h2d_async", "h2d_waited", "d2h_shard_fetched",
 def transfers():
     family = stats_metrics.VOLUME_SERVER_EC_DEVICE_TRANSFERS
     return {k: family.labels(kind=k)._value.get() for k in TRANSFER_KINDS}
+
+
+def _device_calls():
+    """Device calls dispatched so far: each is one hit or one miss."""
+    family = stats_metrics.VOLUME_SERVER_EC_DEVICE_COMPILE
+    return sum(
+        family.labels(result=r)._value.get() for r in ("hit", "miss")
+    )
 
 
 def five_buckets(length):
@@ -225,21 +233,21 @@ class TestReconstruct:
         for (sid, off, size), out in zip(reqs, outs):
             assert out == coded[sid][off : off + size].tobytes()
 
-    def test_make_batched_call_shapes(self, coded):
+    def test_homogeneous_batch_is_one_call_under_both_kernels(self, coded):
+        """Eight 4 KiB requests of one size bucket are ONE device call
+        (a hit or a miss of ec_device_compile_total, what the
+        benchmark's device_calls_per_get reads) under the fused and the
+        gather kernel, byte-equal to the oracle."""
         cache = fill_cache(coded, missing=(3,))
-        # offsets FUSED_ALIGN-aligned so the raw device array starts at
-        # the requested byte under both the fused and gather paths
         reqs = [(3, 4096 * i, 4096) for i in range(8)]
         for kernel in ("pallas", "xla"):
-            thunk = rs_resident.make_batched_call(
+            calls0 = _device_calls()
+            outs = rs_resident.reconstruct_intervals(
                 cache, 7, reqs, kernel=kernel, interpret=True
             )
-            out = np.asarray(thunk()).reshape(8, -1)  # flat D2H by design
-            assert out.shape[1] >= 4096
-            for i in range(8):
-                assert (
-                    out[i, : 4096] == coded[3][4096 * i : 4096 * i + 4096]
-                ).all()
+            assert _device_calls() - calls0 == 1
+            for (sid, off, size), out in zip(reqs, outs):
+                assert out == coded[sid][off : off + size].tobytes()
 
     def test_cache_miss(self, coded):
         cache = fill_cache(coded, missing=range(5, 14))
@@ -249,6 +257,44 @@ class TestReconstruct:
     def test_empty_requests(self, coded):
         cache = fill_cache(coded)
         assert rs_resident.reconstruct_intervals(cache, 7, []) == []
+
+
+def test_serving_warm_grid_covers_timed_needle_shapes():
+    """Every fetch-ladder shape a 4KB needle read can produce (any
+    sub-FUSED_ALIGN alignment) is covered by a warm grid of
+    warm_sizes=(4096,) in both warm alignment classes, for the
+    single-wanted case: an edit to SIZE_BUCKETS, _fetch_cover or
+    _blockdiag_fetch_tile that pushes such a read onto an unwarmed shape
+    fails here instead of compiling inside a serving window."""
+    from seaweedfs_tpu.ops import rs_tpu
+    from seaweedfs_tpu.storage import needle as needle_mod
+
+    needle_size = needle_mod.actual_size(4096, needle_mod.CURRENT_VERSION)
+
+    def fused_shape(size, extra_delta):
+        # mirror _plan + _fused_vectors: LANE-align, then FUSED_ALIGN
+        # re-align; span = delta + take
+        span = extra_delta + size
+        fetch = rs_resident._fetch_cover(span)
+        blk_fetch, blk_tile = rs_resident._blockdiag_fetch_tile(
+            fetch, rs_tpu.BLOCKDIAG_GROUPS
+        )
+        return (
+            rs_resident._bucket(rs_resident.SIZE_BUCKETS, span),
+            blk_fetch,
+            blk_tile,
+        )
+
+    warm_shapes = {fused_shape(4096, off) for off in (0, 1)}
+    timed_shapes = {
+        fused_shape(needle_size, delta)
+        for delta in range(rs_resident.FUSED_ALIGN)
+    }
+    missing = timed_shapes - warm_shapes
+    assert not missing, (
+        f"4KB needle reads can hit fetch shapes a (4096,) warm grid "
+        f"never compiles: {sorted(missing)}"
+    )
 
 
 class TestEcVolumeWiring:

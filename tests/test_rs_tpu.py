@@ -61,7 +61,7 @@ def test_cross_backend_identical():
 def test_large_batch_tiling():
     """B spanning multiple grid tiles incl. a ragged tail (explicit small
     tile so interpret mode stays fast; the real-TPU multi-tile path is
-    exercised by bench.py on hardware)."""
+    compiled by tests/test_tpu_compile.py and runs in the bulk cell)."""
     m = gf256.parity_matrix(10, 14)
     x = _rand(10, 3 * 512 + 77, 5)
     assert np.array_equal(

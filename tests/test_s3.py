@@ -535,3 +535,60 @@ def test_s3_request_payment_and_signed_response_overrides(tmp_path):
             await cluster.stop()
 
     run(go())
+
+
+@pytest.mark.parametrize(
+    "drop_shards", [(), (0, 11)], ids=["healthy", "two_shards_dropped"]
+)
+def test_s3_get_rides_the_resident_ec_path(tmp_path, drop_shards):
+    """End to end through the gateway and the filer: an object whose
+    chunk lies on an EC volume pinned in the device cache comes back
+    byte-equal, and its chunk read is admitted on the dispatcher's
+    batched route under the s3 origin (ec_read_route_total
+    {route="s3_batched"}) — with every shard mounted and with two
+    destroyed, where the bytes can only come from a reconstruct."""
+    from degraded_cluster import build_degraded_cluster
+    from seaweedfs_tpu import stats
+    from seaweedfs_tpu.repair import RepairConfig
+
+    payload = os.urandom(40_000)
+
+    async def put_object(cluster):
+        client = S3Client(cluster.s3.url)
+        status, _, _ = await client.request("PUT", "/resident")
+        assert status == 200
+        status, _, _ = await client.request(
+            "PUT", "/resident/obj", data=payload
+        )
+        assert status == 200
+
+    def routed(route):
+        return stats.REGISTRY.get_sample_value(
+            "SeaweedFS_volumeServer_ec_read_route_total", {"route": route}
+        ) or 0
+
+    async def go():
+        cluster, vs, _blobs, _vid = await build_degraded_cluster(
+            str(tmp_path), n_blobs=6, device_cache=True,
+            drop_shards=drop_shards, with_s3=True, fill=put_object,
+            # the master would rebuild the destroyed shards by itself
+            master_kwargs={"ec_repair": RepairConfig(enabled=False)},
+        )
+        try:
+            entry = cluster.filer.filer.find_entry(
+                "/buckets/resident/obj"
+            )
+            vids = {int(c.file_id.split(",")[0]) for c in entry.chunks}
+            assert vids, "the object must be chunked, not inline"
+            assert all(vs.store.ec_volume_is_resident(v) for v in vids)
+            batched0, native0 = routed("s3_batched"), routed("s3_native")
+            status, body, _ = await S3Client(cluster.s3.url).request(
+                "GET", "/resident/obj"
+            )
+            assert status == 200 and body == payload
+            assert routed("s3_batched") - batched0 == len(entry.chunks)
+            assert routed("s3_native") == native0
+        finally:
+            await cluster.stop()
+
+    run(go())

@@ -4,7 +4,7 @@ over a mounted volume's shards and count mismatching bytes.
 Three layers: the CPU file scrub (encoder.verify_ec_files), the
 device-resident scrub (rs_resident.scrub_volume — only a [4] mismatch
 vector leaves the device), and the volume-server RPC end-to-end (the
-path bench.py times on the real TPU).  Reference analogue: the
+path chip_smoke.py drives on the real TPU).  Reference analogue: the
 read-verify passes of volume.fsck / ec.rebuild.
 """
 import asyncio

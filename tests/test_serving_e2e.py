@@ -9,8 +9,8 @@ This is the CI-scaled promotion of the round-4 hardware drive
 encode/mount/pin/degrade sequence, byte-exactness asserted for
 sequential reads, coalesced concurrent bursts, and the no-cache native
 path — on the CPU backend (tests/conftest.py forces JAX cpu; the device
-cache runs the XLA fallback kernels).  bench.py's serving sweep runs the
-same path on the real TPU and publishes the measured numbers.
+cache runs the XLA fallback kernels).  The benchmark's GET cells
+(benchmark/, BENCHMARK.json) run the same path on the chip.
 
 Reference path being matched: weed/storage/store_ec.go:136-393.
 """
@@ -29,20 +29,14 @@ async def _build_degraded_cluster(
 ):
     """Cluster with one volume EC-encoded, mounted, and `drop_shards`
     destroyed; returns (cluster, vs, blobs dict fid->bytes).  Thin CI
-    wrapper over bench.build_degraded_cluster — ONE implementation of
-    the degrade choreography shared with the benchmark, so the measured
-    path and the tested path cannot drift."""
-    from bench import build_degraded_cluster
+    wrapper over degraded_cluster.build_degraded_cluster — ONE
+    implementation of the degrade choreography for every test."""
+    from degraded_cluster import build_degraded_cluster
 
     cluster, vs, blobs, _vid = await build_degraded_cluster(
         str(tmp_path),
         n_blobs=n_blobs,
         device_cache=device_cache,
-        cache_budget=1 << 30,
-        # no pre-warm in CI: the XLA-fallback kernels compile in
-        # milliseconds at first use, and the full warm plan (every count
-        # bucket x size) would dominate the test's runtime
-        warm_sizes=(),
         drop_shards=drop_shards,
     )
     return cluster, vs, blobs

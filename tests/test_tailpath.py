@@ -3,8 +3,7 @@ tail-based retention under churn, cross-node assembly with clock-skew
 reconciliation, client-anchored critical-path attribution, and the
 end-to-end degraded read crossing filer -> volume -> remote-shard hops.
 
-Reference: the Dapper trace model in obs/trace.py; the acceptance
-arithmetic here is the same bucketing bench_tailpath_sweep gates on.
+Reference: the Dapper trace model in obs/trace.py.
 """
 import asyncio
 import time
@@ -64,6 +63,49 @@ def test_tail_ring_retention_under_churn():
         # to) and the assembler's local view both still find it
         assert tailstore.pinned(slow_id)
         assert critpath.local_entries(slow_id)
+    finally:
+        store.uninstall()
+
+
+def test_route_segments_sum_to_the_route_total():
+    """Per route, the critical-path segment counters
+    (SeaweedFS_critpath_seconds{route,segment}) add up to the route's
+    own total (SeaweedFS_critpath_route_seconds{route}): every second a
+    pinned trace spent is in exactly one segment."""
+
+    def counted(name, **labels):
+        return stats.REGISTRY.get_sample_value(name, labels) or 0.0
+
+    def sums(route):
+        return (
+            counted("SeaweedFS_critpath_route_seconds_total", route=route),
+            sum(
+                counted(
+                    "SeaweedFS_critpath_seconds_total",
+                    route=route, segment=seg,
+                )
+                for seg in critpath.SEGMENTS
+            ),
+        )
+
+    store = tailstore.TailStore(node="vs1", capacity=8, floor_ms=50.0)
+    store.install()
+    try:
+        names = ("GET /1,aabbcc", "POST /2,ddeeff")
+        before = {critpath.route_of(n): sums(critpath.route_of(n))
+                  for n in names}
+        for i, name in enumerate(names):
+            t, tok = obs.start_trace(name, "volume", "vs1")
+            t.t0 -= 0.2 + 0.1 * i
+            with obs.span("shard_read"):
+                pass
+            obs.finish_trace(t, tok, 200)
+        routes = store.routes()
+        assert set(before) <= set(routes)
+        for route, (total0, segs0) in before.items():
+            total, segs = sums(route)
+            assert total - total0 >= 0.2, route
+            assert total - total0 == pytest.approx(segs - segs0, rel=1e-6)
     finally:
         store.uninstall()
 
@@ -214,7 +256,7 @@ def test_degraded_read_assembly_across_hops(tmp_path):
     the volume's dispatcher pipeline, and the remote-shard fetches; the
     client-anchored segments sum to the client-measured total; a bogus
     id gets the 404 contract on both forensics endpoints."""
-    from bench import build_degraded_cluster
+    from degraded_cluster import build_degraded_cluster
 
     async def go():
         # host reconstruct path (no device cache): a read touching a

@@ -86,12 +86,11 @@ def test_zero_copy_http_reads_byte_equal_and_copyless(tmp_path):
     the zero-copy and the copying path — and the zero-copy route must
     add exactly 0 to response_copy_bytes_total while the copying route
     visibly pays."""
-    from bench import build_degraded_cluster
+    from degraded_cluster import build_degraded_cluster
 
     async def go():
         cluster, vs, blobs, _vid = await build_degraded_cluster(
-            str(tmp_path), n_blobs=6, device_cache=True,
-            cache_budget=1 << 30, warm_sizes=(),
+            str(tmp_path), n_blobs=6, device_cache=True
         )
         try:
             cfg = vs.ec_dispatcher.cfg
