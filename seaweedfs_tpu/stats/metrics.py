@@ -521,7 +521,8 @@ VOLUME_SERVER_EC_BULK_CODEC_SECONDS = Counter(
     "SeaweedFS_volumeServer_ec_bulk_codec_seconds",
     "Cumulative seconds of the bulk EC pipelines' device leg by part, "
     "each named for what the host waits on (stage = the batch laid out "
-    "in one flat host buffer, enqueue = device_put + the kernel call, "
+    "in one flat host buffer: nothing where the reader leg delivered it "
+    "laid out, enqueue = device_put + the kernel call, "
     "which both return before the device is done, fetch = the blocking "
     "copy back: H2D, kernel and D2H end inside it, unstack = the layout "
     "undone); zero under a CPU codec.",
@@ -547,6 +548,19 @@ VOLUME_SERVER_EC_BULK_BATCHES = Counter(
     ["pipeline"],
     registry=REGISTRY,
 )
+# over ec_bulk_batches: the share of a pipeline's batches that reached
+# the device without a staging copy (rebuild's; 0 where a second reader
+# holds the payload to plain rows, and under a CPU codec, which puts
+# nothing)
+VOLUME_SERVER_EC_BULK_DIRECT_BATCHES = Counter(
+    "SeaweedFS_volumeServer_ec_bulk_direct_batches",
+    "Stripe batches the bulk EC pipelines' device leg put on the device "
+    "as the reader leg delivered them: read from the shard files "
+    "straight into the row order the codec's program takes, no staging "
+    "copy on the host.",
+    ["pipeline"],
+    registry=REGISTRY,
+)
 VOLUME_SERVER_EC_BULK_OVERLAP_FRACTION = Gauge(
     "SeaweedFS_volumeServer_ec_bulk_overlap_fraction",
     "Leg-active seconds / wall seconds of the last bulk EC pipeline run "
@@ -563,6 +577,7 @@ for _p in EC_BULK_PIPELINES:
     for _part in EC_BULK_CODEC_PARTS:
         VOLUME_SERVER_EC_BULK_CODEC_SECONDS.labels(pipeline=_p, part=_part)
     VOLUME_SERVER_EC_BULK_BATCHES.labels(pipeline=_p)
+    VOLUME_SERVER_EC_BULK_DIRECT_BATCHES.labels(pipeline=_p)
     VOLUME_SERVER_EC_BULK_OVERLAP_FRACTION.labels(pipeline=_p)
 
 # heat-tiered residency ladder (serving/tiering.py): HBM -> host RAM ->

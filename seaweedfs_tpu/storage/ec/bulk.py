@@ -17,7 +17,9 @@ bounded queues, so wall-clock trends toward max(read, device, write):
 Reads use one vectored ``os.preadv`` per stripe where the platform has
 it and the stripe's rows are contiguous on disk (full-block batches),
 instead of DATA_SHARDS serial preads; every other row is one ``preadv``
-straight into its row, no intermediate ``bytes``.
+straight into its row, no intermediate ``bytes``.  A batch out of
+per-shard files (rebuild, verify) is one ``preadv`` a file, the files
+read side by side on READ_THREADS threads (``read_shard_rows``).
 
 The [k, stride]-sized host buffers of a batch — the reader leg's payload
 and the codec worker's staging buffer — come from ``POOL`` and go back
@@ -28,6 +30,15 @@ PR 24/25).  A recycled buffer holds the previous batch's bytes, so what
 keeps old bytes out of parity is the readers' zero fill past what was
 read (``_read_row``, ``_zero_tail``) — never a memset of the whole
 buffer, which was ~10% of the read leg at device speeds.
+
+Rebuild's payload has no reader but the codec, so its reader leg fills
+it in the row order the codec's program takes (``Codec.segments``: each
+``preadv`` scatters a shard's segments to their stacked rows) and the
+worker puts it on the device as it is: the payload IS the staged buffer,
+and no second copy of the batch is made on the host.  Encode's payload
+is also the writer leg's (the ten data rows go to their shard files) and
+verify's is compared against, so both stay plain rows and the worker
+stages its own copy.
 
 Stats contract (the dict ``run()`` fills, same keys for all three
 pipelines):
@@ -57,7 +68,7 @@ import queue
 import threading
 import time
 from collections import deque
-from concurrent.futures import Future, ThreadPoolExecutor
+from concurrent.futures import Future, ThreadPoolExecutor, wait
 from dataclasses import dataclass
 
 import numpy as np
@@ -77,13 +88,25 @@ DEFAULT_STRIDE = 4 * 1024 * 1024
 # the wire, one landing.  NOTE the overlapped pipeline's peak host
 # footprint is 2*prefetch + depth + 2 payloads — the stripe queue, the
 # pending deque, the result queue (payloads ride along for the writer),
-# and one in each leg's hands — plus the codec worker's one staging
-# buffer: 12 buffers (480MB of [10, 4MB] rebuild batches at the default
-# stride) vs the serial mode's 2.  POOL keeps that many after the run
-# (see BufferPool), so the footprint is the process's from its first
-# bulk verb on; size stride/prefetch down together on memory-tight
-# volume servers.
+# and one in each leg's hands — plus, where the codec worker stages
+# (encode, verify; not rebuild, whose payload is what it puts), its one
+# staging buffer: 11 buffers in a rebuild (440MB of [10, 4MB] batches at
+# the default stride), 12 in an encode or a scrub, vs the serial mode's
+# 1 and 2.  POOL keeps as many as the widest run had in flight (see
+# BufferPool), so the footprint is the process's from its first bulk
+# verb on; size stride/prefetch down together on memory-tight volume
+# servers.
 PIPELINE_DEPTH = 3
+# Threads that read one shard-file batch's rows side by side
+# (read_shard_rows).  A constant from a measurement, not a knob: alone on
+# the chip tool's 13-core host ten 4MB preadv out of the page cache take
+# 16.8ms one after another, 9.1 on 2 threads, 5.5 on 4, 4.0-5.4 on 5 and
+# 2.8-3.7 on 10 (experiments/host_read_fanout.py, PERF.md PR 31).  5
+# splits a rebuild's ten rows evenly and leaves the reader a quarter of
+# the codec worker's batch; more would take the cores the worker's
+# transfers, the writer leg and the server's loop run on for a
+# millisecond that no leg waits for.
+READ_THREADS = 5
 
 # test seams / portability: the slow-IO fixtures in tests/test_ec_bulk.py
 # wrap these, and platforms without preadv (none we target) fall back to
@@ -236,6 +259,11 @@ class Codec:
             )
             for part in _metrics.EC_BULK_CODEC_PARTS
         ]
+        self._direct_batches = (
+            _metrics.VOLUME_SERVER_EC_BULK_DIRECT_BATCHES.labels(
+                pipeline=pipeline
+            )
+        )
         self._pool = None
         if self.device:
             from ...ops import rs_tpu
@@ -254,9 +282,28 @@ class Codec:
                     max_workers=1, thread_name_prefix="ec-host"
                 )
 
-    def submit(self, shards: np.ndarray):
+    def segments(self, b: int) -> int:
+        """The row order this codec's program takes a [k, b] batch in:
+        1 is plain rows; g > 1 is rs_tpu.stack_segments' order, the same
+        bytes as [g*k, b/g] with shard i's segment s at row s*k + i (the
+        block-diagonal fast path: the MXU runs with a full M dimension,
+        ~152 vs ~123 GB/s, see ops/rs_tpu.py header).  A reader whose
+        payload only the codec reads fills it in this order
+        (read_shard_rows) and submits it `direct`."""
+        if self.backend == "pallas":
+            groups = self._tpu.BLOCKDIAG_GROUPS
+            if b % (groups * 128) == 0:
+                return groups
+        return 1
+
+    def submit(self, shards: np.ndarray, direct: bool = False):
+        """Queue one [k, b] batch.  `direct` says that `shards` is a
+        pooled payload already in segments(b)'s order which nothing else
+        reads before resolve() has returned: the device leg puts it as it
+        is, where otherwise it lays its own copy out in a staging
+        buffer."""
         if self.device:
-            return self._pool.submit(self._device_leg, shards)
+            return self._pool.submit(self._device_leg, shards, direct)
         if self._pool is not None:
             return self._pool.submit(self._host_leg, shards)
         return self._host_leg(shards)
@@ -273,11 +320,11 @@ class Codec:
         )
         return out
 
-    def _device_leg(self, shards: np.ndarray) -> np.ndarray:
+    def _device_leg(self, shards: np.ndarray, direct: bool) -> np.ndarray:
         """Both transfers ship FLAT 1-D buffers
         (apply_matrix_device_flat)."""
         t0 = time.perf_counter()
-        parity = self._device_leg_tagged(shards)
+        parity = self._device_leg_tagged(shards, direct)
         dur = time.perf_counter() - t0
         self.busy_s += dur
         devledger.record(
@@ -286,25 +333,24 @@ class Codec:
         )
         return parity
 
-    def _device_leg_tagged(self, shards: np.ndarray) -> np.ndarray:
+    def _device_leg_tagged(
+        self, shards: np.ndarray, direct: bool
+    ) -> np.ndarray:
         """The leg in four parts, each an event of a profiler capture and
         a term of ec_bulk_codec_seconds, named for what the HOST waits on (the
         device trace has the kernel's own time; nothing here
         synchronises to tell them apart): `bulk_stage` lays the batch
-        out in one flat pooled host buffer, `bulk_enqueue` is device_put
+        out in one flat pooled host buffer (nothing to do for a `direct`
+        payload, which arrived laid out), `bulk_enqueue` is device_put
         plus the kernel call (both return before the device is done),
         `bulk_fetch` the blocking copy back — where the host waits for
         H2D, kernel and D2H to finish — and `bulk_unstack` the layout
         undone.  The boundaries are shared, so the parts sum to the leg."""
         import jax
 
-        groups = self._tpu.BLOCKDIAG_GROUPS
         k, b = shards.shape
-        # block-diagonal fast path: host stages segment-stacked rows (one
-        # copy of the batch at memory speed into a kept buffer) and the
-        # MXU runs with a full M dimension (~152 vs ~123 GB/s, see
-        # ops/rs_tpu.py header)
-        blockdiag = self.backend == "pallas" and b % (groups * 128) == 0
+        groups = self.segments(b)
+        blockdiag = groups > 1
         clock = time.perf_counter
         # the with-block tags the dispatch IN the leg thread — the pool
         # worker never inherits the submitter's ledger context (GL116's
@@ -314,16 +360,22 @@ class Codec:
             with obs_trace.event("bulk_stage", bytes=int(shards.nbytes)):
                 # device_put reads this memory until the transfer is done
                 # (the CPU backend may alias it for the life of `x`), so
-                # the buffer goes back to the pool only below, after the
-                # blocking fetch of the program that consumed `x`.  One
+                # a staging buffer goes back to the pool only below,
+                # after the blocking fetch of the program that consumed
+                # `x`, and a direct payload where its pipeline gives it
+                # back, after resolve() — so after that same fetch.  One
                 # worker runs one batch at a time, so one staging buffer
                 # circulates; a leg that staged batch n+1 while it
                 # fetched n would hold two, by the same take and give.
-                staged = POOL.take(self.pipeline, k, b)
-                if blockdiag:
-                    self._tpu.stack_segments(shards, out=staged)
+                if direct:
+                    staged = shards
+                    self._direct_batches.inc()
                 else:
-                    np.copyto(staged, shards)
+                    staged = POOL.take(self.pipeline, k, b)
+                    if blockdiag:
+                        self._tpu.stack_segments(shards, out=staged)
+                    else:
+                        np.copyto(staged, shards)
             t1 = clock()
             with obs_trace.event("bulk_enqueue"):
                 x = jax.device_put(staged.reshape(-1))
@@ -350,7 +402,8 @@ class Codec:
                 # graftlint: allow(device-sync): the codec worker's own
                 # D2H — fetched on the dedicated device leg, timed busy_s
                 flat = np.asarray(out)
-            POOL.give(staged)
+            if not direct:
+                POOL.give(staged)
             t3 = clock()
             with obs_trace.event("bulk_unstack"):
                 if blockdiag:
@@ -393,18 +446,31 @@ def _zero_tail(out: np.ndarray, filled: int) -> None:
         out[row:] = 0
 
 
-def _read_row(fd: int, row: np.ndarray, n: int, off: int) -> None:
-    """Fill `row` with the file's bytes at [off, off+n) and zeros past
-    what was read (EOF, n < len(row)): read straight into the row where
-    the platform has preadv, no intermediate bytes."""
-    got = 0
+def _read_row(fd: int, pieces: list, n: int, off: int) -> None:
+    """Fill `pieces` — one row's consecutive parts, in file order: the
+    row itself, or its segments where they lie apart — with the file's
+    bytes at [off, off+n) and zeros past what was read (EOF, n short of
+    the row): one preadv scatters straight into them where the platform
+    has it, no intermediate bytes."""
+    got, buf = 0, None
     if n > 0 and _preadv is not None:
-        got = _preadv(fd, [row[:n]], off)
+        iov, left = [], n
+        for piece in pieces:
+            if left <= 0:
+                break
+            iov.append(piece[:left])
+            left -= len(piece)
+        got = _preadv(fd, iov, off)
     elif n > 0:
-        buf = _pread(fd, n, off)
+        buf = np.frombuffer(_pread(fd, n, off), dtype=np.uint8)
         got = len(buf)
-        row[:got] = np.frombuffer(buf, dtype=np.uint8)
-    row[got:] = 0
+    at = 0
+    for piece in pieces:
+        have = min(max(got - at, 0), len(piece))
+        if buf is not None:
+            piece[:have] = buf[at:at + have]
+        piece[have:] = 0
+        at += len(piece)
 
 
 def read_stripe(
@@ -435,18 +501,42 @@ def read_stripe(
         # whole stripe on the per-row path rather than resuming mid-iov
     for i in range(DATA_SHARDS):
         start = row_start + i * block_size + stride_off
-        _read_row(fd, out[i], min(stride, dat_size - start), start)
+        _read_row(fd, [out[i]], min(stride, dat_size - start), start)
     return out
 
 
-def read_shard_rows(handles: dict, ids, off: int, out: np.ndarray) -> np.ndarray:
+def row_readers() -> ThreadPoolExecutor:
+    """The threads one run's read_shard_rows calls share; the run that
+    made them shuts them down."""
+    return ThreadPoolExecutor(
+        max_workers=READ_THREADS, thread_name_prefix="ec-bulk-row"
+    )
+
+
+def read_shard_rows(
+    handles: dict, ids, off: int, out: np.ndarray,
+    readers: ThreadPoolExecutor, segments: int = 1,
+) -> np.ndarray:
     """Fill the [len(ids), n] batch `out` from per-shard FILES
-    (rebuild/verify inputs): row j is shard ids[j]'s bytes at
-    [off, off+n), zero-padded on a short read.  Separate files can't
-    share a preadv, but each row is one contiguous read."""
-    n = out.shape[1]
-    for j, sid in enumerate(ids):
-        _read_row(handles[sid].fileno(), out[j], n, off)
+    (rebuild/verify inputs) with shard ids[j]'s bytes at [off, off+n),
+    zero-padded on a short read: as row j, or with `segments` g > 1 in
+    the order Codec.segments names, `out`'s bytes as [g*k, n/g] with the
+    shard's segment s at row s*k + j.  Separate files can't share a
+    preadv, but each shard is one (its segments are the iovecs), and the
+    calls release the interpreter lock: they run side by side on
+    `readers` and all have ended, whatever any of them raised, before
+    this returns or raises the first error."""
+    k, n = out.shape
+    rows = out.reshape(segments, k, n // segments)
+    reads = [
+        readers.submit(
+            _read_row, handles[sid].fileno(), list(rows[:, j]), n, off
+        )
+        for j, sid in enumerate(ids)
+    ]
+    wait(reads)
+    for read in reads:
+        read.result()
     return out
 
 
@@ -507,6 +597,7 @@ def run(
     prefetch: int | None = None,
     depth: int = PIPELINE_DEPTH,
     to_codec=None,
+    direct: bool = False,
 ) -> dict:
     """Drive one bulk pipeline over `plan` and return its stats dict.
 
@@ -519,12 +610,20 @@ def run(
 
     A payload that read_batch took from POOL is write_batch's to give
     back when it has finished with it; POOL keeps as many buffers as
-    this run can have in flight (the PIPELINE_DEPTH note)."""
+    this run can have in flight (the PIPELINE_DEPTH note).  `direct`:
+    the payload has no reader but the codec and read_batch filled it in
+    the order codec.segments names, so the codec is handed it to put as
+    it is (Codec.submit) and stages no copy of its own."""
     cfg = DEFAULT
     overlap = cfg.overlap if overlap is None else bool(overlap)
     prefetch = cfg.prefetch if prefetch is None else prefetch
-    POOL.keep = 2 * max(1, prefetch) + depth + 3 if overlap else 2
+    payloads = 2 * max(1, prefetch) + depth + 2 if overlap else 1
+    POOL.keep = payloads + (0 if direct else 1)
     pick = to_codec if to_codec is not None else lambda payload: payload
+
+    def submit(payload):
+        return codec.submit(pick(payload), direct)
+
     t = {
         "read_s": 0.0, "submit_s": 0.0, "wait_s": 0.0, "write_s": 0.0,
         "fsync_s": 0.0, "batches": 0, "overlap": overlap,
@@ -534,16 +633,16 @@ def run(
     with obs_trace.interval("bulk_run", pipeline=name):
         if overlap:
             _run_overlapped(
-                name, plan, read_batch, codec, write_batch, pick, prefetch,
+                name, plan, read_batch, codec, write_batch, submit, prefetch,
                 depth, t,
             )
         else:
-            _run_serial(plan, read_batch, codec, write_batch, pick, t)
+            _run_serial(plan, read_batch, codec, write_batch, submit, t)
     t["device_busy_s"] = codec.busy_s
     return t
 
 
-def _run_serial(plan, read_batch, codec, write_batch, pick, t: dict) -> None:
+def _run_serial(plan, read_batch, codec, write_batch, submit, t: dict) -> None:
     """Every leg on the caller thread, batch after batch."""
     clock = time.perf_counter
     for desc in plan:
@@ -551,7 +650,7 @@ def _run_serial(plan, read_batch, codec, write_batch, pick, t: dict) -> None:
         with obs_trace.event("bulk_read"):
             payload = read_batch(desc)
         t1 = clock()
-        handle = codec.submit(pick(payload))
+        handle = submit(payload)
         t2 = clock()
         result = codec.resolve(handle)
         t3 = clock()
@@ -565,7 +664,7 @@ def _run_serial(plan, read_batch, codec, write_batch, pick, t: dict) -> None:
 
 
 def _run_overlapped(
-    name: str, plan, read_batch, codec, write_batch, pick, prefetch: int,
+    name: str, plan, read_batch, codec, write_batch, submit, prefetch: int,
     depth: int, t: dict,
 ) -> None:
     """Reader and writer legs on their own threads around the caller's
@@ -626,7 +725,7 @@ def _run_overlapped(
                 break
             desc, payload = item
             s0 = clock()
-            handle = codec.submit(pick(payload))
+            handle = submit(payload)
             t["submit_s"] += clock() - s0
             t["batches"] += 1
             pending.append((desc, payload, handle))

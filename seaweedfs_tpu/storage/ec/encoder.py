@@ -219,13 +219,17 @@ def rebuild_ec_files(
         (off, min(stride, shard_size - off))
         for off in range(0, shard_size, stride)
     ]
+    readers = bulk.row_readers()
     t_start = time.perf_counter()
     try:
 
         def read_batch(desc):
+            # nothing but the codec reads this payload (write_batch gives
+            # it straight back), so it is filled in the codec's row order
             off, n = desc
             return bulk.read_shard_rows(
-                inputs, use, off, bulk.POOL.take("rebuild", len(use), n)
+                inputs, use, off, bulk.POOL.take("rebuild", len(use), n),
+                readers, codec.segments(n),
             )
 
         def write_batch(desc, payload, out):
@@ -235,10 +239,11 @@ def rebuild_ec_files(
 
         t = bulk.run(
             "rebuild", plan, read_batch, codec, write_batch,
-            overlap=use_overlap, prefetch=prefetch,
+            overlap=use_overlap, prefetch=prefetch, direct=True,
         )
         _finish_outputs(list(outputs.values()), fsync, t)
     finally:
+        readers.shutdown()
         codec.shutdown()
         for h in list(inputs.values()) + list(outputs.values()):
             h.close()
@@ -286,6 +291,7 @@ def verify_ec_files(
         (off, min(stride, shard_size - off))
         for off in range(0, shard_size, stride)
     ]
+    readers = bulk.row_readers()
     t_start = time.perf_counter()
     try:
 
@@ -293,7 +299,7 @@ def verify_ec_files(
             off, n = desc
             return bulk.read_shard_rows(
                 handles, range(TOTAL_SHARDS), off,
-                bulk.POOL.take("verify", TOTAL_SHARDS, n),
+                bulk.POOL.take("verify", TOTAL_SHARDS, n), readers,
             )
 
         def write_batch(desc, payload, parity):
@@ -310,6 +316,7 @@ def verify_ec_files(
             to_codec=lambda payload: payload[:DATA_SHARDS],
         )
     finally:
+        readers.shutdown()
         codec.shutdown()
         for h in handles:
             h.close()

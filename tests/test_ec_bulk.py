@@ -421,20 +421,20 @@ class TestPooledBuffers:
         ) == ([0, 0, 0, 0], _SHARD_SIZE)
 
     @pytest.mark.parametrize("overlap", [True, False])
-    @pytest.mark.parametrize("backend", ["cpu", "xla"])
+    @pytest.mark.parametrize("backend", ["cpu", "xla", "pallas"])
     def test_fresh_up_to_the_bound_then_reused(
         self, tmp_path, pool, backend, overlap
     ):
-        """Two rebuild verbs of 24 batches each.  Overlapped, the
-        pipeline can hold 2*2 + 3 + 2 payloads and one staging buffer:
-        no more than that many are allocated over both verbs, however
-        the legs interleave.  Serial, one payload (and one staging
-        buffer) is allocated by the first verb and none by the second."""
+        """Two rebuild verbs of 24 batches each, one buffer a batch on
+        every backend: the payload, which a device codec puts as it is
+        (no staging buffer).  Overlapped, the pipeline can hold
+        2*2 + 3 + 2 payloads: no more than that many are allocated over
+        both verbs, however the legs interleave.  Serial, the one
+        payload in flight is the buffer the encode verb left."""
         base = str(tmp_path / "1")
         make_dat(base + ".dat", _DAT_SIZE, seed=26)
         _encode_short_tail(base, "cpu", overlap)
         want = shard_bytes(base)
-        per_batch = 2 if backend == "xla" else 1  # payload (+ staging)
         fresh_by_verb = []
         for _verb in range(2):
             os.remove(base + to_ext(5))
@@ -444,17 +444,16 @@ class TestPooledBuffers:
                 prefetch=2,
             )
             reused, fresh = _handed_since("rebuild", before)
-            assert reused + fresh == 24 * per_batch
+            assert reused + fresh == 24
             fresh_by_verb.append(fresh)
             assert len(pool._free) <= pool.keep
             assert shard_bytes(base) == want
         if overlap:
-            assert pool.keep == 2 * 2 + bulk.PIPELINE_DEPTH + 3
+            assert pool.keep == 2 * 2 + bulk.PIPELINE_DEPTH + 2
             assert sum(fresh_by_verb) <= pool.keep
         else:
-            assert pool.keep == 2
-            # the encode verb above left its payload buffer, 40 KB
-            assert fresh_by_verb == [per_batch - 1, 0]
+            assert pool.keep == 1
+            assert fresh_by_verb == [0, 0]
 
     @pytest.mark.parametrize("leg", ["reader", "writer"])
     def test_failed_leg_ends_the_run_and_leaves_the_pool_usable(
@@ -538,6 +537,266 @@ class TestPooledBuffers:
             "bulk_unstack",
         ] * 6
         assert _handed_since("encode", before) == (5, 1)
+
+
+# ------------------------------------- rebuild's read leg: layout, fan-out
+
+
+def _counter(family, pipeline):
+    from seaweedfs_tpu.stats import metrics as m
+
+    return getattr(m, family).labels(pipeline=pipeline)._value.get()
+
+
+def _direct(pipeline):
+    return _counter("VOLUME_SERVER_EC_BULK_DIRECT_BATCHES", pipeline)
+
+
+def _bulk_threads():
+    import threading
+
+    return sorted(
+        t.name for t in threading.enumerate()
+        if t.name.startswith(("ec-bulk", "ec-dev", "ec-host"))
+    )
+
+
+@pytest.fixture
+def readers():
+    pool = bulk.row_readers()
+    yield pool
+    pool.shutdown()
+
+
+_K, _GROUPS = 10, 4
+
+
+class TestShardRowsLayout:
+    """read_shard_rows into the codec's stacked order against
+    rs_tpu.stack_segments of the plain rows, into a buffer that held
+    0xFF everywhere."""
+
+    # (bytes a shard file holds, offset, n): segments of n/4
+    CASES = {
+        "full_batch": (4096, 0, 2048),
+        "short_last_batch": (2048 + 512, 2048, 512),
+        "eof_inside_a_segment": (1000, 0, 2048),
+        "eof_on_a_segment_boundary": (1024, 0, 2048),
+        "eof_before_the_batch": (100, 2048, 2048),
+    }
+
+    @pytest.mark.parametrize("vectored", [True, False])
+    @pytest.mark.parametrize("case", list(CASES))
+    def test_stacked_read_equals_stacked_plain_rows(
+        self, tmp_path, monkeypatch, readers, case, vectored
+    ):
+        from seaweedfs_tpu.ops import rs_tpu
+
+        if not vectored:
+            monkeypatch.setattr(bulk, "_preadv", None)
+        elif bulk._preadv is None:
+            pytest.skip("platform without preadv")
+        size, off, n = self.CASES[case]
+        rng = np.random.default_rng(31)
+        # no zero byte in a file: a zero in a batch is a filled one
+        files = rng.integers(1, 256, size=(_K, size), dtype=np.uint8)
+        handles = {}
+        for i in range(_K):
+            path = str(tmp_path / f"s{i}")
+            with open(path, "wb") as f:
+                f.write(files[i].tobytes())
+            handles[i + 2] = open(path, "rb")
+        ids = list(handles)
+        want = np.zeros((_K, n), dtype=np.uint8)
+        have = max(0, min(n, size - off))
+        want[:, :have] = files[:, off:off + have]
+        try:
+            plain = bulk.read_shard_rows(
+                handles, ids, off, np.full((_K, n), 0xFF, np.uint8), readers
+            )
+            stacked = bulk.read_shard_rows(
+                handles, ids, off, np.full((_K, n), 0xFF, np.uint8), readers,
+                _GROUPS,
+            )
+        finally:
+            for h in handles.values():
+                h.close()
+        np.testing.assert_array_equal(plain, want)
+        np.testing.assert_array_equal(
+            stacked.reshape(_GROUPS * _K, n // _GROUPS),
+            rs_tpu.stack_segments(want, _GROUPS),
+        )
+
+
+class TestReadFanOut:
+    def _shards(self, tmp_path, seed):
+        base = str(tmp_path / "1")
+        payload = make_dat(base + ".dat", _DAT_SIZE, seed=seed)
+        want = _host_codec_shards(payload)
+        _encode_short_tail(base, "cpu", True)
+        return base, want
+
+    @pytest.mark.parametrize("vectored", [True, False])
+    def test_one_read_a_row_side_by_side_on_the_runs_threads(
+        self, tmp_path, monkeypatch, readers, vectored
+    ):
+        import threading
+
+        base, _ = self._shards(tmp_path, 32)
+        lock = threading.Lock()
+        seen = {"calls": [], "now": 0, "most": 0, "threads": set()}
+
+        def watched(real):
+            def wrapped(fd, arg, off):
+                with lock:
+                    seen["calls"].append((fd, arg if vectored else None))
+                    seen["threads"].add(threading.current_thread().name)
+                    seen["now"] += 1
+                    seen["most"] = max(seen["most"], seen["now"])
+                time.sleep(0.01)
+                try:
+                    return real(fd, arg, off)
+                finally:
+                    with lock:
+                        seen["now"] -= 1
+            return wrapped
+
+        if vectored:
+            if bulk._preadv is None:
+                pytest.skip("platform without preadv")
+            monkeypatch.setattr(bulk, "_preadv", watched(bulk._preadv))
+        else:
+            monkeypatch.setattr(bulk, "_preadv", None)
+            monkeypatch.setattr(bulk, "_pread", watched(bulk._pread))
+        handles = {i: open(base + to_ext(i), "rb") for i in range(14)}
+        fds = sorted(h.fileno() for h in handles.values())
+        try:
+            out = bulk.read_shard_rows(
+                handles, range(14), 1024, np.empty((14, 2048), np.uint8),
+                readers, _GROUPS,
+            )
+        finally:
+            for h in handles.values():
+                h.close()
+        assert sorted(fd for fd, _ in seen["calls"]) == fds
+        if vectored:  # a shard's segments are the iovecs of its one call
+            assert {len(iov) for _, iov in seen["calls"]} == {_GROUPS}
+        assert 1 < seen["most"] <= bulk.READ_THREADS
+        assert all(name.startswith("ec-bulk-row") for name in seen["threads"])
+        assert out.shape == (14, 2048)
+
+    @pytest.mark.parametrize("overlap", [True, False])
+    def test_a_failed_row_ends_the_run_and_leaves_no_thread(
+        self, tmp_path, pool, monkeypatch, overlap
+    ):
+        base, want = self._shards(tmp_path, 33)
+        os.remove(base + to_ext(3))
+        os.remove(base + to_ext(11))
+        calls = {"n": 0}
+        real = bulk._preadv
+
+        def failing(fd, iov, off):
+            calls["n"] += 1
+            if calls["n"] == 14:  # a row of the second batch
+                raise OSError("boom-row")
+            return real(fd, iov, off)
+
+        with monkeypatch.context() as patch:
+            patch.setattr(bulk, "_preadv", failing)
+            t0 = time.monotonic()
+            with pytest.raises(OSError, match="boom-row"):
+                ec.rebuild_ec_files(
+                    base, backend="cpu", stride=5120, overlap=overlap,
+                    prefetch=2,
+                )
+            assert time.monotonic() - t0 < 10.0
+        assert _bulk_threads() == []
+        for lost in (3, 11):  # what the failed run left of them
+            os.remove(base + to_ext(lost))
+        assert ec.rebuild_ec_files(
+            base, backend="cpu", stride=5120, overlap=overlap, prefetch=2
+        ) == [3, 11]
+        assert shard_bytes(base) == want
+        assert _bulk_threads() == []
+
+
+class TestDirectRebuild:
+    # 12 KB shards: a 5120 stride is three batches the block-diagonal
+    # program takes stacked (5120, 5120, 2048 divide by 4*128), a 5000
+    # stride three it takes as plain rows
+    @pytest.mark.parametrize("overlap", [True, False])
+    @pytest.mark.parametrize("stride", [5120, 5000])
+    @pytest.mark.parametrize("backend", ["pallas", "xla", "cpu"])
+    def test_rebuild_puts_its_payload_and_nobody_else_does(
+        self, tmp_path, pool, backend, stride, overlap
+    ):
+        base = str(tmp_path / "1")
+        want = _host_codec_shards(make_dat(base + ".dat", _DAT_SIZE, seed=34))
+        before = {p: _direct(p) for p in ("encode", "rebuild", "verify")}
+        _encode_short_tail(base, backend, overlap)
+        for lost in (0, 3, 11):
+            os.remove(base + to_ext(lost))
+        handed = _handed("rebuild")
+        batches = _counter("VOLUME_SERVER_EC_BULK_BATCHES", "rebuild")
+        assert ec.rebuild_ec_files(
+            base, backend=backend, stride=stride, overlap=overlap, prefetch=2
+        ) == [0, 3, 11]
+        assert shard_bytes(base) == want
+        assert ec.verify_ec_files(
+            base, backend=backend, stride=stride, overlap=overlap, prefetch=2
+        ) == ([0, 0, 0, 0], _SHARD_SIZE)
+        plan = -(-_SHARD_SIZE // stride)
+        assert _counter(
+            "VOLUME_SERVER_EC_BULK_BATCHES", "rebuild") - batches == plan
+        # one buffer a batch: the payload, no staging buffer beside it
+        reused, fresh = _handed_since("rebuild", handed)
+        assert reused + fresh == plan
+        assert fresh <= pool.keep
+        # the host codec puts nothing on a device
+        assert _direct("rebuild") - before["rebuild"] == (
+            0 if backend == "cpu" else plan
+        )
+        assert _direct("encode") == before["encode"]
+        assert _direct("verify") == before["verify"]
+
+    @pytest.mark.parametrize("backend", ["pallas", "xla"])
+    def test_direct_payload_is_put_without_a_take(self, monkeypatch, backend):
+        """The codec worker takes no buffer for a direct batch, and what
+        it computes from a payload in its own order is what it computes
+        from the plain rows it stages itself."""
+        from seaweedfs_tpu.ops import rs_tpu
+
+        taken = []
+
+        class CountingPool(bulk.BufferPool):
+            def take(self, pipeline, rows, width):
+                taken.append((pipeline, rows, width))
+                return super().take(pipeline, rows, width)
+
+        monkeypatch.setattr(bulk, "POOL", CountingPool())
+        host = rs.RSCodec(backend="cpu")
+        matrix = host.matrix[10:]
+        rng = np.random.default_rng(35)
+        plain = rng.integers(0, 256, size=(10, 2048), dtype=np.uint8)
+        codec = bulk.Codec(matrix, backend, threaded=True, pipeline="rebuild")
+        try:
+            groups = codec.segments(2048)
+            assert groups == (_GROUPS if backend == "pallas" else 1)
+            assert codec.segments(2000) == 1
+            laid_out = (
+                rs_tpu.stack_segments(plain, groups).reshape(10, 2048).copy()
+                if groups > 1 else plain.copy()
+            )
+            before = _direct("rebuild")
+            direct = codec.resolve(codec.submit(laid_out, direct=True))
+            assert taken == [] and _direct("rebuild") - before == 1
+            staged = codec.resolve(codec.submit(plain))
+            assert taken == [("rebuild", 10, 2048)]
+            assert _direct("rebuild") - before == 1
+        finally:
+            codec.shutdown()
+        np.testing.assert_array_equal(direct, host.apply_matrix(matrix, plain))
+        np.testing.assert_array_equal(staged, direct)
 
 
 # ------------------------------------------------- .vif + fsync satellite
