@@ -1964,8 +1964,8 @@ def _resolve_codec(cache, vid, requests, data_shards, total_shards, layout):
     per the active layout, staged on the vid's placement) + resident
     survivor tuple + the system's pre-expansion row count + the vid's
     placement ("mesh" | device index | 0 for the legacy default)."""
-    wanted = sorted({r[0] for r in requests})
     resident = cache.shard_ids(vid)
+    wanted = _batch_wanted(requests, resident, data_shards)
     present = [s for s in resident if s not in wanted]
     if len(present) < data_shards:
         raise CacheMiss(
@@ -2633,7 +2633,8 @@ def _pack_calls(
         group = [(i, s) for i, s in enumerate(subs) if s[4] == bucket]
         if not group:
             continue
-        n_bucket = _bucket(COUNT_BUCKETS, min(len(group), _max_count(bucket)))
+        wide = groups > 1 and w_true > 1
+        n_bucket = _call_count(bucket, len(group), wide)
         for start in range(0, len(group), n_bucket):
             part = group[start : start + n_bucket]
             pad = n_bucket - len(part)
@@ -2645,7 +2646,7 @@ def _pack_calls(
                 packed, deltas, fetch = _fused_vectors(
                     part, requests, row_of
                 )
-                fetch, tile = _fused_fetch_tile(fetch, groups)
+                fetch, tile = _call_fetch_tile(fetch, groups, wide)
                 calls.append(
                     ("fused", part, packed, pad, fetch, tile, n_bucket,
                      deltas)
@@ -2654,8 +2655,12 @@ def _pack_calls(
                 cols = _group_vectors(part, requests, row_of)
                 # D2H width: power-of-two cover of the largest actual
                 # request in this call, never wider than the compute tile
+                # nor narrower than the smallest one (so that the rungs
+                # a plan has to hold stay few: _xla_fetch_rungs)
                 max_take = max(s[3] for _, s in part)
-                fetch = min(bucket, 1 << (max_take - 1).bit_length())
+                fetch = min(bucket, max(
+                    SIZE_BUCKETS[0], 1 << (max_take - 1).bit_length()
+                ))
                 calls.append(
                     ("xla", part, cols, pad, fetch, bucket, n_bucket,
                      None)
@@ -2848,7 +2853,7 @@ def reconstruct_intervals(
                  "fused_" if fused else "")
                 + ("blockdiag" if groups > 1 else kernel)),
     )
-    dev_calls = dev_misses = dev_h2d = dev_d2h = 0
+    dev_calls = dev_misses = dev_h2d = dev_d2h = rows_wanted = 0
     sub_out: list[bytes | None] = [None] * len(subs)
 
     # PIPELINE: enqueue everything, then collect.  jax dispatch is
@@ -3062,6 +3067,11 @@ def reconstruct_intervals(
             )
             pending_bytes += wire_rows * fetch
             dev_calls += 1
+            # the lost shards this call's requests asked for, beside the
+            # w_true rows its program multiplies
+            rows_wanted += (
+                len({requests[e[1][0]][0] for e in part}) if w_true > 1 else 1
+            )
             dev_d2h += wire_rows * fetch
             while pending_bytes > _MAX_PENDING_OUT and len(pending) > 1:
                 pending_bytes -= _finish(pending.pop(0))
@@ -3073,6 +3083,9 @@ def reconstruct_intervals(
                 stats_metrics.VOLUME_SERVER_EC_DEVICE_TRANSFERS.labels(
                     kind=how
                 ).inc(n)
+        rows = stats_metrics.VOLUME_SERVER_EC_RECONSTRUCT_ROWS
+        rows.labels(kind="wanted").inc(rows_wanted)
+        rows.labels(kind="computed").inc(w_true * dev_calls)
         dev_span.annotate(
             device_calls=dev_calls, compile_misses=dev_misses,
             h2d_bytes=dev_h2d, d2h_bytes=dev_d2h,
@@ -3544,7 +3557,14 @@ def warm(
     grid OBSERVED-SHAPES-FIRST (`observed`, default this process's
     dispatch history): a re-pin under live traffic reaches
     serving-readiness for the workload's real (size, count) buckets
-    before burning compiles on ladder corners nobody hits."""
+    before burning compiles on ladder corners nobody hits.
+
+    When a plan is made: at the pin (storage/store.py), at a promotion
+    (serving/tiering.py), both through this function, and after a loss
+    (warm_replan, below).  The grid is the family of ONE wanted shard.
+    A volume that is two or more data shards down when this runs gets
+    the wide family of its loss as well (_wide_probes); one that loses
+    them later, with its plan made, gets it from warm_replan."""
     if layout is None:
         layout = cache.layout
     kernel, interpret = _kernel_mode(kernel, interpret)
@@ -3573,74 +3593,31 @@ def warm(
                 )
         return
     cache._set_aot_state(vid, "warming")
-    groups = cache.groups if layout == "blockdiag" else 1
     futures = []
-    for size, count in grid:
-        for off in (0, 1):
-            if should_stop is not None and should_stop():
-                # aborted (pin teardown): no plan is coming, so the
-                # volume must not stay shed-armed in "warming"
-                cache._set_aot_state(vid, "none")
-                return
-            reqs = [(missing, off, size)] * count
-            try:
-                calls, _subs, survivors, a_prep, use, w_true, place = (
-                    _pack_calls(
-                        cache, vid, reqs, kernel, interpret, layout,
-                        DATA_SHARDS, total_shards, record_observed=False,
-                    )
-                )
-            except CacheMiss:
-                # evicted under the planner: nothing to warm — reset the
-                # state so a later direct re-pin doesn't shed forever
-                # against a plan that never ran
-                cache._set_aot_state(vid, "none")
-                return
-            surv_len = int(survivors[0].size)
-            key_place = _key_place(cache, place)
-            keys = []
-            for kind, part, _c, _pad, fetch, tile, n_bucket, _d in calls:
-                shapes = [(fetch, tile)]
-                if kind == "fused":
-                    # a live group's fetch follows its LARGEST span, so
-                    # a batch in this probe's size bucket can land on
-                    # any rung of the bucket's ladder: compile them all,
-                    # or a warmed (size, count) still sheds cold
-                    bucket = part[0][1][4]
-                    shapes = dict.fromkeys(
-                        _fused_fetch_tile(f, groups)
-                        for f in _fused_fetch_rungs(bucket)
-                    )
-                keys.extend(
-                    _call_key(
-                        kind, kernel, groups, w_true, t, f, n_bucket,
-                        len(use), a_prep.shape, surv_len, interpret,
-                        key_place,
-                    )
-                    for f, t in shapes
-                )
-            if isinstance(key_place, int) and key_place >= 2:
-                # lane-sharded: the key's count bucket is the PER-DEVICE
-                # width — a live batch of `count` reads lands anywhere
-                # between ceil(count/n_dev) (spread) and count (every
-                # hot needle in one chunk) per device — and its
-                # fetch(=tile) can be any cover-ladder rung up to the
-                # probe's bucket (stripe-boundary splits shrink the
-                # span, backward alignment grows it to the full
-                # bucket).  Compile every (fetch rung, count rung at or
-                # below the probe's) so no distribution or boundary
-                # placement of a warmed batch width hits a cold shape
-                # (tile/fetch are key[3:5], n_bucket key[5])
-                keys = list(
-                    dict.fromkeys(
-                        key[:3] + (f, f, cb) + key[6:]
-                        for key in keys
-                        for f in _sharded_fetch_rungs(key[4])
-                        for cb in COUNT_BUCKETS
-                        if cb <= key[5]
-                    )
-                )
-            futures.extend(_schedule_aot_compiles(keys))
+    probes = [
+        [(missing, off, size)] * count
+        for size, count in grid for off in (0, 1)
+    ]
+    # a volume that is pinned with two or more data shards down already
+    # (a restart, a promotion) gets the wide family of its loss with the
+    # plan; one that loses them later gets it from warm_replan
+    for reqs in probes + _wide_probes(cache, vid, sizes):
+        if should_stop is not None and should_stop():
+            # aborted (pin teardown): no plan is coming, so the
+            # volume must not stay shed-armed in "warming"
+            cache._set_aot_state(vid, "none")
+            return
+        try:
+            keys = _probe_keys(
+                cache, vid, reqs, kernel, interpret, layout, total_shards
+            )
+        except CacheMiss:
+            # evicted under the planner: nothing to warm — reset the
+            # state so a later direct re-pin doesn't shed forever
+            # against a plan that never ran
+            cache._set_aot_state(vid, "none")
+            return
+        futures.extend(_schedule_aot_compiles(keys))
     if wait:
         for f in futures:
             f.result()
@@ -3651,6 +3628,200 @@ def warm(
         )
     else:  # every shape already warm
         cache._set_aot_state(vid, "done")
+
+
+def _probe_keys(cache, vid, reqs, kernel, interpret, layout, total_shards):
+    """Every call key a live batch shaped like the probe `reqs` can
+    dispatch.  Raises CacheMiss where the volume cannot serve it."""
+    calls, _subs, survivors, a_prep, use, w_true, place = _pack_calls(
+        cache, vid, reqs, kernel, interpret, layout, DATA_SHARDS,
+        total_shards, record_observed=False,
+    )
+    groups = cache.groups if layout == "blockdiag" else 1
+    surv_len = int(survivors[0].size)
+    key_place = _key_place(cache, place)
+    keys = []
+    for kind, part, _c, _pad, fetch, tile, n_bucket, _d in calls:
+        shapes = [(fetch, tile)]
+        if kind == "fused":
+            # a live group's fetch follows its LARGEST span, so
+            # a batch in this probe's size bucket can land on
+            # any rung of the bucket's ladder: compile them all,
+            # or a warmed (size, count) still sheds cold
+            bucket = part[0][1][4]
+            shapes = dict.fromkeys(
+                _call_fetch_tile(f, groups, groups > 1 and w_true > 1)
+                for f in _fused_fetch_rungs(bucket)
+            )
+        elif kind == "xla":
+            # the fallback kernel's fetch is the power of two that
+            # covers the group's largest take, from the bucket below
+            # up to its own
+            shapes = [(f, tile) for f in _xla_fetch_rungs(tile)]
+        keys.extend(
+            _call_key(
+                kind, kernel, groups, w_true, t, f, n_bucket,
+                len(use), a_prep.shape, surv_len, interpret,
+                key_place,
+            )
+            for f, t in shapes
+        )
+    if isinstance(key_place, int) and key_place >= 2:
+        # lane-sharded: the key's count bucket is the PER-DEVICE
+        # width — a live batch of `count` reads lands anywhere
+        # between ceil(count/n_dev) (spread) and count (every
+        # hot needle in one chunk) per device — and its
+        # fetch(=tile) can be any cover-ladder rung up to the
+        # probe's bucket (stripe-boundary splits shrink the
+        # span, backward alignment grows it to the full
+        # bucket).  Compile every (fetch rung, count rung at or
+        # below the probe's) so no distribution or boundary
+        # placement of a warmed batch width hits a cold shape
+        # (tile/fetch are key[3:5], n_bucket key[5])
+        keys = list(
+            dict.fromkeys(
+                key[:3] + (f, f, cb) + key[6:]
+                for key in keys
+                for f in _sharded_fetch_rungs(key[4])
+                for cb in COUNT_BUCKETS
+                if cb <= key[5]
+            )
+        )
+    return keys
+
+
+def _xla_fetch_rungs(bucket: int) -> list[int]:
+    """Every fetch a live sub-request group of size bucket `bucket` can
+    produce on the XLA fallback kernel: the power of two covering its
+    largest take, held between the smallest bucket and its own; and a
+    take lies within LANE - 1 of the bucket below."""
+    i = SIZE_BUCKETS.index(bucket)
+    f = SIZE_BUCKETS[i - 1] if i else bucket
+    rungs = []
+    while f <= bucket:
+        rungs.append(f)
+        f <<= 1
+    return rungs
+
+
+# --- the warm plan follows the loss -------------------------------------------
+#
+# The block-diagonal kernels take the wanted-set width w_true static, and
+# the prepared matrix has 8*pad4(groups*w_true) rows, so every width is
+# its own family of compiled shapes.  The plan made at pin time is the
+# family of ONE wanted shard.  A volume that is two or more data shards
+# down can be asked, in one batch, for any subset of them; it is answered
+# with ONE more family: a batch that wants more than one lost data shard
+# is given the matrix of ALL the volume's lost data shards (_batch_wanted;
+# the row select is in the kernel, so no more bytes leave the device),
+# and its calls take one count a size class (_WIDE_COUNTS) and the
+# powers of two of the fetch ladder (_call_fetch_tile).  That family is
+# twelve shapes on one chip, small enough to compile in the time a
+# failed holder is noticed in (2-3 s a shape cold), and it is planned
+# when the loss is seen: warm_replan.
+
+# the count bucket of a wide call by size bucket: padded rows ride the
+# wire, so the classes whose rows are large pad to few; a group beyond
+# its count goes out as several calls
+_WIDE_COUNTS = dict(zip(SIZE_BUCKETS, (8, 8, 8, 4, 4, 2)))
+
+
+def _batch_wanted(requests, resident, data_shards) -> list[int]:
+    """The wanted set a batch's matrix is built for: the shards its
+    requests name; all the volume's lost data shards where they name
+    more than one of them."""
+    wanted = sorted({r[0] for r in requests})
+    if len(wanted) > 1:
+        lost = [s for s in range(data_shards) if s not in resident]
+        if set(wanted) <= set(lost):
+            return lost
+    return wanted
+
+
+def _call_count(size_bucket: int, n: int, wide: bool) -> int:
+    """The count bucket of a group of `n` sub-requests of one size
+    bucket; `wide` = a block-diagonal call of more than one wanted row."""
+    if wide:
+        return _WIDE_COUNTS[size_bucket]
+    return _bucket(COUNT_BUCKETS, min(n, _max_count(size_bucket)))
+
+
+def _call_fetch_tile(fetch: int, groups: int, wide: bool) -> tuple[int, int]:
+    """(fetch, tile) of a fused call whose group's cover is `fetch`; a
+    wide call takes the next power of two, so that its family has ten
+    fetch rungs for the ladder's eighteen (at most a third more bytes
+    fetched on a 3*2^(n-1) rung)."""
+    if wide:
+        fetch = 1 << (fetch - 1).bit_length()
+    return _fused_fetch_tile(fetch, groups)
+
+
+def _wide_lost(cache, vid) -> list[int]:
+    """The lost data shards of `vid` where its loss asks for the wide
+    family: two or more of them (the family of one wanted shard serves
+    any other volume whole), and not on the mesh, whose plan does not
+    follow a loss yet."""
+    resident = cache.shard_ids(vid)
+    lost = [s for s in range(DATA_SHARDS) if s not in resident]
+    if len(lost) < 2 or cache.placement(vid) == "mesh":
+        return []
+    return lost
+
+
+def _wide_probes(cache, vid, sizes) -> list[list[tuple[int, int, int]]]:
+    """One probe a size and alignment class that wants every lost data
+    shard of `vid`."""
+    lost = _wide_lost(cache, vid)
+    return [
+        [(sid, off, size) for sid in lost]
+        for size in sizes if lost for off in (0, 1)
+    ]
+
+
+def loss_owes_replan(cache: DeviceShardCache, vid: int) -> bool:
+    """Shards of `vid` just went.  True, with the volume back in
+    "warming", where it has a warm plan and its loss can now ask for
+    the wide family; the caller then runs warm_replan off its thread."""
+    if cache.aot_state(vid) == "none" or not _wide_lost(cache, vid):
+        return False
+    cache._set_aot_state(vid, "warming")
+    return True
+
+
+def warm_replan(cache: DeviceShardCache, vid: int) -> tuple[int, int]:
+    """Compile what the volume's present loss can ask for and the
+    registry lacks, on the AOT executor, and wait for it.  -> (shapes
+    queued, shapes already warm).  The state is the caller's: it set
+    "warming" (loss_owes_replan) and sets "done" (replan_done) unless a
+    later loss has superseded this plan.  A read that races the executor
+    is shed to the host and counted, as during the first plan."""
+    kernel, interpret = _kernel_mode()
+    with obs_trace.span("warm_replan", vid=vid) as sp:
+        keys: list[tuple] = []
+        try:
+            for reqs in _wide_probes(cache, vid, cache.warm_sizes):
+                keys += _probe_keys(
+                    cache, vid, reqs, kernel, interpret, cache.layout,
+                    TOTAL_SHARDS,
+                )
+        except CacheMiss:
+            keys = []  # evicted under the planner: nothing to serve
+        keys = list(dict.fromkeys(keys))
+        warm_already = sum(map(_shape_is_warm, keys))
+        futures = _schedule_aot_compiles(keys)
+        sp.annotate(queued=len(futures), warm=warm_already)
+        # one worker, in order: when this job has run, so has every
+        # compile queued before it, a shed read's as well as this plan's
+        futures.append(_aot_executor().submit(lambda: None))
+        for f in futures:
+            f.result()
+    return len(futures) - 1, warm_already
+
+
+def replan_done(cache: DeviceShardCache, vid: int) -> None:
+    """The newest re-plan of `vid` is in.  (A volume evicted meanwhile
+    has no state left, and gets none.)"""
+    cache._set_aot_state(vid, "done")
 
 
 # At the end of the file on purpose: a Pallas executable's persistent-cache

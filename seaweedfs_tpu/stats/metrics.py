@@ -323,6 +323,7 @@ TRACE_STAGES = (
     "response_write",    # headers, range and the body the handler writes
     "mesh_pack",         # lane-sharded planning: stripe split + owner routing
     "mesh_fetch",        # a sharded call's per-device rows fetched to the host
+    "warm_replan",       # the warm plan made again after a pinned volume's loss
 )
 # the FIXED bucket ladder the heartbeat stage digests ride on: volume
 # servers ship per-bucket count deltas over exactly these edges (+Inf
@@ -416,6 +417,22 @@ VOLUME_SERVER_EC_DEVICE_TRANSFERS = Counter(
 for _k in ("h2d_async", "h2d_waited", "d2h_shard_fetched",
            "d2h_shard_skipped"):
     VOLUME_SERVER_EC_DEVICE_TRANSFERS.labels(kind=_k)
+# how wide the reconstruct calls' systems are: a volume one data shard
+# down asks every call for one row and computes one; a volume several
+# data shards down is answered with the one matrix of all its lost data
+# shards whenever a batch wants more than one of them, and computes rows
+# that no request of the call asked for
+VOLUME_SERVER_EC_RECONSTRUCT_ROWS = Counter(
+    "SeaweedFS_volumeServer_ec_reconstruct_rows",
+    "Rows of the reconstruction matrix by resident EC reconstruct call: "
+    "wanted = the distinct lost shards the call's requests asked for, "
+    "computed = the rows the call's program multiplied (its static "
+    "wanted-set width).",
+    ["kind"],
+    registry=REGISTRY,
+)
+for _k in ("wanted", "computed"):
+    VOLUME_SERVER_EC_RECONSTRUCT_ROWS.labels(kind=_k)
 VOLUME_SERVER_EC_MESH_LANE_REQUESTS = Counter(
     "SeaweedFS_volumeServer_ec_mesh_lane_requests",
     "Sub-requests of lane-sharded reconstruct batches by the mesh device "
@@ -440,7 +457,9 @@ VOLUME_SERVER_EC_PIN_SECONDS = Counter(
     "Seconds the pin thread spent bringing a volume's shards into the "
     "device cache, by phase: stage = shard file (or host-tier array) "
     "into the padded staging buffer, in the mesh layout's owner-major "
-    "stripe order, h2d = the transfer, warm = the AOT warm plan.",
+    "stripe order, h2d = the transfer, warm = the AOT warm plan, replan = "
+    "the plan made again off the pin thread after shards of the pinned "
+    "volume went.",
     ["volume", "phase"],
     registry=REGISTRY,
 )

@@ -13,6 +13,7 @@ layer calls it via ``asyncio.to_thread``.
 from __future__ import annotations
 
 import glob
+import itertools
 import logging
 import os
 import queue
@@ -113,6 +114,9 @@ class Store:
         # an exiting process never aborts inside a background jit compile
         self._closing = threading.Event()
         self._pin_threads: list[threading.Thread] = []
+        # vid -> the ticket of its newest warm re-plan still running
+        self._ec_replan_ticket = itertools.count(1)
+        self._ec_replan_latest: dict[int, int] = {}
         # delta queues drained by the heartbeat loop (store.go:66-70)
         self.new_volumes: queue.SimpleQueue[VolumeMessage] = queue.SimpleQueue()
         self.deleted_volumes: queue.SimpleQueue[VolumeMessage] = queue.SimpleQueue()
@@ -552,11 +556,13 @@ class Store:
                 )
                 stage = "warm"
                 t_warm = time.perf_counter()
-                # aot follows the shed knob: with the shed armed the
-                # plan MUST be ahead-of-time (state != "none" routes
-                # cold shapes to host while the executor compiles);
-                # with it disabled the legacy trace-and-execute walk
-                # keeps inline-compile behavior end to end
+                # a plan is made here (the pin), at a promotion
+                # (serving/tiering.py) and after a loss
+                # (_replan_ec_warm_async).  aot follows the shed knob:
+                # with the shed armed the plan MUST be ahead-of-time
+                # (state != "none" routes cold shapes to host while the
+                # executor compiles); with it disabled the legacy
+                # trace-and-execute walk keeps inline-compile behavior
                 rs_resident.warm(
                     cache, ev.id,
                     sizes=cache.warm_sizes,
@@ -580,12 +586,61 @@ class Store:
                 # pinned or claimed by someone else)
                 cache.release_pin_source(ev.id, ev.dir)
 
+        self._start_pin_thread(pin, f"ec-pin-{ev.id}")
+
+    def _start_pin_thread(self, target, name: str) -> None:
         # prune finished threads so mount/unmount churn over a long
         # server lifetime doesn't accumulate dead Thread objects
         self._pin_threads = [t for t in self._pin_threads if t.is_alive()]
-        t = threading.Thread(target=pin, name=f"ec-pin-{ev.id}", daemon=True)
+        t = threading.Thread(target=target, name=name, daemon=True)
         self._pin_threads.append(t)
         t.start()
+
+    def _replan_ec_warm_async(self, ev: EcVolume) -> None:
+        """The warm plan follows the loss (caller holds the store lock;
+        shards of `ev` just went and others stay).  Where the volume is
+        pinned with a plan and is now two or more data shards down, its
+        reads can ask for reconstruct shapes the pin-time plan never
+        made: the state goes back to "warming" here, and a thread of its
+        own compiles what is missing (rs_resident.warm_replan) and sets
+        "done", off this lock and off the RPC's thread, as the pin does.
+        Reads meanwhile shed to the host codec and are counted.  A later
+        loss supersedes an earlier plan: only the newest sets "done"."""
+        cache = self.ec_device_cache
+        if (
+            cache is None
+            or self._closing.is_set()
+            or cache.pin_source(ev.id) != ev.dir
+        ):
+            return
+        from .. import stats
+        from ..ops import rs_resident
+
+        if not rs_resident.loss_owes_replan(cache, ev.id):
+            return
+        ticket = self._ec_replan_latest[ev.id] = next(self._ec_replan_ticket)
+
+        def replan():
+            t0 = time.perf_counter()
+            try:
+                rs_resident.warm_replan(cache, ev.id)
+            except Exception as e:
+                logging.getLogger(__name__).exception(
+                    "ec device-cache re-plan failed for volume %d", ev.id
+                )
+                rs_resident.note_device_failure(
+                    "warm", f"volume {ev.id}: {e!r}"
+                )
+            finally:
+                stats.VOLUME_SERVER_EC_PIN_SECONDS.labels(
+                    volume=str(ev.id), phase="replan"
+                ).inc(time.perf_counter() - t0)
+                with self._lock:
+                    if self._ec_replan_latest.get(ev.id) == ticket:
+                        del self._ec_replan_latest[ev.id]
+                        rs_resident.replan_done(cache, ev.id)
+
+        self._start_pin_thread(replan, f"ec-replan-{ev.id}")
 
     def _location_with_ec_files(self, vid: int, collection: str) -> DiskLocation | None:
         for loc in self.locations:
@@ -624,6 +679,10 @@ class Store:
                 # arrays alive via refcount — eviction is safe)
                 if self.ec_host_cache is not None:
                     self.ec_host_cache.evict(vid)
+                # a re-plan still running speaks for a volume that is gone
+                self._ec_replan_latest.pop(vid, None)
+            elif bits:
+                self._replan_ec_warm_async(ev)
 
     def delete_ec_shards(self, vid: int, shard_ids: list[int], collection: str = "") -> None:
         """Unmount + remove the shard files; drop sidecars when the last

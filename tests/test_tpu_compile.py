@@ -195,6 +195,37 @@ def test_fused_reconstruct_blockdiag(one_chip, count, fetch):
         )
 
 
+# the wide family: what a volume several data shards down is re-planned
+# with (rs_resident.warm_replan): the matrix of all its lost data shards,
+# one count bucket a size class; its smallest and largest shapes
+WIDE_CORNERS = [
+    (rs_resident._WIDE_COUNTS[size], fetch)
+    for size in (rs_resident.SIZE_BUCKETS[0], rs_resident.SIZE_BUCKETS[-1])
+    for fetch in (rs_resident._fused_fetch_rungs(size)[0],
+                  rs_resident._fused_fetch_rungs(size)[-1])
+]  # (8, 2048 -> 4096), (8, 3072 -> 4096), (2, 786432 -> 1 MiB), (2, 2 MiB)
+
+
+@pytest.mark.parametrize("w_true", (2, 3, 4))
+@pytest.mark.parametrize("count,fetch", WIDE_CORNERS)
+def test_fused_reconstruct_blockdiag_wide(one_chip, count, fetch, w_true):
+    fetch, tile = rs_resident._call_fetch_tile(fetch, GROUPS, True)
+    m_gf = np.ones((w_true, K), dtype=np.uint8)
+    a = sds(rs_tpu.prepare_matrix_blockdiag(m_gf, GROUPS).shape, jnp.int8,
+            one_chip)
+    survivors = tuple(
+        sds((L_PAD,), jnp.uint8, one_chip) for _ in range(K)
+    )
+    meta = sds((count,), jnp.int32, one_chip)
+    with rs_resident._quiet_donation():
+        compile_checked(
+            rs_resident._fused_reconstruct_blockdiag.lower(
+                a, survivors, meta, tile=tile, fetch=fetch, k_true=K,
+                w_true=w_true, groups=GROUPS, interpret=False,
+            )
+        )
+
+
 # --- resident scrub ----------------------------------------------------------
 
 
