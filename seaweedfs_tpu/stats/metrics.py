@@ -532,7 +532,7 @@ VOLUME_SERVER_EC_BULK_SECONDS = Counter(
     registry=REGISTRY,
 )
 # the device leg told apart at the boundaries the host crosses, per
-# batch as it runs (Codec._device_leg_tagged): on a device backend the
+# batch as it runs (Codec._enqueue, Codec._fetch): on a device backend the
 # four parts of a pipeline sum to its ec_bulk_seconds{leg="device"}; a
 # CPU codec has no parts and leaves them at zero
 EC_BULK_CODEC_PARTS = ("stage", "enqueue", "fetch", "unstack")
@@ -543,8 +543,9 @@ VOLUME_SERVER_EC_BULK_CODEC_SECONDS = Counter(
     "in one flat host buffer: nothing where the reader leg delivered it "
     "laid out, enqueue = device_put + the kernel call, "
     "which both return before the device is done, fetch = the blocking "
-    "copy back: H2D, kernel and D2H end inside it, unstack = the layout "
-    "undone); zero under a CPU codec.",
+    "copy back: what is left of H2D and kernel once the next batch, "
+    "where one was submitted, is enqueued, and the D2H, unstack = the "
+    "layout undone); zero under a CPU codec.",
     ["pipeline", "part"],
     registry=REGISTRY,
 )
@@ -580,6 +581,19 @@ VOLUME_SERVER_EC_BULK_DIRECT_BATCHES = Counter(
     ["pipeline"],
     registry=REGISTRY,
 )
+# over ec_bulk_batches: the share of a pipeline's batches whose wait for
+# the device was shared with the next batch's transfer (the codec worker
+# keeps two on the device where a successor has been submitted; 0 under
+# a CPU codec and in the serial mode, which never has a successor)
+VOLUME_SERVER_EC_BULK_PIPELINED_BATCHES = Counter(
+    "SeaweedFS_volumeServer_ec_bulk_pipelined_batches",
+    "Stripe batches whose fetch from the device began with their "
+    "successor already enqueued (its device_put and program issued): "
+    "the bulk EC pipelines' codec worker keeps up to two batches on "
+    "the device.",
+    ["pipeline"],
+    registry=REGISTRY,
+)
 VOLUME_SERVER_EC_BULK_OVERLAP_FRACTION = Gauge(
     "SeaweedFS_volumeServer_ec_bulk_overlap_fraction",
     "Leg-active seconds / wall seconds of the last bulk EC pipeline run "
@@ -597,6 +611,7 @@ for _p in EC_BULK_PIPELINES:
         VOLUME_SERVER_EC_BULK_CODEC_SECONDS.labels(pipeline=_p, part=_part)
     VOLUME_SERVER_EC_BULK_BATCHES.labels(pipeline=_p)
     VOLUME_SERVER_EC_BULK_DIRECT_BATCHES.labels(pipeline=_p)
+    VOLUME_SERVER_EC_BULK_PIPELINED_BATCHES.labels(pipeline=_p)
     VOLUME_SERVER_EC_BULK_OVERLAP_FRACTION.labels(pipeline=_p)
 
 # heat-tiered residency ladder (serving/tiering.py): HBM -> host RAM ->

@@ -40,11 +40,24 @@ is also the writer leg's (the ten data rows go to their shard files) and
 verify's is compared against, so both stay plain rows and the worker
 stages its own copy.
 
+The codec worker keeps up to ``DEVICE_DEPTH`` = 2 batches on the device:
+it enqueues batch n+1 (``device_put`` and the program) before it fetches
+batch n, so the H2D of one batch runs beside the program and the D2H of
+the one before it and the wire is not idle while the host enqueues and
+unstacks.  It clocks itself: it
+enqueues a successor only where one has been submitted already, and
+fetches the oldest batch at once where none has, so the serial mode, a
+verb's last batch and a slow reader need no flush
+(``Codec._advance``; ``ec_bulk_pipelined_batches`` counts the batches
+whose fetch began with their successor enqueued).
+
 Stats contract (the dict ``run()`` fills, same keys for all three
 pipelines):
 
   read_s / submit_s / wait_s / write_s   per-leg active seconds
-  device_busy_s                          codec worker active time
+  device_busy_s                          codec worker active time (one
+                                         thread: never two batches'
+                                         lifetimes added up)
   wall_s, fsync_s, batches               caller-filled wall + tail
   overlap                                the mode the run used
 
@@ -85,18 +98,25 @@ from .layout import DATA_SHARDS, LARGE_BLOCK_SIZE
 DEFAULT_STRIDE = 4 * 1024 * 1024
 # In-flight codec batches: the caller may run this far ahead of the codec
 # worker before blocking on a resolve.  3 keeps one batch staging, one on
-# the wire, one landing.  NOTE the overlapped pipeline's peak host
+# the wire, one landing, and a successor submitted by the time the worker
+# looks for one (DEVICE_DEPTH).  NOTE the overlapped pipeline's peak host
 # footprint is 2*prefetch + depth + 2 payloads — the stripe queue, the
 # pending deque, the result queue (payloads ride along for the writer),
 # and one in each leg's hands — plus, where the codec worker stages
-# (encode, verify; not rebuild, whose payload is what it puts), its one
-# staging buffer: 11 buffers in a rebuild (440MB of [10, 4MB] batches at
-# the default stride), 12 in an encode or a scrub, vs the serial mode's
-# 1 and 2.  POOL keeps as many as the widest run had in flight (see
-# BufferPool), so the footprint is the process's from its first bulk
-# verb on; size stride/prefetch down together on memory-tight volume
-# servers.
+# (encode, verify; not rebuild, whose payload is what it puts), its
+# DEVICE_DEPTH staging buffers: 11 buffers in a rebuild (440MB of
+# [10, 4MB] batches at the default stride), 13 in an encode or a scrub,
+# vs the serial mode's 1 and 2.  POOL keeps as many as the widest run had
+# in flight (see BufferPool), so the footprint is the process's from its
+# first bulk verb on; size stride/prefetch down together on memory-tight
+# volume servers.
 PIPELINE_DEPTH = 3
+# Batches the codec worker keeps on the device: it enqueues a submitted
+# successor before it fetches the oldest, never a third (Codec._advance).
+# Two is what the wire can use — one batch's H2D beside the other's
+# program and D2H — and what a staged pipeline pays for in staging
+# buffers.
+DEVICE_DEPTH = 2
 # Threads that read one shard-file batch's rows side by side
 # (read_shard_rows).  A constant from a measurement, not a knob: alone on
 # the chip tool's 13-core host ten 4MB preadv out of the page cache take
@@ -173,7 +193,7 @@ class BufferPool:
     batch, is a fresh allocation (the small one is let go, so the pool
     settles on the largest batch the process runs).  What bounds the
     buffers in flight is the pipeline (its queues hold 2*prefetch +
-    depth + 2 payloads and one staging buffer at most); `keep`, which
+    depth + 2 payloads and DEVICE_DEPTH staging buffers at most); `keep`, which
     run() sets from those same numbers, bounds what stays here
     afterwards.  A buffer that never comes back — a batch dropped on the
     abort path — is the garbage collector's: nothing waits for it.
@@ -217,6 +237,20 @@ class BufferPool:
 POOL = BufferPool()
 
 
+@dataclass
+class _Batch:
+    """One submitted batch on its way through the device leg: what
+    submit() was given, the handle it returned, and from the enqueue on
+    what the fetch needs."""
+
+    shards: np.ndarray
+    direct: bool
+    handle: Future
+    staged: np.ndarray | None = None
+    out: object = None
+    busy_s: float = 0.0
+
+
 class Codec:
     """Wraps RSCodec so the matrix-multiply leg can run pipelined.
     submit() returns an opaque handle; resolve() turns it into a numpy
@@ -226,7 +260,8 @@ class Codec:
     Device path: one worker thread owns the whole device leg — stage the
     block-diagonal layout, jax.device_put, dispatch the kernel, fetch the
     result — so that the blocking transfers never serialize against the
-    caller's file reads/writes.  CPU
+    caller's file reads/writes, and keeps up to DEVICE_DEPTH batches on
+    the device (_advance).  CPU
     backends get the same worker thread when `threaded` (the overlap
     mode): pread/pwrite and the native kernel all release the GIL, so the
     three legs genuinely overlap."""
@@ -264,6 +299,11 @@ class Codec:
                 pipeline=pipeline
             )
         )
+        self._pipelined_batches = (
+            _metrics.VOLUME_SERVER_EC_BULK_PIPELINED_BATCHES.labels(
+                pipeline=pipeline
+            )
+        )
         self._pool = None
         if self.device:
             from ...ops import rs_tpu
@@ -275,6 +315,10 @@ class Codec:
             self._pool = ThreadPoolExecutor(
                 max_workers=1, thread_name_prefix="ec-dev"
             )
+            # submit() appends, the worker pops: one _advance a batch
+            self._submitted: deque = deque()
+            # the worker's own: enqueued and not fetched yet, oldest first
+            self._on_device: deque = deque()
         else:
             self._codec = rs.RSCodec(backend=self.backend)
             if threaded:
@@ -303,7 +347,10 @@ class Codec:
         is, where otherwise it lays its own copy out in a staging
         buffer."""
         if self.device:
-            return self._pool.submit(self._device_leg, shards, direct)
+            batch = _Batch(shards, direct, Future())
+            self._submitted.append(batch)
+            self._pool.submit(self._advance)
+            return batch.handle
         if self._pool is not None:
             return self._pool.submit(self._host_leg, shards)
         return self._host_leg(shards)
@@ -320,53 +367,91 @@ class Codec:
         )
         return out
 
-    def _device_leg(self, shards: np.ndarray, direct: bool) -> np.ndarray:
-        """Both transfers ship FLAT 1-D buffers
-        (apply_matrix_device_flat)."""
-        t0 = time.perf_counter()
-        parity = self._device_leg_tagged(shards, direct)
-        dur = time.perf_counter() - t0
-        self.busy_s += dur
-        devledger.record(
-            workload=self.workload, busy_s=dur, dispatches=1,
-            nbytes=int(shards.nbytes) + int(parity.nbytes),
-        )
-        return parity
+    def _advance(self) -> None:
+        """One turn of the device worker, queued once for every submitted
+        batch: enqueue that batch, then fetch while DEVICE_DEPTH batches
+        are on the device or no successor has been submitted — so a
+        successor that is there goes on the wire before the oldest batch
+        is waited for, and nothing ever waits for a successor that is
+        not (the serial mode, a verb's last batch, a reader that is
+        behind).  Handles complete oldest first; a batch whose enqueue or
+        fetch raises fails its own handle and the worker goes on, so
+        shutdown() leaves nothing on the device."""
+        batch = self._submitted.popleft()
+        try:
+            self._timed(self._enqueue, batch)
+            self._on_device.append(batch)
+        except BaseException as e:  # noqa: BLE001 — the handle's to raise
+            batch.handle.set_exception(e)
+        while self._on_device and (
+            len(self._on_device) >= DEVICE_DEPTH or not self._submitted
+        ):
+            batch = self._on_device.popleft()
+            if self._on_device:
+                self._pipelined_batches.inc()
+            try:
+                parity = self._timed(self._fetch, batch)
+            except BaseException as e:  # noqa: BLE001 — as above
+                batch.handle.set_exception(e)
+                continue
+            devledger.record(
+                workload=self.workload, busy_s=batch.busy_s, dispatches=1,
+                nbytes=int(batch.shards.nbytes) + int(parity.nbytes),
+            )
+            batch.handle.set_result(parity)
 
-    def _device_leg_tagged(
-        self, shards: np.ndarray, direct: bool
-    ) -> np.ndarray:
-        """The leg in four parts, each an event of a profiler capture and
-        a term of ec_bulk_codec_seconds, named for what the HOST waits on (the
+    def _timed(self, step, batch: _Batch):
+        """`busy_s` is the worker's active time: the seconds of its steps
+        one after another, a batch's two steps to that batch's ledger
+        record, never the lifetimes of two batches that overlap."""
+        t0 = time.perf_counter()
+        try:
+            return step(batch)
+        finally:
+            dur = time.perf_counter() - t0
+            self.busy_s += dur
+            batch.busy_s += dur
+
+    def _enqueue(self, batch: _Batch) -> None:
+        """The first two of the leg's four parts, _fetch the other two:
+        each an event of a profiler capture and a term of
+        ec_bulk_codec_seconds, named for what the HOST waits on (the
         device trace has the kernel's own time; nothing here
-        synchronises to tell them apart): `bulk_stage` lays the batch
+        synchronises to tell them apart).  `bulk_stage` lays the batch
         out in one flat pooled host buffer (nothing to do for a `direct`
         payload, which arrived laid out), `bulk_enqueue` is device_put
         plus the kernel call (both return before the device is done),
-        `bulk_fetch` the blocking copy back — where the host waits for
-        H2D, kernel and D2H to finish — and `bulk_unstack` the layout
-        undone.  The boundaries are shared, so the parts sum to the leg."""
+        `bulk_fetch` the blocking copy back — what is left of H2D and
+        kernel once the successor, if one was there, is on the wire,
+        and the D2H — and `bulk_unstack` the layout undone.  One thread
+        runs them, so they are sequential events on its line and sum to
+        the leg; both transfers ship FLAT 1-D buffers
+        (apply_matrix_device_flat).  Asking for the copy back at
+        dispatch (copy_to_host_async) bought nothing on the chip: the
+        put's transfer is the wire's floor and the D2H runs beside the
+        successor's (PERF.md PR 34)."""
         import jax
 
+        shards, direct = batch.shards, batch.direct
         k, b = shards.shape
         groups = self.segments(b)
         blockdiag = groups > 1
         clock = time.perf_counter
         # the with-block tags the dispatch IN the leg thread — the pool
         # worker never inherits the submitter's ledger context (GL116's
-        # lexical-tagging contract anchors here, not in _device_leg)
+        # lexical-tagging contract anchors here, not in _advance)
         with devledger.workload(self.workload):
             t0 = clock()
             with obs_trace.event("bulk_stage", bytes=int(shards.nbytes)):
                 # device_put reads this memory until the transfer is done
                 # (the CPU backend may alias it for the life of `x`), so
-                # a staging buffer goes back to the pool only below,
+                # a staging buffer goes back to the pool only in _fetch,
                 # after the blocking fetch of the program that consumed
                 # `x`, and a direct payload where its pipeline gives it
-                # back, after resolve() — so after that same fetch.  One
-                # worker runs one batch at a time, so one staging buffer
-                # circulates; a leg that staged batch n+1 while it
-                # fetched n would hold two, by the same take and give.
+                # back, after resolve() — so after that same fetch.  The
+                # worker stages batch n+1 before it fetches n, so
+                # DEVICE_DEPTH staging buffers circulate, by the same
+                # take and give.
                 if direct:
                     staged = shards
                     self._direct_batches.inc()
@@ -376,6 +461,7 @@ class Codec:
                         self._tpu.stack_segments(shards, out=staged)
                     else:
                         np.copyto(staged, shards)
+                batch.staged = staged
             t1 = clock()
             with obs_trace.event("bulk_enqueue"):
                 x = jax.device_put(staged.reshape(-1))
@@ -397,27 +483,34 @@ class Codec:
                         kernel=self.backend,
                         interpret=self._interpret,
                     )
+                batch.out = out
             t2 = clock()
-            with obs_trace.event("bulk_fetch"):
-                # graftlint: allow(device-sync): the codec worker's own
-                # D2H — fetched on the dedicated device leg, timed busy_s
-                flat = np.asarray(out)
-            if not direct:
-                POOL.give(staged)
-            t3 = clock()
-            with obs_trace.event("bulk_unstack"):
-                if blockdiag:
-                    parity = self._tpu.unstack_segments(
-                        flat.reshape(groups * self.rows, b // groups),
-                        self.rows,
-                    )
-                else:
-                    parity = flat.reshape(self.rows, b)
-            t4 = clock()
-        for counter, dur in zip(
-            self._part_seconds, (t1 - t0, t2 - t1, t3 - t2, t4 - t3)
-        ):
-            counter.inc(dur)
+        self._part_seconds[0].inc(t1 - t0)
+        self._part_seconds[1].inc(t2 - t1)
+
+    def _fetch(self, batch: _Batch) -> np.ndarray:
+        b = batch.shards.shape[1]
+        groups = self.segments(b)
+        clock = time.perf_counter
+        t2 = clock()
+        with obs_trace.event("bulk_fetch"):
+            # graftlint: allow(device-sync): the codec worker's own
+            # D2H — fetched on the dedicated device leg, timed busy_s
+            flat = np.asarray(batch.out)
+        if not batch.direct:
+            POOL.give(batch.staged)
+        t3 = clock()
+        with obs_trace.event("bulk_unstack"):
+            if groups > 1:
+                parity = self._tpu.unstack_segments(
+                    flat.reshape(groups * self.rows, b // groups),
+                    self.rows,
+                )
+            else:
+                parity = flat.reshape(self.rows, b)
+        t4 = clock()
+        self._part_seconds[2].inc(t3 - t2)
+        self._part_seconds[3].inc(t4 - t3)
         return parity
 
     def resolve(self, handle) -> np.ndarray:
@@ -618,7 +711,10 @@ def run(
     overlap = cfg.overlap if overlap is None else bool(overlap)
     prefetch = cfg.prefetch if prefetch is None else prefetch
     payloads = 2 * max(1, prefetch) + depth + 2 if overlap else 1
-    POOL.keep = payloads + (0 if direct else 1)
+    # the codec worker's staging buffers: one a batch on the device,
+    # and serial mode never submits a second before the first resolved
+    staging = 0 if direct else (DEVICE_DEPTH if overlap else 1)
+    POOL.keep = payloads + staging
     pick = to_codec if to_codec is not None else lambda payload: payload
 
     def submit(payload):

@@ -178,7 +178,8 @@ class TestStatsContract:
 
 
 class TestByteEquality:
-    def test_encode_overlap_matches_serial(self, tmp_path):
+    @pytest.mark.parametrize("backend", ["cpu", "xla", "pallas"])
+    def test_encode_overlap_matches_serial(self, tmp_path, backend):
         payload = None
         digests = []
         for overlap in (False, True):
@@ -189,13 +190,16 @@ class TestByteEquality:
                 with open(base + ".dat", "wb") as f:
                     f.write(payload.tobytes())
             encoder.write_ec_files(
-                base, backend="cpu", large_block=8192, small_block=1024,
+                base, backend=backend, large_block=8192, small_block=1024,
                 overlap=overlap,
             )
             digests.append(shard_bytes(base))
         assert digests[0] == digests[1]
 
-    def test_rebuild_overlap_matches_serial_and_original(self, tmp_path):
+    @pytest.mark.parametrize("backend", ["cpu", "xla", "pallas"])
+    def test_rebuild_overlap_matches_serial_and_original(
+        self, tmp_path, backend
+    ):
         base = str(tmp_path / "r")
         make_dat(base + ".dat", 8192 * 10 + 300)
         encoder.write_ec_files(
@@ -206,9 +210,12 @@ class TestByteEquality:
             for i in (2, 7, 10, 13):
                 os.remove(base + to_ext(i))
             encoder.rebuild_ec_files(
-                base, backend="cpu", stride=4096, overlap=overlap
+                base, backend=backend, stride=4096, overlap=overlap
             )
             assert shard_bytes(base) == originals, f"overlap={overlap}"
+            assert encoder.verify_ec_files(
+                base, backend=backend, stride=4096, overlap=overlap
+            )[0] == [0, 0, 0, 0]
 
     def test_rebuild_of_sparse_volume_stays_sparse(self, tmp_path):
         """Where encode punched holes, rebuild must punch holes too —
@@ -348,6 +355,20 @@ def _handed_since(pipeline, before):
     return tuple(b - a for a, b in zip(before, _handed(pipeline)))
 
 
+def _counter(family, pipeline):
+    from seaweedfs_tpu.stats import metrics as m
+
+    return getattr(m, family).labels(pipeline=pipeline)._value.get()
+
+
+def _direct(pipeline):
+    return _counter("VOLUME_SERVER_EC_BULK_DIRECT_BATCHES", pipeline)
+
+
+def _pipelined(pipeline):
+    return _counter("VOLUME_SERVER_EC_BULK_PIPELINED_BATCHES", pipeline)
+
+
 class PoisonPool(bulk.BufferPool):
     """Every buffer goes out all 0xFF, past the batch's view too: a byte
     the reader or the staging copy does not write ends up in a file."""
@@ -455,6 +476,38 @@ class TestPooledBuffers:
             assert pool.keep == 1
             assert fresh_by_verb == [0, 0]
 
+    @pytest.mark.parametrize("overlap", [True, False])
+    @pytest.mark.parametrize("backend", ["cpu", "xla", "pallas"])
+    def test_a_staged_pipeline_keeps_a_buffer_for_each_batch_on_the_device(
+        self, tmp_path, pool, backend, overlap
+    ):
+        """Two encode verbs of six batches each: a payload a batch and,
+        under a device codec, the staging buffer the worker lays it out
+        in.  The worker stages a successor before it fetches the oldest
+        batch, so an overlapped run keeps DEVICE_DEPTH buffers beside
+        its payloads where a rebuild keeps none; the serial mode, which
+        never has a second batch submitted, keeps one."""
+        base = str(tmp_path / "1")
+        want = _host_codec_shards(make_dat(base + ".dat", _DAT_SIZE, seed=29))
+        a_batch = 1 if backend == "cpu" else 2
+        fresh_by_verb = []
+        for _verb in range(2):
+            before = _handed("encode")
+            _encode_short_tail(base, backend, overlap)
+            reused, fresh = _handed_since("encode", before)
+            assert reused + fresh == 6 * a_batch
+            fresh_by_verb.append(fresh)
+            assert len(pool._free) <= pool.keep
+            assert shard_bytes(base) == want
+        if overlap:
+            assert pool.keep == (
+                2 * 2 + bulk.PIPELINE_DEPTH + 2 + bulk.DEVICE_DEPTH
+            )
+            assert sum(fresh_by_verb) <= pool.keep
+        else:
+            assert pool.keep == 1 + 1
+            assert fresh_by_verb == [a_batch, 0]
+
     @pytest.mark.parametrize("leg", ["reader", "writer"])
     def test_failed_leg_ends_the_run_and_leaves_the_pool_usable(
         self, tmp_path, pool, monkeypatch, leg
@@ -490,10 +543,13 @@ class TestPooledBuffers:
     def test_staging_buffer_is_kept_until_its_batch_is_fetched(
         self, monkeypatch, backend
     ):
-        """Six batches of distinct content queued on the worker at once:
-        the staging buffer goes back to the pool only after the blocking
-        fetch of the batch that staged it, and every parity is right."""
+        """Six batches of distinct content submitted before the worker's
+        first turn: it stages and enqueues a successor before it fetches
+        the oldest batch, so two staging buffers circulate; each goes
+        back to the pool only after the blocking fetch of the batch that
+        staged it, and every parity is right."""
         import contextlib
+        import threading
 
         log = []
 
@@ -512,9 +568,10 @@ class TestPooledBuffers:
                 super().give(batch)
 
         pool = LoggingPool()
-        pool.keep = 1
+        pool.keep = bulk.DEVICE_DEPTH
         monkeypatch.setattr(bulk, "POOL", pool)
         before = _handed("encode")
+        pipelined = _pipelined("encode")
         monkeypatch.setattr(bulk.obs_trace, "event", logged_event)
         host = rs.RSCodec(backend="cpu")
         matrix = host.matrix[10:]
@@ -524,32 +581,26 @@ class TestPooledBuffers:
             for _ in range(6)
         ]
         codec = bulk.Codec(matrix, backend, threaded=True)
+        gate = threading.Event()
         try:
+            codec._pool.submit(gate.wait)
             handles = [codec.submit(b) for b in batches]
+            gate.set()
             for b, h in zip(batches, handles):
                 np.testing.assert_array_equal(
                     codec.resolve(h), host.apply_matrix(matrix, b)
                 )
         finally:
+            gate.set()
             codec.shutdown()
-        assert log == [
-            "take", "bulk_stage", "bulk_enqueue", "bulk_fetch", "give",
-            "bulk_unstack",
-        ] * 6
-        assert _handed_since("encode", before) == (5, 1)
+        enqueue = ["take", "bulk_stage", "bulk_enqueue"]
+        fetch = ["bulk_fetch", "give", "bulk_unstack"]
+        assert log == enqueue + (enqueue + fetch) * 5 + fetch
+        assert _handed_since("encode", before) == (4, 2)
+        assert _pipelined("encode") - pipelined == 5
 
 
 # ------------------------------------- rebuild's read leg: layout, fan-out
-
-
-def _counter(family, pipeline):
-    from seaweedfs_tpu.stats import metrics as m
-
-    return getattr(m, family).labels(pipeline=pipeline)._value.get()
-
-
-def _direct(pipeline):
-    return _counter("VOLUME_SERVER_EC_BULK_DIRECT_BATCHES", pipeline)
 
 
 def _bulk_threads():
@@ -797,6 +848,197 @@ class TestDirectRebuild:
             codec.shutdown()
         np.testing.assert_array_equal(direct, host.apply_matrix(matrix, plain))
         np.testing.assert_array_equal(staged, direct)
+
+
+# ------------------------------- the codec worker: two on the device
+
+
+class DeviceSeams:
+    """jax.device_put and bulk's np.asarray wrapped: a batch is on the
+    device from its put until its fetch has returned.  `fail` names the
+    seam ("put" / "fetch") whose sixth call raises."""
+
+    def __init__(self, monkeypatch, fail=None):
+        import jax
+
+        self.on_device = self.most = self.puts = self.fetches = 0
+        self.fail = fail
+        real_put = jax.device_put
+
+        def device_put(x, *args, **kw):
+            if isinstance(x, np.ndarray) and x.ndim == 1:  # a flat batch
+                self.puts += 1
+                if self.fail == "put" and self.puts == 6:
+                    raise RuntimeError("boom-put")
+                self.on_device += 1
+                self.most = max(self.most, self.on_device)
+            return real_put(x, *args, **kw)
+
+        seams = self
+
+        class Numpy:
+            """bulk's `np`: numpy, but for asarray of a device array."""
+
+            def __getattr__(self, name):
+                return getattr(np, name)
+
+            def asarray(self, a, *args, **kw):
+                if not isinstance(a, jax.Array):
+                    return np.asarray(a, *args, **kw)
+                seams.fetches += 1
+                if seams.fail == "fetch" and seams.fetches == 6:
+                    seams.on_device -= 1
+                    raise RuntimeError("boom-fetch")
+                out = np.asarray(a, *args, **kw)
+                seams.on_device -= 1
+                return out
+
+        monkeypatch.setattr(jax, "device_put", device_put)
+        monkeypatch.setattr(bulk, "np", Numpy())
+
+
+def _codec_parts(pipeline):
+    from seaweedfs_tpu.stats import metrics as m
+
+    return sum(
+        m.VOLUME_SERVER_EC_BULK_CODEC_SECONDS.labels(
+            pipeline=pipeline, part=part
+        )._value.get()
+        for part in m.EC_BULK_CODEC_PARTS
+    )
+
+
+class TestTwoOnTheDevice:
+    @pytest.mark.parametrize("backend", ["pallas", "xla"])
+    def test_handles_complete_in_plan_order_two_on_the_device_at_most(
+        self, monkeypatch, backend
+    ):
+        """Eight direct batches submitted before the worker's first
+        turn: every fetch but the last begins with the successor
+        enqueued, never a third batch, and the handles complete in the
+        order they were submitted."""
+        import threading
+
+        seams = DeviceSeams(monkeypatch)
+        host = rs.RSCodec(backend="cpu")
+        matrix = host.matrix[10:]
+        rng = np.random.default_rng(36)
+        batches = [
+            rng.integers(0, 256, size=(10, 1000), dtype=np.uint8)
+            for _ in range(8)
+        ]
+        done = []
+        before = _pipelined("rebuild")
+        codec = bulk.Codec(matrix, backend, threaded=True, pipeline="rebuild")
+        gate = threading.Event()
+        try:
+            assert codec.segments(1000) == 1  # plain rows are direct as is
+            codec._pool.submit(gate.wait)
+            handles = [codec.submit(b, direct=True) for b in batches]
+            for n, h in enumerate(handles):
+                h.add_done_callback(lambda _h, n=n: done.append(n))
+            gate.set()
+            for b, h in zip(batches, handles):
+                np.testing.assert_array_equal(
+                    codec.resolve(h), host.apply_matrix(matrix, b)
+                )
+        finally:
+            gate.set()
+            codec.shutdown()
+        assert done == list(range(8))
+        assert (seams.puts, seams.fetches, seams.on_device) == (8, 8, 0)
+        assert seams.most == bulk.DEVICE_DEPTH
+        assert _pipelined("rebuild") - before == 7
+        assert _bulk_threads() == []
+
+    @pytest.mark.parametrize("overlap", [True, False])
+    @pytest.mark.parametrize("backend", ["pallas", "xla", "cpu"])
+    def test_a_successor_is_enqueued_only_where_one_was_submitted(
+        self, tmp_path, pool, monkeypatch, backend, overlap
+    ):
+        """Encode, rebuild and verify through the seams: never more
+        than two batches on the device, the parts sum to device_busy_s,
+        and a fetch counts as pipelined only where a successor was
+        there: never in the serial mode, never for a one-batch plan,
+        never under the host codec, at most all but a verb's last."""
+        seams = DeviceSeams(monkeypatch)
+        base = str(tmp_path / "1")
+        want = _host_codec_shards(make_dat(base + ".dat", _DAT_SIZE, seed=37))
+        verbs = {
+            "encode": lambda stats: ec.write_ec_files(
+                base, backend=backend, stride=4096, large_block=_LARGE,
+                small_block=_SMALL, overlap=overlap, prefetch=2, stats=stats,
+            ),
+            "rebuild": lambda stats: ec.rebuild_ec_files(
+                base, backend=backend, stride=512, overlap=overlap,
+                prefetch=2, stats=stats,
+            ),
+            "verify": lambda stats: ec.verify_ec_files(
+                base, backend=backend, stride=512, overlap=overlap,
+                prefetch=2, stats=stats,
+            ),
+            # the whole shard in one batch
+            "rebuild-one-batch": lambda stats: ec.rebuild_ec_files(
+                base, backend=backend, stride=_SHARD_SIZE, overlap=overlap,
+                prefetch=2, stats=stats,
+            ),
+        }
+        for name, verb in verbs.items():
+            pipeline = name.split("-")[0]
+            if pipeline == "rebuild":
+                for lost in (3, 11):
+                    os.remove(base + to_ext(lost))
+            pipelined, parts = _pipelined(pipeline), _codec_parts(pipeline)
+            stats = {}
+            verb(stats)
+            assert shard_bytes(base) == want, name
+            pipelined = _pipelined(pipeline) - pipelined
+            parts = _codec_parts(pipeline) - parts
+            busy = stats["device_busy_s"]
+            if backend == "cpu":
+                assert (pipelined, parts, seams.puts) == (0, 0, 0), name
+                continue
+            assert seams.on_device == 0 and seams.most <= 2, name
+            assert 0 < parts <= busy, (name, parts, busy)
+            assert busy - parts <= max(0.05 * busy, 0.005), (name, parts, busy)
+            if overlap and stats["batches"] > 1:
+                assert 0 <= pipelined <= stats["batches"] - 1, name
+            else:
+                assert pipelined == 0, name
+        if backend != "cpu":
+            assert seams.puts == seams.fetches == 6 + 24 + 24 + 1
+            if not overlap:
+                assert seams.most == 1
+
+    @pytest.mark.parametrize("seam", ["put", "fetch"])
+    @pytest.mark.parametrize("backend", ["pallas", "xla"])
+    def test_a_failed_enqueue_or_fetch_ends_the_run_and_leaves_no_thread(
+        self, tmp_path, pool, monkeypatch, backend, seam
+    ):
+        base = str(tmp_path / "1")
+        make_dat(base + ".dat", _DAT_SIZE, seed=38)
+        _encode_short_tail(base, "cpu", True)
+        want = shard_bytes(base)
+        os.remove(base + to_ext(5))
+        with monkeypatch.context() as patch:
+            seams = DeviceSeams(patch, fail=seam)
+            t0 = time.monotonic()
+            with pytest.raises(RuntimeError, match=f"boom-{seam}"):
+                ec.rebuild_ec_files(
+                    base, backend=backend, stride=512, overlap=True,
+                    prefetch=2,
+                )
+            assert time.monotonic() - t0 < 10.0
+            # shutdown() drained what was submitted behind the failure
+            assert seams.on_device == 0 and seams.most <= 2
+        assert _bulk_threads() == []
+        os.remove(base + to_ext(5))  # what the failed run left of it
+        assert ec.rebuild_ec_files(
+            base, backend=backend, stride=512, overlap=True, prefetch=2
+        ) == [5]
+        assert shard_bytes(base) == want
+        assert 0 < len(pool._free) <= pool.keep
+        assert _bulk_threads() == []
 
 
 # ------------------------------------------------- .vif + fsync satellite
