@@ -4,7 +4,6 @@ time with nested children, precedence between two threads, `no_request`
 against `unspanned`, sections open across the window's edges, a capture
 without the program's spans, and the capture found by its place on disk.
 """
-import json
 import os
 import sys
 from types import SimpleNamespace
@@ -14,11 +13,11 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, REPO)
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-from benchmark import metrics_eval, trace  # noqa: E402
+import declarations  # noqa: E402
+from benchmark import trace  # noqa: E402
 from benchmark.reducers import host_spans  # noqa: E402
-
-BENCH = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
 
 
 def _event(name, start_us, dur_us=0, **stats):
@@ -177,23 +176,4 @@ def test_the_capture_is_found_by_its_place_and_held_to_the_trace(
 
 
 def test_every_reader_of_this_reducer_names_a_mode_and_known_stages():
-    from seaweedfs_tpu.stats import TRACE_STAGES
-
-    # what is in a capture only: pairs, and the bulk pipelines' events
-    sections = {"get", "get_queued", "batch_window", "bulk_run"}
-    events = {"bulk_read", "bulk_write", "bulk_stage", "bulk_enqueue",
-              "bulk_fetch", "bulk_unstack"}
-    mine = [m for m in BENCH["per_layer"]
-            if metrics_eval.load_reader(m["name"]).get("reducer")
-            == "host_spans"]
-    assert len(mine) == 10
-    for m in mine:
-        reader = metrics_eval.load_reader(m["name"])
-        assert reader["mode"] in host_spans.MODES
-        assert m["source"] == "program_span"
-        named = (reader.get("spans", []) + reader.get("minus", [])
-                 + reader.get("precedence", []))
-        assert named and set(named) <= (
-            set(TRACE_STAGES) | sections | events), m["name"]
-        if reader["mode"] == "idle":
-            assert reader["open"] in sections
+    declarations.check_host_spans_readers(declarations.load(REPO), REPO)

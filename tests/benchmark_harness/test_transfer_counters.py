@@ -1,10 +1,9 @@
-"""`ec_device_transfers_total` (PR 27) as the harness would read it: the
-ratio a `benchmark/layer_metrics/mesh_shards_skipped_pct.json` would
-hold (PERF.md section 7, ask 7: the file waits for a `benchmark` issue,
-because `test_mesh_cell.py` holds the mesh cell's own metrics at six),
-over the program's own `/metrics` text, parsed by the harness's parser.
-A program without the family (the parent of PR 27) gives the reader
-nothing to read, and the metric is left out, not failed.
+"""`ec_device_transfers_total` (PR 27) as the harness reads it:
+`benchmark/layer_metrics/mesh_shards_skipped_pct.json` (PERF.md section
+7, ask 7, landed by PR 35), over the program's own `/metrics` text,
+parsed by the harness's parser.  A program without the family (the
+parent of PR 27) gives the reader nothing to read, and the metric is
+left out, not failed.
 """
 import os
 import sys
@@ -23,12 +22,17 @@ from seaweedfs_tpu.ops import rs, rs_resident  # noqa: E402
 from seaweedfs_tpu.stats import metrics as stats_metrics  # noqa: E402
 
 FAMILY = "ec_device_transfers_total"
-SKIPPED_PCT = {
+# the ratio ask 7 specified: skipped result shards over all of them
+ASKED = {
     "num": [{"series": FAMILY, "labels": {"kind": "d2h_shard_skipped"}}],
     "den": [{"series": FAMILY, "labels": {"kind": "d2h_shard_skipped"}},
             {"series": FAMILY, "labels": {"kind": "d2h_shard_fetched"}}],
     "scale": 100,
 }
+
+
+def skipped_pct() -> dict:
+    return metrics_eval.load_reader("mesh_shards_skipped_pct")["ratio"]
 
 
 def scrape() -> str:
@@ -57,10 +61,14 @@ def window():
     return before, after
 
 
+def test_the_data_file_holds_the_asked_ratio():
+    assert skipped_pct() == ASKED
+
+
 def test_the_reader_takes_the_skipped_share_of_the_window(window):
     before, after = (parse_metrics(text) for text in window)
     # one call, four shards, one of them without an asked-for row
-    assert metrics_eval.ratio(SKIPPED_PCT, before, after, {}) == 25.0
+    assert metrics_eval.ratio(skipped_pct(), before, after, {}) == 25.0
 
 
 def test_a_program_without_the_family_leaves_the_metric_out(window):
@@ -69,7 +77,7 @@ def test_a_program_without_the_family_leaves_the_metric_out(window):
             line for line in text.splitlines() if FAMILY not in line))
         for text in window)
     assert after  # the parent's text still has every other family
-    assert metrics_eval.ratio(SKIPPED_PCT, before, after, {}) is None
+    assert metrics_eval.ratio(skipped_pct(), before, after, {}) is None
 
 
 def test_the_family_is_exposed_with_all_four_kinds_from_the_start():
