@@ -594,6 +594,18 @@ VOLUME_SERVER_EC_BULK_PIPELINED_BATCHES = Counter(
     ["pipeline"],
     registry=REGISTRY,
 )
+# over ec_bulk_batches: the share of a pipeline's batches whose shard
+# rows the writer leg wrote side by side (bulk.write_shard_rows; 0 in
+# the serial mode, which writes inline, and for a batch of fewer than
+# two rows that are not holes)
+VOLUME_SERVER_EC_BULK_PARALLEL_WRITE_BATCHES = Counter(
+    "SeaweedFS_volumeServer_ec_bulk_parallel_write_batches",
+    "Stripe batches whose shard rows the bulk EC pipelines' writer leg "
+    "handed to its writer threads with two or more rows written at "
+    "once, each to its own shard file from the row's own buffer.",
+    ["pipeline"],
+    registry=REGISTRY,
+)
 VOLUME_SERVER_EC_BULK_OVERLAP_FRACTION = Gauge(
     "SeaweedFS_volumeServer_ec_bulk_overlap_fraction",
     "Leg-active seconds / wall seconds of the last bulk EC pipeline run "
@@ -612,6 +624,7 @@ for _p in EC_BULK_PIPELINES:
     VOLUME_SERVER_EC_BULK_BATCHES.labels(pipeline=_p)
     VOLUME_SERVER_EC_BULK_DIRECT_BATCHES.labels(pipeline=_p)
     VOLUME_SERVER_EC_BULK_PIPELINED_BATCHES.labels(pipeline=_p)
+    VOLUME_SERVER_EC_BULK_PARALLEL_WRITE_BATCHES.labels(pipeline=_p)
     VOLUME_SERVER_EC_BULK_OVERLAP_FRACTION.labels(pipeline=_p)
 
 # heat-tiered residency ladder (serving/tiering.py): HBM -> host RAM ->

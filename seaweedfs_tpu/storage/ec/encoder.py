@@ -25,7 +25,7 @@ import numpy as np
 from ...ops import rs
 from .. import needle_map
 from . import bulk
-from .bulk import DEFAULT_STRIDE, read_stripe, write_or_seek  # re-exported
+from .bulk import DEFAULT_STRIDE, read_stripe  # re-exported
 from .layout import (
     DATA_SHARDS,
     LARGE_BLOCK_SIZE,
@@ -136,6 +136,7 @@ def write_ec_files(
             plan.append((row_start, block_size, off, step))
 
     outputs = [open(base_name + to_ext(i), "wb") for i in range(TOTAL_SHARDS)]
+    writers = bulk.row_writers() if use_overlap else None
     t_start = time.perf_counter()
     try:
         with open(dat_path, "rb") as f:
@@ -148,11 +149,12 @@ def write_ec_files(
                 )
 
             def write_batch(desc, data, parity):
-                for i in range(DATA_SHARDS):
-                    write_or_seek(outputs[i], data[i])
+                # the data rows are written from the payload itself, so
+                # it goes back to the pool only once every write ended
+                bulk.write_shard_rows(
+                    "encode", outputs, [*data, *parity], writers
+                )
                 bulk.POOL.give(data)
-                for i in range(codec.rows):
-                    write_or_seek(outputs[DATA_SHARDS + i], parity[i])
 
             t = bulk.run(
                 "encode", plan, read_batch, codec, write_batch,
@@ -160,6 +162,8 @@ def write_ec_files(
             )
         _finish_outputs(outputs, fsync, t)
     finally:
+        if writers is not None:
+            writers.shutdown()
         codec.shutdown()
         for o in outputs:
             o.close()
@@ -185,7 +189,7 @@ def rebuild_ec_files(
     Reconstruct is one precomputed reconstruction matrix applied as a single
     batched multiply, staged through the same overlapped executor as encode.
 
-    Output goes through write_or_seek + a final truncate, so a rebuilt
+    Output goes through write_shard_rows + a final truncate, so a rebuilt
     shard of a sparse volume is sparse too (byte-identical on read); the
     .vif sidecar is preserved/recreated from the .ec00 superblock like the
     encode path; `fsync=True` makes the rebuilt shards durable before
@@ -214,12 +218,13 @@ def rebuild_ec_files(
 
     shard_size = os.path.getsize(base_name + to_ext(present[0]))
     inputs = {i: open(base_name + to_ext(i), "rb") for i in use}
-    outputs = {i: open(base_name + to_ext(i), "wb") for i in missing}
+    outputs = [open(base_name + to_ext(i), "wb") for i in missing]
     plan = [
         (off, min(stride, shard_size - off))
         for off in range(0, shard_size, stride)
     ]
     readers = bulk.row_readers()
+    writers = bulk.row_writers() if use_overlap else None
     t_start = time.perf_counter()
     try:
 
@@ -234,18 +239,19 @@ def rebuild_ec_files(
 
         def write_batch(desc, payload, out):
             bulk.POOL.give(payload)
-            for j, shard_id in enumerate(missing):
-                write_or_seek(outputs[shard_id], out[j])
+            bulk.write_shard_rows("rebuild", outputs, out, writers)
 
         t = bulk.run(
             "rebuild", plan, read_batch, codec, write_batch,
             overlap=use_overlap, prefetch=prefetch, direct=True,
         )
-        _finish_outputs(list(outputs.values()), fsync, t)
+        _finish_outputs(outputs, fsync, t)
     finally:
         readers.shutdown()
+        if writers is not None:
+            writers.shutdown()
         codec.shutdown()
-        for h in list(inputs.values()) + list(outputs.values()):
+        for h in list(inputs.values()) + outputs:
             h.close()
     # shard 0 exists now (present or just rebuilt): its head is the .dat's
     # head, so a missing .vif can be restored exactly like encode does

@@ -8,6 +8,7 @@ concurrent shell fan-out (spread copies in parallel with `.vif` shipped
 exactly once; ec.rebuild's gather with per-RPC retry)."""
 import asyncio
 import os
+import sys
 import time
 from types import SimpleNamespace
 
@@ -18,6 +19,9 @@ from seaweedfs_tpu.ops import rs
 from seaweedfs_tpu.storage import ec
 from seaweedfs_tpu.storage.ec import bulk, encoder
 from seaweedfs_tpu.storage.ec.layout import to_ext
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+from benchmark.reference import rs_plain  # noqa: E402
 
 
 def run(coro):
@@ -62,10 +66,10 @@ def slow_io(monkeypatch):
 
     def slow_write(fobj, row):
         time.sleep(0.001)
-        real_write(fobj, row)
+        return real_write(fobj, row)
 
-    # encoder binds write_or_seek into its own namespace at import
-    monkeypatch.setattr(encoder, "write_or_seek", slow_write)
+    # write_shard_rows looks write_or_seek up in bulk at every row
+    monkeypatch.setattr(bulk, "write_or_seek", slow_write)
 
     real_apply = rs.RSCodec.apply_matrix
 
@@ -514,12 +518,13 @@ class TestPooledBuffers:
     ):
         base = str(tmp_path / "1")
         want = _host_codec_shards(make_dat(base + ".dat", _DAT_SIZE, seed=27))
-        calls = {"n": 0}
+        import itertools
+
+        calls = itertools.count(1)  # next() is atomic: writes run side by side
 
         def failing(real):
             def wrapped(*args):
-                calls["n"] += 1
-                if calls["n"] == 6:
+                if next(calls) == 6:
                     raise OSError(f"boom-{leg}")
                 return real(*args)
             return wrapped
@@ -529,7 +534,7 @@ class TestPooledBuffers:
                 patch.setattr(bulk, "_preadv", failing(bulk._preadv))
             else:
                 patch.setattr(
-                    encoder, "write_or_seek", failing(bulk.write_or_seek)
+                    bulk, "write_or_seek", failing(bulk.write_or_seek)
                 )
             t0 = time.monotonic()
             with pytest.raises(OSError, match=f"boom-{leg}"):
@@ -1039,6 +1044,245 @@ class TestTwoOnTheDevice:
         assert shard_bytes(base) == want
         assert 0 < len(pool._free) <= pool.keep
         assert _bulk_threads() == []
+
+
+# ------------------------------------------ the write leg: rows side by side
+
+
+def _parallel_writes(pipeline):
+    return _counter("VOLUME_SERVER_EC_BULK_PARALLEL_WRITE_BATCHES", pipeline)
+
+
+def _plain_reference_dat(path):
+    """Four 1 MiB rows whose zeros land on every kind of hole: shard 6's
+    first block (a hole at a file's start), shard 1's second (in the
+    middle), the whole third row (every file, parity too), all of shard
+    8, and a short last row that ends 1000 bytes into shard 3's block
+    (holes at the ends of shards 4-9) -> the 14 shards by
+    benchmark/reference/rs_plain.py."""
+    block = rs_plain.BLOCK
+    rng = np.random.default_rng(39)
+    dat = rng.integers(1, 256, size=3 * 10 * block + 3 * block + 1000,
+                       dtype=np.uint8)
+
+    def zero(row, shard):
+        start = (row * 10 + shard) * block
+        dat[start:start + block] = 0
+
+    zero(0, 6)
+    zero(1, 1)
+    for shard in range(10):
+        zero(2, shard)
+    for row in range(3):
+        zero(row, 8)
+    with open(path, "wb") as f:
+        f.write(dat.tobytes())
+    shards = rs_plain.encode_rows(
+        dat.tobytes(), 0, 4, rs_plain.coding_matrix()[rs_plain.DATA_SHARDS:]
+    )
+    return {i: shards[i].tobytes() for i in range(14)}
+
+
+class RowLog:
+    """bulk.write_or_seek wrapped: every call's file, position, thread,
+    what it did and when, and how many calls were in flight at once.
+    `slow` names the shard file (by extension) whose rows take 20 ms;
+    `fail` = (extension, nth call on that file) raises instead."""
+
+    def __init__(self, monkeypatch, slow=None, fail=None, sleep=0.0):
+        import threading
+
+        self.lock = threading.Lock()
+        self.calls = []
+        self.now = self.most = 0
+        real = bulk.write_or_seek
+
+        def watched(fobj, row):
+            ext = os.path.splitext(fobj.name)[1]
+            with self.lock:
+                nth = sum(1 for c in self.calls if c["ext"] == ext)
+                call = {
+                    "ext": ext, "nth": nth, "at": fobj.tell(),
+                    "len": len(row), "start": time.monotonic(),
+                    "thread": threading.current_thread().name,
+                }
+                self.calls.append(call)
+                self.now += 1
+                self.most = max(self.most, self.now)
+            try:
+                time.sleep(0.02 if ext == slow else sleep)
+                if fail == (ext, nth):
+                    raise OSError("boom-write")
+                call["wrote"] = real(fobj, row)
+                return call["wrote"]
+            finally:
+                call["end"] = time.monotonic()
+                with self.lock:
+                    self.now -= 1
+
+        monkeypatch.setattr(bulk, "write_or_seek", watched)
+
+    def by_file(self):
+        out = {}
+        for call in self.calls:
+            out.setdefault(call["ext"], []).append(call)
+        return out
+
+
+class TestParallelWrites:
+    """The writer leg hands a batch's rows to the run's writer threads,
+    one task a row written from the row's own buffer, and starts the
+    next batch only when every task has ended; the serial mode writes
+    inline."""
+
+    @pytest.mark.parametrize("overlap", [True, False])
+    def test_the_files_are_the_plain_references_holes_and_sizes_included(
+        self, tmp_path, monkeypatch, overlap
+    ):
+        base = str(tmp_path / "1")
+        want = _plain_reference_dat(base + ".dat")
+        half = 1 << 19  # two batches a 1 MiB row
+        log = RowLog(monkeypatch, sleep=0.001)
+        counted = _parallel_writes("encode")
+        ec.write_ec_files(base, backend="cpu", stride=half, overlap=overlap)
+        assert shard_bytes(base) == want
+        assert {os.path.getsize(base + to_ext(i)) for i in range(14)} == {
+            4 << 20}
+        files = log.by_file()
+        # 8 batches: every file took its rows in order, and each all-zero
+        # half row became a hole, not written zeros
+        for i in range(14):
+            calls = files[to_ext(i)]
+            assert [c["at"] for c in calls] == [n * half for n in range(8)]
+            rows = np.frombuffer(want[i], dtype=np.uint8).reshape(8, half)
+            assert [c["wrote"] for c in calls] == list(rows.any(axis=1))
+        assert not any(c["wrote"] for c in files[to_ext(8)][:6])
+        assert not files[to_ext(6)][0]["wrote"]
+        assert not files[to_ext(1)][2]["wrote"]
+        assert not any(c["wrote"] for c in files[to_ext(9)][6:])
+        threads = {c["thread"] for c in log.calls}
+        if overlap:
+            assert all(t.startswith("ec-bulk-write") for t in threads)
+            assert 1 < log.most <= bulk.WRITE_THREADS
+            # the third row's two batches are holes in every file
+            assert _parallel_writes("encode") - counted == 6
+        else:
+            assert log.most == 1
+            assert _parallel_writes("encode") - counted == 0
+        assert _bulk_threads() == []
+        # rebuild three data shards and a parity shard the same way
+        lost = (1, 6, 8, 11)
+        for lost_shard in lost:
+            os.remove(base + to_ext(lost_shard))
+        counted = _parallel_writes("rebuild")
+        assert ec.rebuild_ec_files(
+            base, backend="cpu", stride=half, overlap=overlap
+        ) == list(lost)
+        assert shard_bytes(base) == want
+        # two rows of four written in every batch but the third row's
+        assert _parallel_writes("rebuild") - counted == (6 if overlap else 0)
+
+    @pytest.mark.parametrize("backend", ["cpu", "xla", "pallas"])
+    def test_a_slow_file_keeps_every_files_order_across_batches(
+        self, tmp_path, pool, monkeypatch, backend
+    ):
+        base = str(tmp_path / "1")
+        want = _host_codec_shards(make_dat(base + ".dat", _DAT_SIZE, seed=39))
+        log = RowLog(monkeypatch, slow=to_ext(5), sleep=0.001)
+        _encode_short_tail(base, backend, True)
+        assert shard_bytes(base) == want
+        files = log.by_file()
+        assert sorted(files) == [to_ext(i) for i in range(14)]
+        for calls in files.values():
+            at = 0
+            for call in calls:
+                assert call["at"] == at
+                at += call["len"]
+        # a batch's rows all ended before the next batch's first began,
+        # the slow file's included
+        batches = len(files[to_ext(0)])
+        assert batches == 6
+        for n in range(batches - 1):
+            ended = max(calls[n]["end"] for calls in files.values())
+            began = min(calls[n + 1]["start"] for calls in files.values())
+            assert ended <= began, n
+
+    @pytest.mark.parametrize("pipeline", ["encode", "rebuild"])
+    def test_a_failed_write_ends_the_run_after_every_task_and_no_early_give(
+        self, tmp_path, monkeypatch, pipeline
+    ):
+        """The write of shard 3's second row raises while the batch's
+        other rows are still being written: the run raises it, every
+        task of that batch has ended by then, and no payload went back
+        to the pool while a write was in flight."""
+        base = str(tmp_path / "1")
+        make_dat(base + ".dat", _DAT_SIZE, seed=40)
+        _encode_short_tail(base, "cpu", True)
+        want = shard_bytes(base)
+        if pipeline == "rebuild":
+            for lost in (3, 11):
+                os.remove(base + to_ext(lost))
+        log = RowLog(monkeypatch, fail=(to_ext(3), 1), sleep=0.01)
+        gives = []  # the writes in flight at each give
+
+        class WatchingPool(bulk.BufferPool):
+            def give(self, batch):
+                gives.append(log.now)
+                super().give(batch)
+
+        monkeypatch.setattr(bulk, "POOL", WatchingPool())
+        real_rows = bulk.write_shard_rows
+        in_flight_at_raise = []
+
+        def rows_then_count(*args):
+            try:
+                return real_rows(*args)
+            except OSError:
+                in_flight_at_raise.append(log.now)
+                raise
+
+        monkeypatch.setattr(bulk, "write_shard_rows", rows_then_count)
+        t0 = time.monotonic()
+        with pytest.raises(OSError, match="boom-write"):
+            if pipeline == "encode":
+                _encode_short_tail(base, "cpu", True)
+            else:
+                ec.rebuild_ec_files(
+                    base, backend="cpu", stride=4096, overlap=True,
+                    prefetch=2,
+                )
+        assert time.monotonic() - t0 < 10.0
+        assert in_flight_at_raise == [0]
+        assert all(c.get("end") for c in log.calls)
+        assert gives and set(gives) == {0}
+        assert _bulk_threads() == []
+        monkeypatch.undo()
+        if pipeline == "rebuild":
+            for lost in (3, 11):  # what the failed run left of them
+                os.remove(base + to_ext(lost))
+            ec.rebuild_ec_files(base, backend="cpu", stride=4096)
+        else:
+            _encode_short_tail(base, "cpu", True)
+        assert shard_bytes(base) == want
+
+    @pytest.mark.parametrize("overlap", [True, False])
+    def test_one_row_a_batch_and_verify_count_no_parallel_write(
+        self, tmp_path, overlap
+    ):
+        base = str(tmp_path / "1")
+        make_dat(base + ".dat", _DAT_SIZE, seed=41)
+        _encode_short_tail(base, "cpu", True)
+        want = shard_bytes(base)
+        before = {p: _parallel_writes(p) for p in ("rebuild", "verify")}
+        os.remove(base + to_ext(7))
+        assert ec.rebuild_ec_files(
+            base, backend="cpu", stride=4096, overlap=overlap
+        ) == [7]
+        assert ec.verify_ec_files(
+            base, backend="cpu", stride=4096, overlap=overlap
+        ) == ([0, 0, 0, 0], _SHARD_SIZE)
+        assert shard_bytes(base) == want
+        assert {p: _parallel_writes(p) for p in before} == before
 
 
 # ------------------------------------------------- .vif + fsync satellite
